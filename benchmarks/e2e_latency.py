@@ -602,7 +602,7 @@ def sharded_parity(cfg, n_shards: int = 2, batch: int = 1) -> dict:
     for strat in ("channel", "spatial"):
         splan = build_sharded_network_plan(
             params, cfg, n_shards=n_shards, strategies=(strat,),
-            batch=batch)
+            batch=batch, base=base)
         y = forward_spectral_sharded(params, splan, x, mesh=mesh)
         err = float(jnp.abs(y - ref).max())
         counts = {}
@@ -750,7 +750,7 @@ def resnet_dag_column(batch: int = 1) -> dict:
         for strat in ("channel", "spatial"):
             splan = build_sharded_network_plan(
                 params, cfg, n_shards=2, strategies=(strat,),
-                batch=batch)
+                batch=batch, base=plan)
             y = forward_spectral_sharded(params, splan, x, mesh=mesh)
             err = float(jnp.abs(y - ref).max())
             sh[strat] = {"max_abs_err": err}
@@ -777,6 +777,8 @@ def main() -> None:
                     "skip full-resolution plan/parity sweeps")
     ap.add_argument("--iters", type=int, default=3)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     traffic_cfg = (vgg16_spectral.SMOKE if args.quick
                    else vgg16_spectral.CONFIG)

@@ -240,6 +240,8 @@ def main() -> None:
                     help="also fold a serve summary into this "
                     "BENCH_e2e.json (atomic rewrite)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n_steps = 2 + bool(args.chaos)
     report: dict = {

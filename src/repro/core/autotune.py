@@ -36,9 +36,12 @@ import itertools
 from typing import Callable, Iterable, Sequence
 
 from repro.core import dataflow as df
+from repro.core import spectral as spec
 from repro.core.dataflow import FLOWS
 
-# Power-of-two VMEM block candidates; clamped to each layer's dims.
+# Power-of-two VMEM block candidates; clamped to each layer's dims, and
+# only those the TPU's (8, 128) tiling admits are searched
+# (``dataflow.tiling_violations``).
 BLOCK_CANDIDATES = (32, 64, 128, 256)
 
 
@@ -121,6 +124,22 @@ def _layer_candidates(layer: df.ConvLayer, fft_size: int, batch: int,
                  | {d for d in doubling if d <= t})
     for flow, bn, bm, bp in itertools.product(flows, bns, bms, bps):
         yield flow, bn, bm, bp
+
+
+def _legal_block_p(layer: df.ConvLayer, fft_size: int, batch: int,
+                   bn: int, bm: int, bp: int, mode: str | None,
+                   imode: str | None, geo=None) -> int | None:
+    """The block_p a candidate runs with, or None when Mosaic would
+    refuse its blocks (``dataflow.tiling_violations``).  A windowed
+    block_p sits on the lane axis, so it is recorded as the lane-padded
+    block the kernel streams (a 9-tile layer runs one 128-lane block);
+    a halo block_p is a tile budget and is kept."""
+    if df.tiling_violations(layer, fft_size, bn, bm, bp, hadamard=mode,
+                            input_mode=imode, geo=geo):
+        return None
+    if imode == "halo":
+        return bp
+    return df.lane_round(min(bp, layer.tiles(fft_size) * batch))
 
 
 def autotune_layer(layer: df.ConvLayer, fft_size: int, alpha: float, *,
@@ -206,33 +225,32 @@ def autotune_layer(layer: df.ConvLayer, fft_size: int, alpha: float, *,
                        batch=batch, active_bins=active_bins, **kw)
 
     scored: list[FusedTuning] = []
+    over: list[FusedTuning] = []
+    seen = set()
     for flow, bn, bm, bp in _layer_candidates(layer, fft_size, batch,
                                               blocks, hw_safe, flows):
         for mode in modes:
             for imode in imodes:
-                c = cost(bn, bp, bm, flow, mode, imode)
-                if c["vmem_bytes"] > vmem_budget:
+                bp_ = _legal_block_p(layer, fft_size, batch, bn, bm, bp,
+                                     mode, imode)
+                if bp_ is None or (flow, bn, bm, bp_, mode, imode) in seen:
                     continue
-                scored.append(FusedTuning(
-                    layer.name, flow, bn, bm, bp, c["hbm_bytes"],
-                    c["vmem_bytes"], _predict(c),
-                    hadamard=mode, input_mode=imode,
-                    grid_steps=c.get("grid_steps"),
-                    residual=residual))
+                seen.add((flow, bn, bm, bp_, mode, imode))
+                c = cost(bn, bp_, bm, flow, mode, imode)
+                (over if c["vmem_bytes"] > vmem_budget else scored).append(
+                    FusedTuning(
+                        layer.name, flow, bn, bm, bp_, c["hbm_bytes"],
+                        c["vmem_bytes"], _predict(c),
+                        hadamard=mode, input_mode=imode,
+                        grid_steps=c.get("grid_steps"),
+                        residual=residual))
     if not scored:
-        # Nothing fits the budget: return the smallest-footprint config
-        # anyway.  Interpret mode runs it regardless; on real TPU an
-        # over-budget working set fails at Mosaic compile time, so the
-        # caller sees vmem_bytes > budget on the returned tuning and can
-        # shrink blocks/batch before hitting that opaque error.
-        flow = flows[0]
-        bn = bm = bp = min(blocks)
-        c = cost(bn, bp, bm, flow, modes[0], imodes[0])
-        return FusedTuning(layer.name, flow, bn, bm, bp, c["hbm_bytes"],
-                           c["vmem_bytes"], _predict(c),
-                           hadamard=modes[0], input_mode=imodes[0],
-                           grid_steps=c.get("grid_steps"),
-                           residual=residual)
+        # Nothing fits the budget: return the smallest-footprint legal
+        # config anyway.  Interpret mode runs it regardless; on real TPU
+        # an over-budget working set fails at Mosaic compile time, so
+        # the caller sees vmem_bytes > budget on the returned tuning and
+        # can shrink blocks/batch before hitting that opaque error.
+        return min(over, key=lambda tn: (tn.vmem_bytes, tn.predicted_s))
     scored.sort(key=lambda tn: (tn.predicted_s,
                                 tn.grid_steps if tn.grid_steps is not None
                                 else 0.0,
@@ -448,10 +466,23 @@ def autotune_layer_sharded(layer: df.ConvLayer, fft_size: int,
         local = df.shard_local_layer(layer, fft_size, n_shards, strategy)
         if local is None:
             continue
+        geo = None                    # spatial shards run the band geometry
+        if strategy == "spatial" and n_shards > 1:
+            full = spec.make_geometry(layer.h_in, layer.w_in, layer.ksize,
+                                      fft_size, layer.pad)
+            geo = spec.make_band_geometry(
+                full, spec.shard_band_rows(full, n_shards))
+        seen = set()
         for flow, bn, bm, bp in _layer_candidates(local, fft_size, batch,
                                                   blocks, hw_safe, flows):
             for mode in modes:
                 for imode in imodes:
+                    bp_ = _legal_block_p(local, fft_size, batch, bn, bm,
+                                         bp, mode, imode, geo=geo)
+                    if (bp_ is None
+                            or (flow, bn, bm, bp_, mode, imode) in seen):
+                        continue
+                    seen.add((flow, bn, bm, bp_, mode, imode))
                     kw = {} if mode is None else {
                         "hadamard": mode, "r": schedule_r,
                         "mu": schedule_mu}
@@ -462,13 +493,13 @@ def autotune_layer_sharded(layer: df.ConvLayer, fft_size: int,
                     if residual is not None:
                         kw["residual"] = residual
                     c = df.tpu_sharded_flow_cost(
-                        layer, fft_size, alpha, bn, bp, bm, flow,
+                        layer, fft_size, alpha, bn, bp_, bm, flow,
                         n_shards=n_shards, strategy=strategy,
                         batch=batch, active_bins=active_bins, **kw)
                     if c is None or c["vmem_bytes"] > vmem_budget:
                         continue
                     tn = FusedTuning(
-                        layer.name, flow, bn, bm, bp, c["hbm_bytes"],
+                        layer.name, flow, bn, bm, bp_, c["hbm_bytes"],
                         c["vmem_bytes"], _predict(c), hadamard=mode,
                         input_mode=imode,
                         grid_steps=c.get("grid_steps"),

@@ -38,8 +38,8 @@ import dataclasses
 import math
 from typing import Iterable
 
-from repro.core.spectral import (halo_block_geometry, make_geometry,
-                                 shard_band_rows)
+from repro.core.spectral import (halo_block_geometry, halo_block_starts,
+                                 make_geometry, shard_band_rows)
 
 BRAM_DEPTH = 1024
 WORD_BYTES = 2  # 16-bit fixed point
@@ -326,8 +326,94 @@ def layer_latency_budget(layers: Iterable[ConvLayer], fft_size: int,
 # TPU v5e-class constants (also used by repro.roofline).
 TPU_HBM_GBPS = 819e9
 TPU_PEAK_FLOPS = 197e12
-TPU_VMEM_BYTES = 16 * 2 ** 20   # ~16 MiB usable kernel working set
+# Alg 1's VMEM budget: the working set ``tpu_fused_flow_cost`` prices.
+TPU_VMEM_BYTES = 48 * 2 ** 20
 TPU_ICI_GBPS = 50e9
+# Scoped-VMEM limit handed to Mosaic for every fused kernel.  A v5e
+# TensorCore has 128 MiB of VMEM; the compiler's own temporaries (dot
+# operands, Karatsuba sums) come on top of the working set Alg 1 prices
+# against TPU_VMEM_BYTES, so the limit leaves them 3x room.  (Mosaic's
+# default scoped limit, 16 MiB, is below the smallest legal working set
+# of a 64-channel dense layer.)
+TPU_VMEM_LIMIT_BYTES = 96 * 2 ** 20
+
+# TPU vreg tiling: a block's minor (lane) dim is a multiple of LANES or
+# the array's full extent, its second-minor (sublane) dim a multiple of
+# SUBLANES or the full extent.  The fused kernel pads its tile (lane)
+# axis to whole lane tiles; ``tiling_violations`` checks the rest.
+LANES = 128
+SUBLANES = 8
+
+
+def lane_round(n: int) -> int:
+    """``n`` rounded up to whole lane tiles."""
+    return -(-n // LANES) * LANES
+
+
+def kernel_lanes(layer: "ConvLayer", fft_size: int, block_p: int,
+                 input_mode: str | None, batch: int = 1
+                 ) -> tuple[int, int]:
+    """(bp, gp): the fused kernel's lanes per grid step and p-grid size.
+
+    'windowed' streams P = T*batch tiles in ``lane_round(min(block_p,
+    P))``-lane blocks.  'halo' splits ``block_p`` into a bth x btw tile
+    block (``halo_block_geometry``) whose tile columns are padded to a
+    lane tile, so a step holds bth * lane_round(btw) lanes and the grid
+    has one step per (image, block-row, block-col)."""
+    if input_mode == "halo":
+        geo = make_geometry(layer.h_in, layer.w_in, layer.ksize, fft_size,
+                            layer.pad)
+        hg = halo_block_geometry(geo, block_p)
+        return hg.bth * lane_round(hg.btw), batch * hg.n_blocks
+    t = layer.tiles(fft_size) * batch
+    bp = lane_round(min(block_p, t))
+    return bp, _ceil(t, bp)
+
+
+def tiling_violations(layer: "ConvLayer", fft_size: int, block_n: int,
+                      block_m: int, block_p: int, *, hadamard: str | None,
+                      input_mode: str | None, geo=None) -> list[str]:
+    """Blocks of the fused kernel that Mosaic would refuse, as messages
+    (empty = legal).  Blocks are clamped to the layer like the kernel
+    clamps them; a block equal to the clamped dim is the full extent
+    (a full-extent M is padded to a sublane tile by the kernel).
+
+      bias [N, 1] / planes [Fa, N, M] / shortcut [S2, N, P]: block_n on
+        a sublane axis;
+      planes: block_m on the lane axis; windows [S, M, P]: block_m on a
+        sublane axis (scheduled tables hold M on a major axis);
+      halo raw block [B, M, H, W]: its rows (sublanes) and columns
+        (lanes), and the offsets it is read from — ``halo_block_geometry``
+        / ``halo_block_starts`` of ``geo`` (default: the layer's own).
+    The tile (lane) axis of every other block is padded by the kernel.
+    """
+    out = []
+    bn, bm = min(block_n, layer.c_out), min(block_m, layer.c_in)
+    if bn % SUBLANES and bn != layer.c_out:
+        out.append(f"block_n={bn} on a sublane axis is neither a multiple "
+                   f"of {SUBLANES} nor c_out={layer.c_out}")
+    if hadamard != "scheduled" and bm % LANES and bm != layer.c_in:
+        out.append(f"block_m={bm} on the planes' lane axis is neither a "
+                   f"multiple of {LANES} nor c_in={layer.c_in}")
+    if bm % SUBLANES and bm != layer.c_in:
+        out.append(f"block_m={bm} on the windows' sublane axis is "
+                   f"neither a multiple of {SUBLANES} nor "
+                   f"c_in={layer.c_in}")
+    if input_mode == "halo":
+        if geo is None:
+            geo = make_geometry(layer.h_in, layer.w_in, layer.ksize,
+                                fft_size, layer.pad)
+        hg = halo_block_geometry(geo, block_p)
+        sh, sw = halo_block_starts(geo, hg)
+        for axis, size, starts, full, tile in (
+                ("rows", hg.rh, sh, geo.h_in, SUBLANES),
+                ("cols", hg.rw, sw, geo.w_in, LANES)):
+            if (size % tile and size != full) or (starts % tile).any():
+                out.append(
+                    f"halo block {axis}: {size} read from offsets "
+                    f"{sorted({int(s) for s in starts})} are not aligned "
+                    f"to {tile} (extent {full}, block_p={block_p})")
+    return out
 
 # Double-buffered VMEM accumulator slots the fused kernel's manual-DMA
 # output path cycles through (PR 8): slot = linearized_grid_step %
@@ -403,10 +489,15 @@ def tpu_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
     are what turn compression into traffic/compute savings.
     ``hadamard`` is likewise accepted-and-ignored (the staged Hadamard
     has exactly one datapath).
+
+    The tile axis P = T*batch is the planes' lane (minor) axis, so it is
+    priced like the fused kernel's (``kernel_lanes``, 'windowed'): in
+    ``lane_round(min(block_p, P))``-lane blocks, the last one padded.
     """
     del alpha, active_bins, hadamard  # dense-plane streaming only
     k2 = fft_size * fft_size
-    t = layer.tiles(fft_size) * batch
+    bp, gp = kernel_lanes(layer, fft_size, block_p, "windowed", batch)
+    t = bp * gp                       # tiles, padded to whole lane blocks
     cplx = 2
     x_bytes = layer.c_in * k2 * t * cplx * bytes_per_el
     w_bytes = layer.c_out * layer.c_in * k2 * cplx * bytes_per_el
@@ -416,18 +507,18 @@ def tpu_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
         hbm = (x_bytes * math.ceil(layer.c_out / block_n)
                + w_bytes + y_bytes)
     elif flow == "input_stationary":
-        hbm = (x_bytes + w_bytes * math.ceil(t / block_p) + y_bytes)
+        hbm = (x_bytes + w_bytes * gp + y_bytes)
     elif flow == "output_stationary":
         hbm = (x_bytes * math.ceil(layer.c_out / block_n)
-               + w_bytes * math.ceil(t / block_p) + y_bytes)
+               + w_bytes * gp + y_bytes)
     else:
         raise ValueError(flow)
 
     # per-grid-step working set: ONE frequency bin's blocks (the Pallas
     # grid blocks F with size 1; see kernels/spectral_hadamard.py)
-    vmem = (block_m * block_p * cplx * bytes_per_el             # X block
+    vmem = (block_m * bp * cplx * bytes_per_el                  # X block
             + block_n * block_m * cplx * bytes_per_el           # W block
-            + block_n * block_p * cplx * 4)                     # f32 acc
+            + block_n * bp * cplx * 4)                          # f32 acc
     flops = 8 * t * k2 * layer.c_in * layer.c_out
     return {
         "hbm_bytes": float(hbm),
@@ -595,10 +686,16 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
     fa = k2 if active_bins is None else max(1, min(int(active_bins), k2))
     gn = max(1, _ceil(layer.c_out, block_n))
     gm = max(1, _ceil(layer.c_in, block_m))
-    gp = max(1, _ceil(t, block_p))
     bn = min(block_n, layer.c_out)
     bm = min(block_m, layer.c_in)
-    bp = min(block_p, t)
+    if bm == layer.c_in:             # full-extent M: kernel pads to 8
+        bm = -(-bm // SUBLANES) * SUBLANES
+    # Lanes the kernel actually computes: the tile axis padded to whole
+    # lane tiles (windowed) or each halo tile row padded to one
+    # (``kernel_lanes``).  Every per-tile term below prices p_pad.
+    bp, gp = kernel_lanes(layer, fft_size, block_p, input_mode, batch)
+    p_pad = gp * bp
+    m_pad = gm * bm
     s = k2                   # overlap-save: K x K input windows
     s2 = tile * tile         # only the valid rows are written back
     raw_words = layer.c_in * layer.h_in * layer.w_in * batch
@@ -606,61 +703,51 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
         geo = make_geometry(layer.h_in, layer.w_in, layer.ksize,
                             fft_size, layer.pad)
         hg = halo_block_geometry(geo, block_p)
-        bp = hg.block_tiles          # effective tile block of the split
-        # the kernel's actual p grid: one step per (image, block-row,
-        # block-col) — NOT ceil(T / bt), which undercounts whenever the
-        # halo split pads the tile grid.
-        gp = max(1, batch * hg.n_blocks)
+        lw = lane_round(hg.btw)
         # raw-plus-halo words: every block reads its bth*t+k-1 x
         # btw*t+k-1 clamped raw region; overlap between neighbours is
-        # the k-1 halo only (vs the windowed tensor's ~(K/t)^2 full
-        # duplication), and nothing is materialized first.
-        x_stream = (layer.c_in * batch * hg.n_blocks * hg.rh * hg.rw
+        # the k-1 halo only, and nothing is materialized first.
+        x_stream = (m_pad * batch * hg.n_blocks * hg.rh * hg.rw
                     * bytes_per_el)
         # One-hot selector traffic is residency-aware: a selector block
         # is refetched only when its block index changes between
-        # consecutive grid steps, so a single-block axis (nbh == 1 /
-        # nbw == 1 — the btw-first split's common case) stays resident
+        # consecutive grid steps, so a single-block axis stays resident
         # for the whole kernel; otherwise it re-streams with the p
         # steps (upper bound: every p step, times the n revisits).
         sel_reread = {"output_stationary": gn * gp,
                       "weight_stationary": gn * gm * gp,
                       "input_stationary": gp}.get(flow, gp)
         gr_words = hg.bth * fft_size * hg.rh
-        gc_words = hg.btw * fft_size * hg.rw
+        gc_words = hg.rw * fft_size * lw
         x_once = ((gr_words * (1 if hg.nbh == 1 else sel_reread))
                   + (gc_words * (1 if hg.nbw == 1 else sel_reread))
                   ) * bytes_per_el
     else:
-        # windowed: the kernel streams the host-materialized window
-        # tensor (T * K^2 words/channel); the relayout pass that builds
-        # it (raw read + windowed write) happens once, outside the
-        # kernel, and is honest HBM traffic of this input path.
-        x_stream = layer.c_in * s * t * bytes_per_el
+        # windowed: the kernel streams the host-materialized, lane-
+        # padded window tensor; the relayout pass that builds it (raw
+        # read + windowed write) happens once, outside the kernel.
+        x_stream = m_pad * s * p_pad * bytes_per_el
         x_once = (raw_words + layer.c_in * s * t) * bytes_per_el
-    y_bytes = layer.c_out * s2 * t * bytes_per_el
+    y_bytes = layer.c_out * s2 * p_pad * bytes_per_el
 
     t_cyc = max(nnz, _ceil(nnz, mu))     # schedule length estimate
     if hadamard is None:                 # legacy compressed stream
         w_bytes = layer.c_out * layer.c_in * nnz * cplx * bytes_per_el
-        had_flops = 8 * t * nnz * layer.c_in * layer.c_out
-    elif hadamard == "dense":
-        w_bytes = layer.c_out * layer.c_in * k2 * cplx * bytes_per_el
-        had_flops = 8 * t * k2 * layer.c_in * layer.c_out
+        had_flops = 8 * p_pad * nnz * layer.c_in * layer.c_out
+    elif hadamard == "dense":         # planes padded to (gn*bn, m_pad)
+        w_bytes = gn * bn * m_pad * k2 * cplx * bytes_per_el
+        had_flops = 8 * p_pad * k2 * m_pad * layer.c_out
     elif hadamard == "bin":
-        w_bytes = layer.c_out * layer.c_in * fa * cplx * bytes_per_el
-        had_flops = 8 * t * fa * layer.c_in * layer.c_out
+        w_bytes = gn * bn * m_pad * fa * cplx * bytes_per_el
+        had_flops = 8 * p_pad * fa * m_pad * layer.c_out
     else:                                # scheduled: Alg-2 tables
-        mp = gm * bm
-        w_bytes = gn * mp * t_cyc * (r + 3 * bn) * bytes_per_el
-        # One-hot-matmul realization, per (group, channel, cycle):
-        #   p-dependent  gather 2*r*Fa + route 2*N'*r + cmul 6*N'
-        #                + scatter 2*N'*Fa  (per tile element),
-        #   p-independent  scatter one-hot o = sel @ gather,
-        #                2*N'*r*Fa, recomputed per p block.
-        per_cyc_p = 2 * r * fa + 2 * bn * r + 6 * bn + 2 * bn * fa
-        per_cyc_fix = 2 * bn * r * fa
-        had_flops = gn * mp * t_cyc * (per_cyc_p * t + per_cyc_fix * gp)
+        w_bytes = gn * m_pad * t_cyc * (r + 3 * bn) * bytes_per_el
+        # The kernel expands each table block into [Fa, bm, N'] planes
+        # in VMEM on every grid step — per cycle one [Fa, r] @ [r, N']
+        # one-hot matmul per channel plus two weight FMAs — then runs
+        # the plane GEMM on the Fa compacted bins.
+        expand = gn * gm * gp * t_cyc * bm * fa * bn * (2 * r + 4)
+        had_flops = 8 * p_pad * fa * m_pad * gn * bn + expand
 
     if flow == "output_stationary":
         x_hbm = x_stream * gn + x_once
@@ -695,46 +782,50 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
         sc_vmem = float(y_bytes)
 
     # Streamed blocks are double-buffered by the Pallas pipeline (x2);
-    # the DFT operators, the in-flight spectral blocks and the psum
+    # the DFT operators, the in-flight spectral values and the psum
     # scratch are single-copy VMEM residents.  Spectral dims are Fa.
     if hadamard == "scheduled":
         w_block = bm * t_cyc * (r + 3 * bn)       # table block
-        flight = bm * (r * fa + bn * r + bn * fa  # one-hot g/s/o
-                       + 2 * r * bp + 2 * bn * bp)  # replicas + PE in
+        flight = 3 * bm * fa * bn                 # expanded planes + hit
     else:
         w_block = cplx * fa * bn * bm             # W plane block
-        flight = 0
+        flight = fa * bn * bm                     # Karatsuba wr + wi
     if halo:
-        # raw halo block instead of a window block; the gathered
-        # windows [S, bm, bt] live in VMEM registers in flight, as do
-        # this block's one-hot selectors.
+        # raw halo block instead of a window block; one window row's
+        # masked raw block [bm, rh, rw], one tile row's column gathers
+        # [K, bm, K*lw], the double-buffered selector blocks and the
+        # windows live in VMEM in flight.
         x_block = bm * hg.rh * hg.rw
-        flight += (s * bm * bp
-                   + hg.bth * fft_size * hg.rh
-                   + hg.btw * fft_size * hg.rw)
+        flight += (s * bm * bp + bm * hg.rh * hg.rw
+                   + bm * fft_size * fft_size * lw
+                   + 2 * (gr_words + gc_words))
     else:
         x_block = s * bm * bp
-    vmem = (2 * (x_block                          # X block (windows/raw)
-                 + w_block)
+        flight += s * bm * bp
+    scratch = {"output_stationary": cplx * fa * bn * bp,
+               "input_stationary": cplx * fa * bm * bp}.get(flow, 0)
+    vmem = (2 * (x_block + w_block)               # pipelined input blocks
             + DMA_SLOTS * s2 * bn * bp            # manual-DMA Y staging
-            + cplx * fa * bm * bp                 # X~ in flight
-            + 2 * cplx * fa * bn * bp             # Y~ psum / Karatsuba
+            + scratch                             # psums / FFT-once X~
+            + (cplx + 1) * fa * bm * bp           # X~ + Karatsuba sum
+            + 3 * fa * bn * bp                    # Karatsuba products
+            + s2 * bn * bp                        # IFFT'd block
             + flight
             + 2 * fa * s + 2 * s2 * fa            # DFT / IDFT operators
             ) * bytes_per_el + sc_vmem            # retained / staged shortcut
 
     refft = gn if flow != "input_stationary" else 1
-    fft_flops = 2 * 2 * fa * s * layer.c_in * t * refft
+    fft_flops = 2 * 2 * fa * s * m_pad * p_pad * refft
     if halo:
-        # the in-kernel gather's two one-hot matmuls, recomputed
-        # whenever the block's FFT is
-        gather_macs = (hg.n_blocks
-                       * (hg.bth * fft_size * hg.rh * hg.rw
-                          + hg.bth * fft_size * hg.btw * fft_size
-                          * hg.rw))
-        fft_flops += 2 * gather_macs * layer.c_in * batch * refft
+        # the in-kernel gather (row mask-and-sum per window row, column
+        # one-hot matmul per window row), recomputed whenever the
+        # block's FFT is
+        gather_macs = gp * gm * (bm * hg.bth * fft_size * hg.rh * hg.rw
+                                 + hg.bth * bm * fft_size * hg.rw
+                                 * fft_size * lw)
+        fft_flops += 2 * gather_macs * refft
     ifft_passes = 1 if flow == "output_stationary" else gm
-    ifft_flops = 2 * 2 * s2 * fa * layer.c_out * t * ifft_passes
+    ifft_flops = 2 * 2 * s2 * fa * gn * bn * p_pad * ifft_passes
     flops = had_flops + fft_flops + ifft_flops
     serial = 0 if halo else x_once      # windowed relayout pass: serial
     grid_steps = gn * gm * gp

@@ -925,7 +925,11 @@ class ShardedLayerPlan:
 
     ``tuning`` is the two-level Alg-1 verdict (``autotune.ShardTuning``)
     that chose the strategy; ``provenance`` audits shard-level
-    demotions (``resilience.harden_sharded_plan``).
+    demotions (``resilience.harden_sharded_plan``).  ``operands``
+    (channel only) are the shards' kernels and tables stacked on a
+    leading device axis (``stack_shard_operands``), placed on the mesh
+    when the plan is built for one, so each device keeps its slice
+    resident across calls.
     """
 
     base: LayerPlan
@@ -934,6 +938,7 @@ class ShardedLayerPlan:
     tuning: at.ShardTuning
     shards: tuple[LayerPlan, ...]
     provenance: tuple[str, ...] = ()
+    operands: tuple[Array, ...] = ()
 
     def stats(self) -> dict:
         row = self.base.stats()
@@ -1007,19 +1012,39 @@ def _band_tables(lp: LayerPlan, tn: at.FusedTuning,
                       jnp.asarray(lt.vr), jnp.asarray(lt.vi))
 
 
+def stack_shard_operands(shards: Sequence[LayerPlan],
+                         tables: Sequence[PlanTables | None] | None = None
+                         ) -> tuple[Array, ...]:
+    """(wr, wi[, idx, sel, vr, vi]) of channel shard plans stacked on a
+    leading device axis, shard d's slice at index d.  ``tables``
+    overrides the shards' own tables (the executor's fault-site
+    staging may rewrite them)."""
+    if tables is None:
+        tables = [sh.tables for sh in shards]
+    ops = (jnp.stack([sh.wr for sh in shards]),      # [D, Fa, N, Mloc]
+           jnp.stack([sh.wi for sh in shards]))
+    if tables[0] is not None:
+        ops += tuple(jnp.stack([jnp.asarray(getattr(tb, f))
+                                for tb in tables])
+                     for f in PlanTables._fields)
+    return ops
+
+
 def make_sharded_layer_plan(lp: LayerPlan, st: at.ShardTuning,
                             n_shards: int, *,
-                            schedule_r: int = df.SCHEDULE_R
-                            ) -> ShardedLayerPlan:
+                            schedule_r: int = df.SCHEDULE_R,
+                            mesh=None) -> ShardedLayerPlan:
     """Construct the shard-local plans for one layer (see
     ``ShardedLayerPlan``).  Also the REBUILD step of the sharded
     degradation ladder: after ``resilience`` demotes the base plan one
     rung, calling this again re-derives consistent shard plans.
+    Given a ``mesh``, channel operands are placed on it.
 
     A base plan demoted off the fused backend executes replicated —
     sharded execution is a fused-kernel path; 'staged'/'einsum' rungs
-    run the base plan outside ``shard_map`` (a plan-level, uniform
-    decision, so no device can be left waiting on a collective).
+    run the whole base plan on every device, with no collective (a
+    plan-level, uniform decision, so no device can be left waiting on
+    a collective).
     """
     strategy = st.strategy
     if (n_shards <= 1 or strategy == "replicate"
@@ -1084,8 +1109,16 @@ def make_sharded_layer_plan(lp: LayerPlan, st: at.ShardTuning,
                             if raw_tables else lp.pe_utilization),
             tables=tables[d])
         for d in range(n_shards))
+    operands = stack_shard_operands(shards)
+    if mesh is not None:
+        from jax.sharding import NamedSharding
+
+        from repro.distributed import sharding as shd
+        operands = jax.device_put(
+            operands, NamedSharding(mesh, shd.spectral_stacked_spec()))
     return ShardedLayerPlan(base=lp, strategy="channel",
-                            n_shards=n_shards, tuning=st, shards=shards)
+                            n_shards=n_shards, tuning=st, shards=shards,
+                            operands=operands)
 
 
 def resharded_layer_plan(slp: ShardedLayerPlan, new_base: LayerPlan, *,
@@ -1097,7 +1130,7 @@ def resharded_layer_plan(slp: ShardedLayerPlan, new_base: LayerPlan, *,
     input-mode so shard plans track the base down the ladder; once the
     base leaves the fused backend, ``make_sharded_layer_plan`` collapses
     the strategy to 'replicate' (terminal rung — structurally immune to
-    collective hangs because no shard_map runs at all).
+    collective hangs because it runs no collective at all).
     """
     tn = dataclasses.replace(slp.tuning.base,
                              hadamard=new_base.hadamard,
@@ -1115,6 +1148,8 @@ def build_sharded_network_plan(params: dict, cfg, *,
                                batch: int = 1,
                                strategies: Sequence[str] | None = None,
                                validate: bool = True,
+                               base: NetworkPlan | None = None,
+                               mesh=None,
                                **build_kwargs) -> ShardedNetworkPlan:
     """Compile a ``NetworkPlan`` AND its per-layer partitioning.
 
@@ -1128,10 +1163,19 @@ def build_sharded_network_plan(params: dict, cfg, *,
     the partitioning search (e.g. ``("channel",)`` for a forced-mode
     test).  Remaining kwargs flow to ``build_network_plan`` and the
     relevant ones (vmem budget, blocks, schedule knobs) are re-read for
-    the sharded tuner so both levels cost the same machine.
+    the sharded tuner so both levels cost the same machine.  ``base``
+    is a ``NetworkPlan`` already built from the same params, config,
+    batch and kwargs; it is reused instead of built again (the host
+    build of a full-size plan takes minutes).  ``mesh`` is the device
+    mesh the plan will run on: channel-sharded kernels are placed on it
+    once, here (without it the executor moves them on every call).
     """
-    base = build_network_plan(params, cfg, batch=batch,
-                              validate=validate, **build_kwargs)
+    if base is None:
+        base = build_network_plan(params, cfg, batch=batch,
+                                  validate=validate, **build_kwargs)
+    elif base.batch != batch:
+        raise ValueError(f"base plan was built for batch {base.batch}, "
+                         f"not {batch}")
     vmem_budget = build_kwargs.get("vmem_budget", df.TPU_VMEM_BYTES)
     blocks = build_kwargs.get("blocks", at.BLOCK_CANDIDATES)
     hw_safe = build_kwargs.get("hw_safe", True)
@@ -1165,7 +1209,8 @@ def build_sharded_network_plan(params: dict, cfg, *,
             schedule_r=schedule_r, schedule_mu=schedule_mu,
             step_overhead_s=step_overhead_s, residual=residual)
         slayers.append(make_sharded_layer_plan(lp, st, n_shards,
-                                               schedule_r=schedule_r))
+                                               schedule_r=schedule_r,
+                                               mesh=mesh))
     splan = ShardedNetworkPlan(
         base=base, n_shards=n_shards,
         mesh_shape=(tuple(int(d) for d in mesh_shape)

@@ -339,6 +339,12 @@ def validate_layer_plan(lp, *, batch: int = 1,
           f"input_mode {lp.input_mode!r} not in {df.INPUT_MODES}")
         return out
 
+    # --- TPU (8, 128) tiling of the kernel's blocks -------------------
+    for msg in df.tiling_violations(
+            lp.layer, lp.geo.fft_size, tn.block_n, tn.block_m, tn.block_p,
+            hadamard=lp.hadamard, input_mode=lp.input_mode, geo=lp.geo):
+        d("tiling", f"{msg}; Mosaic refuses this block on a TPU")
+
     k2 = lp.geo.fft_size ** 2
     s2 = lp.geo.tile ** 2
     fa = lp.n_active_bins

@@ -163,17 +163,19 @@ class HaloGeometry(NamedTuple):
     clamped to the image (small images fit in one block) — and gathers
     its stride-t, size-K windows in VMEM with one-hot row/col matmuls
     (``halo_gather_matrices``).  Consecutive blocks overlap by the halo,
-    which Pallas expresses with element-offset (``pl.Unblocked``) index
-    maps; no ``[B, M, T, K, K]`` windowed tensor is ever materialized
-    in HBM.
+    which Pallas expresses with element-offset (``pl.Element``) block
+    dims; no ``[B, M, T, K, K]`` windowed tensor is ever materialized
+    in HBM.  Rows are the TPU's sublane axis, so a block split along H
+    starts on a multiple of ``ROW_ALIGN`` rows and reads that many
+    extra rows (``halo_block_geometry``).
     """
 
     bth: int             # tiles per block along H
     btw: int             # tiles per block along W
     nbh: int             # blocks along H  (ceil(n_tiles_h / bth))
     nbw: int             # blocks along W
-    rh: int              # raw rows per block: min(bth*t + k - 1, h_in)
-    rw: int              # raw cols per block
+    rh: int              # raw rows per block (aligned, <= h_in)
+    rw: int              # raw cols per block: min(btw*t + k - 1, w_in)
 
     @property
     def block_tiles(self) -> int:
@@ -193,33 +195,58 @@ def halo_block_geometry(geo: SpectralGeometry, block_p: int) -> HaloGeometry:
     ``block_tiles = bth*btw <= block_p`` is what the VMEM/psum blocks
     are sized by.  Deterministic: the kernel, the cost model and the
     autotuner all derive the same blocks from (geo, block_p).
+
+    When the image splits into several block rows (nbh > 1), each
+    block's read starts at its first window row rounded DOWN to a
+    multiple of ``ROW_ALIGN`` (the TPU's sublane tile), so ``rh`` grows
+    by the largest such rounding and is itself rounded up to
+    ``ROW_ALIGN``.  A single block row reads the whole height.
     """
     block_p = max(1, block_p)
     btw = max(1, min(geo.n_tiles_w, block_p))
     bth = max(1, min(geo.n_tiles_h, block_p // btw))
     ov = geo.ksize - 1
+    nbh = -(-geo.n_tiles_h // bth)
+    need = bth * geo.tile + ov
+    if nbh > 1:
+        slack = int((_raw_row_starts(geo, bth, nbh) % ROW_ALIGN).max())
+        need = -(-(need + slack) // ROW_ALIGN) * ROW_ALIGN
     return HaloGeometry(
-        bth=bth, btw=btw,
-        nbh=-(-geo.n_tiles_h // bth), nbw=-(-geo.n_tiles_w // btw),
-        rh=min(bth * geo.tile + ov, geo.h_in),
+        bth=bth, btw=btw, nbh=nbh, nbw=-(-geo.n_tiles_w // btw),
+        rh=min(need, geo.h_in),
         rw=min(btw * geo.tile + ov, geo.w_in))
+
+
+# Row alignment of a halo block's start and height: the f32 sublane
+# tile of a TPU vreg.  Mosaic DMAs a block only from a second-minor
+# offset it can prove to be a multiple of this.
+ROW_ALIGN = 8
+
+
+def _raw_row_starts(geo: SpectralGeometry, bth: int, nbh: int
+                    ) -> np.ndarray:
+    """First raw row each block row's windows read (>= 0)."""
+    ov = geo.ksize - 1
+    return np.maximum(np.arange(nbh) * bth * geo.tile - ov
+                      + geo.pre_halo_h, 0)
 
 
 def halo_block_starts(geo: SpectralGeometry, hg: HaloGeometry
                       ) -> tuple[np.ndarray, np.ndarray]:
     """Clamped raw-image start offsets of every halo block, per axis.
 
-    Block ib's windows span raw rows ``[ib*bth*t - (k-1), ...+rh)``;
-    the start is clamped to ``[0, h_in - rh]`` so the block never reads
-    out of bounds — the gather matrices re-align the windows against
-    the clamped block and encode the 'same'-padding (and bottom/right
-    tile padding) as all-zero one-hot rows.  The kernel's element-offset
-    index map computes exactly this formula on traced indices.
+    Block ib's windows span raw rows from ``ib*bth*t - (k-1)``; its read
+    starts there rounded down to ``ROW_ALIGN`` and clamped to ``[0,
+    h_in - rh]`` so the block never reads out of bounds — the gather
+    matrices re-align the windows against the block's start and encode
+    the 'same'-padding (and bottom/right tile padding) as all-zero
+    one-hot rows.  The kernel's element-offset index map computes
+    exactly this formula on traced indices.
     """
     ov = geo.ksize - 1
-    sh = np.arange(hg.nbh) * hg.bth * geo.tile - ov + geo.pre_halo_h
+    sh = _raw_row_starts(geo, hg.bth, hg.nbh) // ROW_ALIGN * ROW_ALIGN
     sw = np.arange(hg.nbw) * hg.btw * geo.tile - ov
-    return (np.clip(sh, 0, geo.h_in - hg.rh),
+    return (np.minimum(sh, geo.h_in - hg.rh),
             np.clip(sw, 0, geo.w_in - hg.rw))
 
 

@@ -28,19 +28,25 @@ global layout:
       canvases concatenate on H (``P(None, None, axis, None)``) and the
       'same' crop runs ONCE, globally.
 
-  replicate no shard_map at all: the base plan executes as on a single
-      device.  Also the terminal rung of the sharded degradation ladder
+  replicate every device executes the base plan on the full, replicated
+      activation: a ``shard_map`` with replicated specs and no
+      collective (a Mosaic kernel cannot be partitioned automatically,
+      so even a replicated kernel over a mesh runs under ``shard_map``).
+      Also the terminal rung of the sharded degradation ladder
       (``resilience.harden_sharded_plan``) — any layer that cannot run
       its fused shard kernels falls back here, a uniform plan-level
       decision, so no device is ever left blocked in a collective.
 
-Every collective runs with ``check_rep=False`` — the bodies launch
-Pallas kernels, which carry no replication rule.  The shard-scoped
-fault site ``shard_tables`` is consulted HOST-SIDE (operand staging),
-never inside a shard_map body: per-device python control flow does not
-exist there (one trace serves all devices), and host-side is precisely
-what turns an injected shard fault into a structured error *before*
-any device enters a collective.
+Every ``shard_map`` runs with ``check_vma=False`` — the bodies launch
+Pallas kernels, which carry no replication rule.  The channel
+strategy's stacked operands (``ShardedLayerPlan.operands``) are placed
+on the mesh when the plan is built for it, so each device keeps its own
+slice of the kernel resident.  The shard-scoped fault site
+``shard_tables`` is consulted HOST-SIDE (operand staging), never inside
+a shard_map body: per-device python control flow does not exist there
+(one trace serves all devices), and host-side is precisely what turns
+an injected shard fault into a structured error *before* any device
+enters a collective.
 """
 
 from __future__ import annotations
@@ -50,7 +56,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.sharding import NamedSharding
 
 from repro.core import resilience as res
 from repro.core import spectral as spec
@@ -126,8 +132,8 @@ def _execute_spatial(x: Array, slp, mesh, axis: str,
         return execute_band_plan(x_ext, band, interpret=interpret)
 
     sp_ = shd.spectral_specs("spatial", axis)
-    f = shard_map(body, mesh=mesh, in_specs=sp_["x"],
-                  out_specs=sp_["out"], check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=sp_["x"],
+                      out_specs=sp_["out"], check_vma=False)
     canvas = f(xp)                               # [B, N, D*hb, w_pad]
     return spec.crop_canvas_same(canvas, geo)
 
@@ -135,20 +141,19 @@ def _execute_spatial(x: Array, slp, mesh, axis: str,
 def _execute_channel(x: Array, slp, mesh, axis: str,
                      interpret: bool | None,
                      defer_relu: bool = False) -> Array:
-    from repro.core.plan import PlanTables
+    from repro.core.plan import PlanTables, stack_shard_operands
     from repro.kernels.fused_spectral_conv import execute_layer_plan
 
     base = slp.base
     shards = slp.shards
     mloc = shards[0].layer.c_in
     staged = _stage_shard_tables(slp, "channel")
-    wr = jnp.stack([sh.wr for sh in shards])     # [D, Fa, N, Mloc]
-    wi = jnp.stack([sh.wi for sh in shards])
-    tabs: tuple[Array, ...] = ()
-    if staged[0] is not None:
-        tabs = tuple(jnp.stack([jnp.asarray(getattr(tb, f))
-                                for tb in staged])
-                     for f in ("idx", "sel", "vr", "vi"))
+    ops = slp.operands
+    if any(st is not sh.tables for st, sh in zip(staged, shards)):
+        ops = stack_shard_operands(shards, staged)   # a fault rewrote them
+    # a no-op for operands the plan already placed on this mesh
+    wr, wi, *tabs = jax.device_put(
+        ops, NamedSharding(mesh, shd.spectral_stacked_spec(axis)))
 
     def body(xf, wrd, wid, *tb):
         i = jax.lax.axis_index(axis)
@@ -160,14 +165,30 @@ def _execute_channel(x: Array, slp, mesh, axis: str,
         return jax.lax.psum(y, axis)
 
     sp_ = shd.spectral_specs("channel", axis)
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(sp_["x"],) + (sp_["operand"],) * (2 + len(tabs)),
-        out_specs=sp_["out"], check_rep=False)
+        out_specs=sp_["out"], check_vma=False)
     y = f(x, wr, wi, *tabs)
     # deferred bias(+ReLU) — a partial sum through a ReLU is wrong
     epi = _defer_epilogue(base) if defer_relu else base
     return res._spatial_epilogue(y, epi)
+
+
+def _execute_replicated(x: Array, lp, mesh, interpret: bool | None,
+                        shortcut: Array | None = None) -> Array:
+    """The base plan on every device of ``mesh``, on the replicated
+    activation (and shortcut): no collective, replicated output."""
+    rep = shd.spectral_replicated_spec()
+
+    def body(xf, *sc):
+        return res.execute_planned_layer(xf, lp, interpret=interpret,
+                                         shortcut=sc[0] if sc else None)
+
+    args = (x,) if shortcut is None else (x, shortcut)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(rep,) * len(args),
+                      out_specs=rep, check_vma=False)
+    return f(*args)
 
 
 def execute_sharded_layer(x: Array, slp, mesh, *,
@@ -190,7 +211,7 @@ def execute_sharded_layer(x: Array, slp, mesh, *,
     """
     if slp.strategy == "replicate" or not slp.shards:
         base = _defer_epilogue(slp.base) if defer_relu else slp.base
-        return res.execute_planned_layer(x, base, interpret=interpret)
+        return _execute_replicated(x, base, mesh, interpret)
     _check_mesh(slp, mesh, axis)
     if slp.strategy == "spatial":
         return _execute_spatial(x, slp, mesh, axis, interpret,
@@ -257,9 +278,8 @@ def forward_spectral_sharded(params: dict, splan, x: Array, *,
                   == "fused"):
                 # replicated residual-FUSED node: the shortcut rides
                 # the kernel's epilogue flush (stride 1 by invariant)
-                y = res.execute_planned_layer(src, base,
-                                              interpret=interpret,
-                                              shortcut=sc)
+                y = _execute_replicated(src, base, mesh, interpret,
+                                        shortcut=sc)
             else:
                 y = execute_sharded_layer(src, slp, mesh,
                                           axis=splan.axis,
@@ -274,8 +294,5 @@ def forward_spectral_sharded(params: dict, splan, x: Array, *,
                 refs[s] -= 1
                 if refs[s] == 0:
                     acts.pop(s, None)
-    x = acts[out_id]
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ params["fc1"])
-    x = jax.nn.relu(x @ params["fc2"])
-    return x @ params["fc3"]
+    from repro.models.cnn import fc_head
+    return fc_head(params, acts[out_id])

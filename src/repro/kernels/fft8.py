@@ -24,8 +24,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 Array = jax.Array
 
 
@@ -38,21 +36,24 @@ def dft_matrices(fft_size: int) -> tuple[np.ndarray, np.ndarray]:
             (-np.sin(theta)).astype(np.float32))
 
 
+def _tile_matmul(w, a):
+    """``w @ a[b]`` for every tile b: [K, K] x [B, K, K] -> [B, K, K], as
+    one batched MXU matmul (no reshape moves data across lanes)."""
+    wb = jnp.broadcast_to(w[None], a.shape[:1] + w.shape)
+    return jax.lax.dot_general(wb, a, (((2,), (1,)), ((0,), (0,))),
+                               preferred_element_type=jnp.float32)
+
+
 def _fft_kernel(x_ref, cr_ref, ci_ref, yr_ref, yi_ref, *, k: int, bb: int):
     x = x_ref[...].reshape(bb * k, k)          # [B*K, K]
     cr, ci = cr_ref[...], ci_ref[...]
     # stage 1: A = X @ W^T   (X real)
     ar = jnp.dot(x, cr.T, preferred_element_type=jnp.float32)
     ai = jnp.dot(x, ci.T, preferred_element_type=jnp.float32)
-    # stage 2: Y = W @ A  per tile; pack as [K, B*K]
-    ar = ar.reshape(bb, k, k).transpose(1, 0, 2).reshape(k, bb * k)
-    ai = ai.reshape(bb, k, k).transpose(1, 0, 2).reshape(k, bb * k)
-    yr = (jnp.dot(cr, ar, preferred_element_type=jnp.float32)
-          - jnp.dot(ci, ai, preferred_element_type=jnp.float32))
-    yi = (jnp.dot(cr, ai, preferred_element_type=jnp.float32)
-          + jnp.dot(ci, ar, preferred_element_type=jnp.float32))
-    yr_ref[...] = yr.reshape(k, bb, k).transpose(1, 0, 2)
-    yi_ref[...] = yi.reshape(k, bb, k).transpose(1, 0, 2)
+    ar, ai = ar.reshape(bb, k, k), ai.reshape(bb, k, k)
+    # stage 2: Y = W @ A  per tile
+    yr_ref[...] = _tile_matmul(cr, ar) - _tile_matmul(ci, ai)
+    yi_ref[...] = _tile_matmul(cr, ai) + _tile_matmul(ci, ar)
 
 
 def _ifft_kernel(xr_ref, xi_ref, vr_ref, vi_ref, y_ref, *, k: int, bb: int):
@@ -64,12 +65,9 @@ def _ifft_kernel(xr_ref, xi_ref, vr_ref, vi_ref, y_ref, *, k: int, bb: int):
           - jnp.dot(xi, vi.T, preferred_element_type=jnp.float32))
     ai = (jnp.dot(xr, vi.T, preferred_element_type=jnp.float32)
           + jnp.dot(xi, vr.T, preferred_element_type=jnp.float32))
-    ar = ar.reshape(bb, k, k).transpose(1, 0, 2).reshape(k, bb * k)
-    ai = ai.reshape(bb, k, k).transpose(1, 0, 2).reshape(k, bb * k)
+    ar, ai = ar.reshape(bb, k, k), ai.reshape(bb, k, k)
     # stage 2: y = Re(V @ A)
-    y = (jnp.dot(vr, ar, preferred_element_type=jnp.float32)
-         - jnp.dot(vi, ai, preferred_element_type=jnp.float32))
-    y_ref[...] = y.reshape(k, bb, k).transpose(1, 0, 2)
+    y_ref[...] = _tile_matmul(vr, ar) - _tile_matmul(vi, ai)
 
 
 def _pad_batch(x: Array, bb: int) -> tuple[Array, int]:
@@ -100,7 +98,7 @@ def fft2_tiles(x: Array, *, fft_size: int, block_b: int = 256,
         in_specs=[spec_x, spec_d, spec_d],
         out_specs=[spec_x, spec_x],
         out_shape=[jax.ShapeDtypeStruct(x.shape, jnp.float32)] * 2,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x.astype(jnp.float32), cr, ci)
@@ -126,7 +124,7 @@ def ifft2_tiles(yr: Array, yi: Array, *, block_b: int = 256,
         in_specs=[spec_x, spec_x, spec_d, spec_d],
         out_specs=spec_x,
         out_shape=jax.ShapeDtypeStruct(yr.shape, jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(yr.astype(jnp.float32), yi.astype(jnp.float32), vr, vi)

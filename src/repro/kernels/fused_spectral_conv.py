@@ -2,128 +2,78 @@
 
 The staged Pallas path (``ops.spectral_conv2d_pallas``) launches three
 kernels per layer — fft8 -> spectral_hadamard -> ifft8 — and round-trips
-the complex spectral tensors ``X~``/``Y~`` ([B, M, T, K, K], 2 f32 planes)
-through HBM between stages.  That inter-stage traffic is the TPU analogue
-of exactly the off-chip communication the paper's dataflow eliminates
-(§4): the FPGA design pipelines FFT -> Hadamard -> IFFT through on-chip
-buffers, touching DDR only for spatial inputs, spectral kernels and
-spatial outputs.
-
-This kernel restores that property, and (PR 3) adds the paper's other
-two contributions to the same pallas_call:
-
-  * **Overlap-save tiling + fused epilogue.**  Input windows are K x K
-    with stride t = K-k+1 (``core.spectral.extract_tiles_overlapping``),
-    so every tile's t x t valid output rows are *complete* full-conv
-    results — no cross-tile Overlap-and-Add sums remain.  That makes a
-    non-linear epilogue inside the kernel mathematically exact: the
-    flush step applies bias + ReLU before the single output write, and
-    post-conv elementwise work never round-trips HBM.  The inverse
-    operator keeps only the t^2 valid rows, so output traffic *drops*
-    from K^2 to t^2 words per tile relative to the OaA formulation.
-  * **Active-frequency-bin compaction (Alg 2 meets the MXU).**  For
-    pruned kernels the spectral GEMM batch is restricted to the Fa <= K^2
-    frequency bins that are non-zero in ANY kernel — the bin set the
-    exact-cover schedule touches (``scheduler.active_bins_from_tables``;
-    by the exact-cover property it equals the union of non-zero kernel
-    bins, which ``core.plan`` precomputes).  Forward DFT rows, kernel
-    planes, Karatsuba Hadamard batch, IFFT columns and the psum scratch
-    all shrink by Fa/K^2.  When nnz ~= K^2 (padded Fa >= K^2) the caller
-    falls back to dense — compaction would buy nothing.
-  * **In-kernel halo gather (PR 5 — true activation reuse).**  The
-    windowed input path consumes a host-materialized [B, M, T, K, K]
-    overlapping-window tensor: one full HBM relayout pass plus a
-    ~(K/t)^2 duplicated stream before any flow-level reuse happens.
-    ``input_mode='halo'`` eliminates it: the kernel reads the RAW NCHW
-    activation through overlapping halo input blocks — element-offset
-    (``pl.Unblocked``) index maps hand each grid step ``bth*t + (k-1)``
-    rows x ``btw*t + (k-1)`` cols covering its bth x btw tiles plus
-    their shared halo, clamped at the image edges — and two one-hot MXU
-    matmuls (``spectral.halo_gather_matrices``) assemble the stride-t
-    K x K windows in VMEM, with all-zero selector rows supplying the
-    'same' zero-padding.  The gather is numerically exact, so the halo
-    path is bit-identical to the windowed one (which stays as the
-    fallback/oracle); HBM sees raw-plus-halo words only.  Available for
-    every flow and Hadamard mode; ``core.plan`` ranks the two input
-    modes per layer as a fourth Alg-1 axis (DESIGN.md adaptation
-    note 7, docs/DATAFLOW.md section 2).
-  * **Element-granular scheduled sparse Hadamard (Alg 2 proper).**  The
-    Hadamard stage has three modes.  'dense' and 'bin' stream kernel
-    PLANES ([Fa, N, M] complex) and run the Karatsuba GEMM above.
-    'scheduled' instead streams the exact-cover schedule's INDEX/VALUE
-    tables (``scheduler.compile_layer_tables``) and executes them with
-    the one-hot-matmul datapath of ``kernels.sparse_hadamard`` — gather
-    r replicas per cycle, route through the sel crossbar, complex-MAC,
-    scatter — *inside the same pallas_call*, between the tile-FFT and
-    the IFFT/epilogue.  Kernel-operand traffic drops from O(Fa*N*M)
-    plane words toward O(nnz) table words (~3*T*N' words per group and
-    channel, T ~= nnz/mu cycles), which is what the paper streams; the
-    price is one-hot MXU work, so ``core.autotune`` ranks the mode per
-    layer against bin compaction with ``dataflow.tpu_fused_flow_cost
-    (hadamard=...)`` and falls back to dense/bin when the schedule
-    degenerates (alpha ~= 1).
+the complex spectral tensors through HBM between stages.  That
+inter-stage traffic is the TPU analogue of the off-chip communication
+the paper's dataflow eliminates (§4).  This kernel keeps the spectral
+intermediates in VMEM: HBM sees only the input, the kernel operand and
+the finished spatial output.
 
 Per grid step the kernel performs, entirely in VMEM:
 
-  1. tile-FFT   — one MXU GEMM against D = kron(W, W)[active, :]
-     ([Fa, K^2], W the K-point DFT matrix): the K x K windows are stored
-     s-leading ([S=K^2, M, P]) so the contraction is over the *leading*
-     dim and needs no in-kernel transposes;
-  2. Hadamard   — the frequency-batched complex GEMM in
-     3-multiplication Karatsuba form over the Fa active bins,
-        Y~[f, n, p] = sum_m W~[f, n, m] X~[f, m, p];
-  3. IFFT + epilogue (flush) — Re(Dinv @ Y~) with Dinv restricted to the
-     t^2 valid output rows and Fa active columns ([t^2, Fa]), then
-     y = relu(y + bias) (both optional).  The finished rectangle is
-     DMA'd to the output buffer by the kernel itself (PR 8): the halo
-     path re-lays its tiles into the spatial output canvas *in VMEM*
-     before the copy, so the host keeps only the final 'same'-crop
-     slice and ``assemble_valid_tiles`` is off the fused hot path.
+  1. windows  — the step's K x K overlap-save windows as ``[S=K^2, bm,
+     bp]`` (s-leading, lanes = tiles).  Two sources
+     (``dataflow.INPUT_MODES``):
+       * 'windowed': a block of the host-materialized ``[S, M, P]``
+         window tensor;
+       * 'halo': the RAW NCHW activation, read through overlapping
+         element-offset blocks (``pl.Element`` block dims) that cover
+         ``bth`` tile rows x ``btw`` tile columns plus their shared k-1
+         halo, clamped at the image edges.  Two exact one-hot gathers
+         build the windows: per window row, a masked sum over the raw
+         rows (``spectral.halo_gather_matrices``' ``gr``, on the VPU),
+         then an MXU column gather whose output lanes are ``(kw, jj)``
+         with the tile column ``jj`` padded to a whole lane tile
+         (``LANES``).  All-zero selector rows are the 'same'
+         zero-padding.
+  2. tile-FFT — one MXU GEMM against D = kron(W, W)[active, :]
+     ([Fa, K^2]) on the ``[S, bm * bp]`` view of the windows;
+  3. Hadamard — the frequency-batched complex GEMM in 3-multiplication
+     Karatsuba form over the Fa active bins.  The kernel operand has two
+     sources (``dataflow.HADAMARD_MODES``): spectral planes ``[Fa, N,
+     M]`` ('dense' / 'bin'), or the Alg-2 INDEX/VALUE tables
+     ('scheduled'), which the kernel expands into the same planes in
+     VMEM — per schedule cycle one one-hot matmul resolves each PE
+     lane's frequency (``idx[sel]``) and the lane's weight is added
+     there.  HBM then streams O(nnz) table words instead of O(Fa*N*M)
+     plane words, and the MXU runs the dense GEMM;
+  4. IFFT + epilogue — Re(Dinv @ Y~) with Dinv restricted to the t^2
+     valid output rows and Fa active columns, then bias (+ residual
+     shortcut) + ReLU.  Every window's t x t valid outputs are complete
+     (overlap-save), so the non-linear epilogue is exact in-kernel.
 
 The contraction over input channels M runs across a grid dimension; the
-paper's three reuse choices map onto grid iteration orders exactly as in
-``spectral_hadamard`` (which operand block Pallas keeps resident between
-consecutive grid steps):
+paper's three reuse choices map onto grid iteration orders:
 
-  * ``output_stationary``  grid (n, p, m): f32 psums accumulate in VMEM
-    scratch across the innermost m loop; HBM sees each output once and
-    never sees X~/Y~ at all.
+  * ``output_stationary``  grid (n, p, m): f32 spectral psums accumulate
+    in VMEM scratch across the innermost m loop; one output write.
   * ``weight_stationary``  grid (n, m, p) (Flow #1, reuse kernels): the
-    W~ block is constant across the inner p loop so it loads exactly
-    once, but partial outputs are read-modify-written per m block.
-    IFFT is linear, so partial Y~ blocks are IFFT'd eagerly and the RMW
-    traffic is *spatial* psums (t^2 real words/tile) — spectral
-    intermediates still never reach HBM.  The epilogue fires on the
-    final m visit, after the last accumulation.
+    kernel block is constant across the inner p loop; each m block's
+    partial result is IFFT'd eagerly (IFFT is linear) and the SPATIAL
+    psum is read-modify-written through the output buffer.
   * ``input_stationary``   grid (p, m, n) (Flow #2, reuse activations):
-    the raw window block is constant across the inner n loop and its FFT
-    is computed once into VMEM scratch (at n-block 0) and reused;
-    kernels re-stream per p block, same spatial-psum RMW + final-visit
-    epilogue.
+    the window block's FFT is computed once (n-block 0) into VMEM
+    scratch and reused across the inner n loop; same spatial-psum RMW.
 
-Output side (PR 8 — manual-DMA psum streaming): the kernels do NOT use
-a pipelined output BlockSpec.  The output buffer lives in ANY memory
-space (HBM) and every kernel moves its finished or partial rectangles
-itself with ``pltpu.make_async_copy`` through ``dataflow.DMA_SLOTS``
-double-buffered VMEM accumulator tiles + DMA semaphores.  The RMW flows
-(weight/input-stationary) prefetch the accumulator rectangle *before*
-the step's FFT/Hadamard/IFFT compute — the inbound DMA overlaps the MXU
-work — then add the step's partial spatial psum and copy it back; the
-first m visit is a pure write and the last applies the epilogue.  This
-is exactly the FPGA design's psum stream through DDR, and it removes
-the old hardware restriction that the accumulation revisit be
-CONSECUTIVE in the grid: any (block_n, block_m, block_p) is now valid
-on hardware for every flow, including halo + weight_stationary at
-batch > 1.  (The former ``_check_hw_safe`` guard and the autotuner's
-hw-safe candidate filters are gone; ``core.resilience.validate_plan``
-instead checks the DMA accumulator geometry — rectangle bounds, revisit
-count, slot budget — at plan-build time.)
+Output side: the output lives in ANY memory (HBM) and the kernel moves
+its rectangles itself with ``pltpu.make_async_copy`` through
+``dataflow.DMA_SLOTS`` double-buffered VMEM staging tiles.  The RMW
+flows prefetch the accumulator rectangle before the step's compute and
+add to it afterwards, so a revisit may come any number of grid steps
+later.  Both input modes write the same ``[S2, N, lanes]`` tile stream;
+the host relays it to the spatial canvas in one fused XLA copy together
+with the 'same' crop.
 
-HBM traffic per flow is modeled by ``repro.core.dataflow.tpu_fused_flow_cost``
-(sparsity-aware since PR 3); flow/blocks are chosen per layer by
-``repro.core.autotune`` and precompiled into a ``core.plan.LayerPlan``
-whose operands ``execute_layer_plan`` consumes without re-deriving any
-of this per call.
+TPU tiling: every block's lane (minor) dim is a multiple of 128 or the
+array's extent and every sublane dim a multiple of 8 or the extent
+(``dataflow.tiling_violations`` is the rule; Alg 1 searches only legal
+blocks and ``resilience.validate_layer_plan`` rejects the rest).  The
+kernel pads the tile (lane) axis to whole lane tiles itself.  All
+in-kernel dots run at ``Precision.HIGHEST``: the one-hot gathers must be
+exact, and the f32 result is compared with the f32 einsum oracle.
+
+HBM traffic per flow is modeled by ``core.dataflow.tpu_fused_flow_cost``;
+flow/blocks/modes are chosen per layer by ``core.autotune`` and compiled
+into a ``core.plan.LayerPlan`` that ``execute_layer_plan`` consumes.
 """
 
 from __future__ import annotations
@@ -136,19 +86,21 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core import resilience as res
 from repro.core import sparse as sp
-from repro.core.dataflow import DMA_SLOTS, FLOWS, INPUT_MODES
-from repro.core.spectral import (HaloGeometry, SpectralGeometry,
-                                 assemble_tile_canvas,
-                                 assemble_valid_tiles,
+from repro.core.dataflow import (DMA_SLOTS, FLOWS, INPUT_MODES, LANES,
+                                 SUBLANES, TPU_VMEM_LIMIT_BYTES,
+                                 lane_round)
+from repro.core.spectral import (ROW_ALIGN, HaloGeometry, SpectralGeometry,
+                                 assemble_tile_canvas, crop_canvas_same,
                                  extract_tiles_overlapping,
                                  halo_block_geometry, halo_gather_matrices)
 from repro.kernels.fft8 import dft_matrices
+from repro.kernels.ops import default_interpret
 
 Array = jax.Array
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -185,294 +137,189 @@ def overlap_save_operators(fft_size: int, ksize: int,
 
 
 # ---------------------------------------------------------------------------
-# Kernel bodies
+# Kernel stages
 # ---------------------------------------------------------------------------
 
-def _tile_fft(x_ref, dfr_ref, dfi_ref):
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+_MM = (((1,), (0,)), ((), ()))              # [a, k] @ [k, b]
+_BMM = (((2,), (1,)), ((0,), (0,)))         # batched [z, a, k] @ [z, k, b]
+_BMM_TN = (((1,), (1,)), ((0,), (0,)))      # batched [z, k, a]^T @ [z, k, b]
+
+
+def _tile_fft(x, dfr, dfi):
     """Stage 1: one GEMM against the kron'd DFT operator.
     [S, bm, bp] real windows -> (re, im) [Fa, bm, bp] spectral planes."""
-    s, bm, bp = x_ref.shape
-    fa = dfr_ref.shape[0]
-    x2 = x_ref[...].reshape(s, bm * bp)
-    xfr = jnp.dot(dfr_ref[...], x2,
-                  preferred_element_type=jnp.float32).reshape(fa, bm, bp)
-    xfi = jnp.dot(dfi_ref[...], x2,
-                  preferred_element_type=jnp.float32).reshape(fa, bm, bp)
-    return xfr, xfi
+    s, bm, bp = x.shape
+    fa = dfr.shape[0]
+    x2 = x.reshape(s, bm * bp)
+    return (_dot(dfr, x2, _MM).reshape(fa, bm, bp),
+            _dot(dfi, x2, _MM).reshape(fa, bm, bp))
 
 
-def _hadamard(wr_ref, wi_ref, xfr, xfi):
+def _hadamard(wr, wi, xfr, xfi, *, w_mn: bool):
     """Stage 2: frequency-batched Karatsuba complex GEMM over active bins.
-    W [Fa, bn, bm] x X~ [Fa, bm, bp] -> (re, im) [Fa, bn, bp]."""
-    def bmm(a, b):
-        return jax.lax.dot_general(
-            a, b, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-
-    wr, wi = wr_ref[...], wi_ref[...]
-    m1 = bmm(wr, xfr)
-    m2 = bmm(wi, xfi)
-    m3 = bmm(wr + wi, xfr + xfi)
+    W [Fa, bn, bm] (or [Fa, bm, bn] when ``w_mn``) x X~ [Fa, bm, bp] ->
+    (re, im) [Fa, bn, bp]."""
+    dims = _BMM_TN if w_mn else _BMM
+    m1 = _dot(wr, xfr, dims)
+    m2 = _dot(wi, xfi, dims)
+    m3 = _dot(wr + wi, xfr + xfi, dims)
     return m1 - m2, m3 - m1 - m2
 
 
-def _ifft_real(re, im, dvr_ref, dvi_ref, bn, bp):
+def _ifft_real(re, im, dvr, dvi):
     """Stage 3: Re(Dinv @ Y~) -> [S2, bn, bp] finished spatial outputs."""
-    fa = re.shape[0]
-    s2 = dvr_ref.shape[0]
-    y = (jnp.dot(dvr_ref[...], re.reshape(fa, bn * bp),
-                 preferred_element_type=jnp.float32)
-         - jnp.dot(dvi_ref[...], im.reshape(fa, bn * bp),
-                   preferred_element_type=jnp.float32))
+    fa, bn, bp = re.shape
+    s2 = dvr.shape[0]
+    y = (_dot(dvr, re.reshape(fa, bn * bp), _MM)
+         - _dot(dvi, im.reshape(fa, bn * bp), _MM))
     return y.reshape(s2, bn, bp)
 
 
-def _epilogue(y, b_ref, relu: bool):
-    """Fused bias + ReLU on [S2, bn, bp]; bias block is [1, bn]."""
-    y = y + b_ref[0][None, :, None]
-    if relu:
-        y = jnp.maximum(y, 0.0)
-    return y
-
-
-def _ifft_real_nf(re, im, dvr_ref, dvi_ref):
-    """Stage 3 for the scheduled datapath: Re(Dinv @ Y~) on n-leading
-    psums.  re/im [N', Fa, bp] -> [S2, N', bp] finished spatial rows."""
-    dn = (((1,), (1,)), ((), ()))
-    return (jax.lax.dot_general(dvr_ref[...], re, dn,
-                                preferred_element_type=jnp.float32)
-            - jax.lax.dot_general(dvi_ref[...], im, dn,
-                                  preferred_element_type=jnp.float32))
-
-
-def _scheduled_hadamard(idx_ref, sel_ref, vr_ref, vi_ref, xfr, xfi):
-    """Stage 2, 'scheduled' mode: execute the Alg-2 INDEX/VALUE tables
-    (``scheduler.LayerTables`` blocks) with MXU one-hot matmuls.
-
-    Per cycle t, vectorized over the bm channels of the block and the
-    bp tiles: gather the r replica rows of X~ (one-hot [r, Fa] @ X~),
-    route them to the N' PE lanes (sel one-hot [N', r] @ replicas),
-    complex-MAC against the VALUE plane (idle lanes carry zero weights),
-    and scatter into the psum — the scatter one-hot is the ROUTED gather
-    one-hot (sel @ gather), which is exactly ``out_index ==
-    index_table[t, sel]`` of Fig 6, so the out-index plane never needs
-    streaming.
-
-    idx_ref [1, bm, T, r] int32 (compacted-bin coords), sel_ref /
-    vr_ref / vi_ref [1, bm, T, N']; xfr/xfi [Fa, bm, bp] spectral
-    planes.  Returns (re, im) psum contributions [N', Fa, bp] summed
-    over the block's channels and cycles.
-    """
-    _, bm, n_cycles, r = idx_ref.shape
-    n_pe = sel_ref.shape[3]
-    fa, _, bp = xfr.shape
-    xr = jnp.transpose(xfr, (1, 0, 2))                  # [bm, Fa, bp]
-    xi = jnp.transpose(xfi, (1, 0, 2))
-    idx, sel = idx_ref[0], sel_ref[0]
-    vr, vi = vr_ref[0], vi_ref[0]
-    f_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, fa), 2)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (1, 1, r), 2)
-
-    def bmm(a, b):                                      # batch over bm
-        return jax.lax.dot_general(a, b, (((2,), (1,)), ((0,), (0,))),
-                                   preferred_element_type=jnp.float32)
-
-    def cycle(t, carry):
-        ar, ai = carry
-        take = lambda a: jax.lax.dynamic_index_in_dim(a, t, 1,
-                                                      keepdims=False)
-        g = (take(idx)[:, :, None] == f_iota).astype(jnp.float32)
-        s = (take(sel)[:, :, None] == r_iota).astype(jnp.float32)
-        rep_r = bmm(g, xr)                              # [bm, r, bp]
-        rep_i = bmm(g, xi)
-        in_r = bmm(s, rep_r)                            # [bm, N', bp]
-        in_i = bmm(s, rep_i)
-        wr = take(vr)[:, :, None]
-        wi = take(vi)[:, :, None]
-        pr = wr * in_r - wi * in_i
-        pi = wr * in_i + wi * in_r
-        o = bmm(s, g)                                   # [bm, N', Fa]
-        dn = (((0,), (0,)), ((1,), (1,)))               # sum channels
-        ar = ar + jax.lax.dot_general(o, pr, dn,
-                                      preferred_element_type=jnp.float32)
-        ai = ai + jax.lax.dot_general(o, pi, dn,
-                                      preferred_element_type=jnp.float32)
-        return ar, ai
-
-    zero = jnp.zeros((n_pe, fa, bp), jnp.float32)
-    return jax.lax.fori_loop(0, n_cycles, cycle, (zero, zero))
-
-
-def _halo_windows(x_ref, gr_ref, gc_ref, *, bth: int, btw: int,
-                  fft_size: int):
-    """In-kernel halo gather (input_mode='halo'): raw activation block ->
-    overlap-save windows, entirely in VMEM.
-
-    x_ref [1, bm, rh, rw] is a clamped raw-image block covering
-    bth x btw tiles plus their shared k-1 halo (``pl.Unblocked``
-    element offsets — consecutive blocks overlap in HBM, nothing is
-    duplicated).  gr_ref [1, bth*K, rh] / gc_ref [1, btw*K, rw] are this
-    block's one-hot window selectors (``spectral.halo_gather_matrices``;
-    all-zero rows encode the 'same' zero-padding and the tile-grid
-    padding, and make the clamp-shift at image edges exact).  Two MXU
-    matmuls select rows then columns; one-hot f32 operands make the
-    gather numerically exact, so the halo path equals the windowed path
-    bit for bit.  Returns [S, bm, bth*btw] windows, s-leading — the
-    layout ``_tile_fft`` contracts.
-    """
-    k = fft_size
-    x = x_ref[0]                                        # [bm, rh, rw]
-    bm = x.shape[0]
-    rows = jax.lax.dot_general(                         # [bth*K, bm, rw]
-        gr_ref[0], x, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    win = jax.lax.dot_general(                          # [bth*K, bm, btw*K]
-        rows, gc_ref[0], (((2,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    win = win.reshape(bth, k, bm, btw, k)
-    win = win.transpose(1, 4, 2, 0, 3)                  # [K, K, bm, bth, btw]
-    return win.reshape(k * k, bm, bth * btw)
-
-
-class _LazyWindows:
-    """Ref-like stand-in for the gathered windows: ``.shape`` is known
-    statically, the gather itself traces at the ``[...]`` read site.
-    That makes the gather *conditional* wherever the body's window read
-    is — in the input-stationary kernels the read sits inside the
-    ``pl.when(gn == 0)`` FFT-once guard, so the flow's n-block revisits
-    skip the gather matmuls too (matching the cost model's refft = 1
-    for that flow)."""
-
-    def __init__(self, fn, shape):
-        self._fn = fn
-        self.shape = shape
-
-    def __getitem__(self, idx):
-        return self._fn()[idx]
-
-
-def _halo_kernel(body, *, bth: int, btw: int, fft_size: int):
-    """Wrap a flow kernel body so its window operand is gathered in-kernel
-    from a raw halo block instead of read pre-materialized.  The body's
-    first argument only ever sees ``x[...]``/``x.shape``, so the lazy
-    gather substitutes for the windowed Ref unchanged."""
-    def kernel(x_ref, gr_ref, gc_ref, *rest):
-        shape = (fft_size * fft_size, x_ref.shape[1], bth * btw)
-        body(_LazyWindows(
-            lambda: _halo_windows(x_ref, gr_ref, gc_ref, bth=bth,
-                                  btw=btw, fft_size=fft_size),
-            shape), *rest)
-    return kernel
-
-
 # ---------------------------------------------------------------------------
-# Manual-DMA output accumulators (PR 8)
+# Operand sources: where a grid step's windows and kernel planes come from
 # ---------------------------------------------------------------------------
 #
-# The output operand of every fused kernel lives in ANY memory space
-# (HBM); the kernel moves rectangles itself with ``pltpu.make_async_copy``
-# through DMA_SLOTS double-buffered VMEM staging tiles.  A *sink* object
-# describes the output layout: where a (n-block, p-block) rectangle
-# lives in the buffer (``dst``), how a computed [S2, bn, bp] spatial
-# block is re-laid before staging (``stage``), and how bias/ReLU apply
-# in that layout (``epilogue``).  The same three flow bodies then serve
-# both output layouts — the windowed [S2, Np, Pp] tile stream and the
-# halo path's assembled spatial canvas.
+# A source wraps the step's input Refs; ``chunks()`` / ``read()`` trace
+# the loads (and, for the halo gather / table expansion, the VMEM
+# compute) at the call site.  The flow bodies call them where they need
+# the value, so input-stationary's FFT-once guard also skips the halo
+# gather on the n-block revisits.  Windows come as a list of
+# [S, bm, LANES] chunks: every GEMM of the pipeline then has one lane
+# tile of columns, whichever input mode or block_p produced them.
+
+def _lanes(c: int) -> slice:
+    return slice(c * LANES, (c + 1) * LANES)
+
+
+class _WindowBlock:
+    """'windowed': a [S, bm, bp] block of the host window tensor."""
+
+    n_refs = 1
+
+    def __init__(self, refs):
+        (self._x,) = refs
+
+    def chunks(self):
+        return [self._x[:, :, _lanes(c)]
+                for c in range(self._x.shape[2] // LANES)]
+
+
+class _HaloBlock:
+    """'halo': gather [S, bm, bth*lw] windows from a raw [1, bm, rh, rw]
+    block.  The row selector ``gr`` [1, bth*K, rh, 1] (each one-hot row
+    of ``halo_gather_matrices`` as a column, broadcast across lanes in
+    the kernel) picks a window row's raw row as a masked sum over rh
+    (zero rows = 'same' padding), giving [bm, rw] with channels on
+    sublanes; the column
+    selector ``gc`` [1, rw, K*lw] then picks the K columns of every
+    window into lanes (kw, jj).  The [bm, lw] planes stack s-major (kh,
+    kw) on a major axis — the windowed path's order, so both paths run
+    the same GEMMs — and no step moves data between sublanes and a
+    major axis.  Each tile row's lw lanes are whole chunks."""
+
+    n_refs = 3
+
+    def __init__(self, refs, *, fft_size: int, bth: int, lw: int):
+        self._x, self._gr, self._gc = refs
+        self._k, self._bth, self._lw = fft_size, bth, lw
+
+    def chunks(self):
+        k, lw = self._k, self._lw
+        x = self._x[0]                                  # [bm, rh, rw]
+        gc = self._gc[0]                                # [rw, K*lw]
+        out = []
+        for ii in range(self._bth):
+            planes = []
+            for kh in range(k):
+                sel = self._gr[0, ii * k + kh]          # [rh, 1] one-hot
+                row = jnp.sum(x * sel[None], axis=1)    # [bm, rw]
+                g = _dot(row, gc, _MM)                  # [bm, (kw, jj)]
+                planes += [g[:, kw * lw:(kw + 1) * lw] for kw in range(k)]
+            win = jnp.stack(planes)                     # [(kh,kw), bm, lw]
+            out += [win[:, :, _lanes(c)] for c in range(lw // LANES)]
+        return out
+
+
+class _PlaneBlock:
+    """'dense' / 'bin': [Fa, bn, bm] spectral kernel plane blocks."""
+
+    n_refs = 2
+    w_mn = False
+
+    def __init__(self, refs):
+        self._wr, self._wi = refs
+
+    def read(self):
+        return self._wr[...], self._wi[...]
+
+
+class _TableBlock:
+    """'scheduled': expand one (group, channel-block) of Alg-2 tables —
+    idx [1, bm, T, r], sel / vr / vi [1, bm, T, N'] — into kernel planes
+    [Fa, bm, N'] in VMEM.  Cycle t contributes lane i's weight at the
+    frequency its routed replica reads, ``idx[c, t, sel[c, t, i]]``
+    (that is also where Fig 6 scatters the product); the one-hot
+    ``[Fa, r] @ [r, N']`` product is that frequency's indicator.  Idle
+    lanes and padded cycles/channels carry zero weight."""
+
+    n_refs = 4
+    w_mn = True
+
+    def __init__(self, refs, *, fa: int):
+        self._idx, self._sel, self._vr, self._vi = refs
+        self._fa = fa
+
+    def read(self):
+        _, bm, n_cycles, r = self._idx.shape
+        n_pe = self._sel.shape[3]
+        fa = self._fa
+        f_iota = jax.lax.broadcasted_iota(jnp.int32, (1, fa, 1), 1)
+        r_iota = jax.lax.broadcasted_iota(jnp.int32, (1, r, 1), 1)
+
+        def cycle(t, carry):
+            wr, wi = carry
+            row = lambda ref: ref[0, :, pl.ds(t, 1), :]  # [bm, 1, lanes]
+            g = (row(self._idx) == f_iota).astype(jnp.float32)   # [bm,Fa,r]
+            s = (row(self._sel) == r_iota).astype(jnp.float32)   # [bm,r,N']
+            hit = _dot(g, s, _BMM)                               # [bm,Fa,N']
+            return (wr + hit * row(self._vr), wi + hit * row(self._vi))
+
+        zero = jnp.zeros((bm, fa, n_pe), jnp.float32)
+        wr, wi = jax.lax.fori_loop(0, n_cycles, cycle, (zero, zero))
+        return jnp.swapaxes(wr, 0, 1), jnp.swapaxes(wi, 0, 1)
+
+
+# ---------------------------------------------------------------------------
+# Manual-DMA output accumulators
+# ---------------------------------------------------------------------------
 
 class _TileSink:
-    """Windowed output layout [S2, Np, Pp]: rectangle (n, p) is the
-    [S2, bn, bp] slab at (n*bn, p*bp); no in-VMEM relayout.
+    """Output layout [S2, Np, lanes]: rectangle (n, p) is the [S2, bn,
+    bp] slab at (n*bn, p*bp).  ``sc`` is the optional residual shortcut
+    Ref in the same layout, indexed on (n, p) only, so at the flush
+    step (the only epilogue site) its block is this rectangle's; it is
+    added after bias and before ReLU."""
 
-    ``_sc`` (set by ``_residual_kernel``) is an optional shortcut Ref in
-    the SAME output layout whose current block is added after bias and
-    before ReLU — the residual-fused epilogue of ISSUE 10.  The sc
-    BlockSpec indexes on (n, p) only, so at the flush step (the only
-    epilogue site) the prefetched block is exactly this rectangle's
-    shortcut."""
-
-    _sc = None
-
-    def __init__(self, s2: int, bn: int, bp: int):
-        self.bn, self.bp = bn, bp
-        self.stage_shape = (s2, bn, bp)
+    def __init__(self, bn: int, bp: int, sc=None):
+        self.bn, self.bp, self.sc = bn, bp, sc
 
     def dst(self, y_hbm, n_idx, p_idx):
         return y_hbm.at[:, pl.ds(n_idx * self.bn, self.bn),
                         pl.ds(p_idx * self.bp, self.bp)]
 
-    def stage(self, y):
-        return y
-
-    def epilogue(self, y, b_ref, relu: bool):
-        y = y + b_ref[0][None, :, None]
-        if self._sc is not None:
-            y = y + self._sc[...]
+    def epilogue(self, y, b_ref, relu: bool, c: int):
+        """Bias (+ shortcut) (+ ReLU) on lane chunk ``c`` [S2, bn, LANES]."""
+        y = y + b_ref[...][None]                        # bias [bn, 1]
+        if self.sc is not None:
+            y = y + self.sc[:, :, _lanes(c)]
         if relu:
             y = jnp.maximum(y, 0.0)
         return y
-
-
-class _CanvasSink:
-    """Halo output layout [B, Np, nbh*bth*t, nbw*btw*t] — the spatial
-    output canvas of ``assemble_valid_tiles``, assembled IN-KERNEL.
-    The p grid axis enumerates (image, block-row, block-col); a computed
-    [S2=t^2, bn, bth*btw] block is re-laid in VMEM to its
-    [bn, bth*t, btw*t] canvas rectangle before the DMA, so tile (i, j)'s
-    t x t valid rows land at canvas (i*t, j*t) exactly as the host
-    relayout used to place them.  The host keeps only the final
-    'same'-crop slice (``_crop_canvas``).
-
-    ``_sc`` (set by ``_residual_kernel``): optional shortcut Ref in the
-    same canvas layout, block (1, bn, bth*t, btw*t) — added after bias,
-    before ReLU at the flush step."""
-
-    _sc = None
-
-    def __init__(self, hg: HaloGeometry, tile: int, bn: int):
-        self.hg, self.t, self.bn = hg, tile, bn
-        self.stage_shape = (bn, hg.bth * tile, hg.btw * tile)
-
-    def dst(self, y_hbm, n_idx, p_idx):
-        hg, t = self.hg, self.t
-        nb = hg.n_blocks
-        b = p_idx // nb
-        ib = (p_idx % nb) // hg.nbw
-        jb = p_idx % hg.nbw
-        return y_hbm.at[b, pl.ds(n_idx * self.bn, self.bn),
-                        pl.ds(ib * hg.bth * t, hg.bth * t),
-                        pl.ds(jb * hg.btw * t, hg.btw * t)]
-
-    def stage(self, y):
-        hg, t, bn = self.hg, self.t, self.bn
-        # [t^2, bn, bth*btw] -> (u, v, n, ith, jtw) -> canvas rows
-        # ith*t + u, cols jtw*t + v (tile axis is bth-major, matching
-        # _halo_windows; s2 rows are u-major, matching the dv operator).
-        y = y.reshape(t, t, bn, hg.bth, hg.btw)
-        y = y.transpose(2, 3, 0, 4, 1)
-        return y.reshape(bn, hg.bth * t, hg.btw * t)
-
-    def epilogue(self, y, b_ref, relu: bool):
-        y = y + b_ref[0][:, None, None]
-        if self._sc is not None:
-            y = y + self._sc[0]
-        if relu:
-            y = jnp.maximum(y, 0.0)
-        return y
-
-
-def _residual_kernel(body, sink):
-    """Wrap a flow kernel body so a residual-shortcut operand — the
-    LEADING input ref, laid out exactly like the output — is peeled off
-    and attached to the sink before the body runs.  The sink's epilogue
-    then adds the shortcut block after bias and before ReLU, so the
-    residual add costs one extra VMEM operand on the flush path and
-    nothing anywhere else (the six flow bodies are untouched).  Composes
-    outside ``_halo_kernel``: pallas hands (sc, x, gr, gc, ...) and each
-    wrapper peels from the front."""
-    def kernel(sc_ref, *rest):
-        sink._sc = sc_ref
-        return body(*rest)
-    return kernel
 
 
 def _dma_slot():
@@ -486,67 +333,75 @@ def _dma_slot():
 
 def _dma_rmw_start(dst, acc, sem, slot, gm):
     """RMW prologue: start the inbound accumulator DMA for a revisit
-    step.  Called BEFORE the step's FFT/Hadamard/IFFT compute, which
-    does not depend on it — the copy-in overlaps the MXU work and
-    ``_dma_rmw_finish`` waits on it only at accumulation time."""
+    step, BEFORE the step's compute — the copy-in overlaps the MXU work
+    and ``_dma_rmw_finish`` waits on it only at accumulation time."""
     @pl.when(gm > 0)
     def _prefetch():
         pltpu.make_async_copy(dst, acc.at[slot], sem.at[slot]).start()
 
 
-def _dma_rmw_finish(sink, dst, acc, sem, y, b_ref, *, slot, gm,
-                    n_m_blocks: int, relu: bool):
-    """Spatial-psum RMW across the m grid axis through the manual-DMA
-    accumulator: first visit writes, middle visits add + write back,
-    the final visit applies the epilogue.  Write-backs complete before
-    the step ends, so a revisit (any number of grid steps later — the
-    revisit no longer needs to be consecutive) always reads finished
-    data."""
-    def write_back():
-        cp = pltpu.make_async_copy(acc.at[slot], dst, sem.at[slot])
-        cp.start()
-        cp.wait()
-
-    if n_m_blocks == 1:
-        acc[slot] = sink.epilogue(sink.stage(y), b_ref, relu)
-        write_back()
-        return
-    last = n_m_blocks - 1
-
-    @pl.when(gm == 0)
-    def _first():
-        acc[slot] = sink.stage(y)
-        write_back()
-
-    @pl.when((gm > 0) & (gm < last))
-    def _mid():
-        pltpu.make_async_copy(dst, acc.at[slot], sem.at[slot]).wait()
-        acc[slot] += sink.stage(y)
-        write_back()
-
-    @pl.when(gm == last)
-    def _last():
-        pltpu.make_async_copy(dst, acc.at[slot], sem.at[slot]).wait()
-        acc[slot] = sink.epilogue(acc[slot] + sink.stage(y), b_ref, relu)
-        write_back()
+def _stage(acc, slot, ys, fn):
+    """Write ``fn(c, y_c)`` into lane chunk c of staging slot ``slot``."""
+    for c, y in enumerate(ys):
+        acc[slot, :, :, _lanes(c)] = fn(c, y)
 
 
-def _dma_flush(sink, dst, acc, sem, y, b_ref, *, slot, relu: bool):
-    """Output-stationary flush: one staged + epilogued write per
-    rectangle, at the last m visit (psums accumulated in spectral
-    scratch, not through HBM)."""
-    acc[slot] = sink.epilogue(sink.stage(y), b_ref, relu)
+def _write_back(dst, acc, sem, slot):
     cp = pltpu.make_async_copy(acc.at[slot], dst, sem.at[slot])
     cp.start()
     cp.wait()
 
 
-def _kernel_os(x_ref, wr_ref, wi_ref, dfr_ref, dfi_ref, dvr_ref, dvi_ref,
-               b_ref, y_hbm, acc_r, acc_i, ydma, sem, *,
-               n_m_blocks: int, relu: bool, sink):
-    """Output-stationary, grid (n, p, m): psums live in VMEM scratch
-    across the innermost m grid dim; IFFT + epilogue + the single DMA
-    write happen once, at the last m block."""
+def _dma_rmw_finish(sink, dst, acc, sem, ys, b_ref, *, slot, gm,
+                    n_m_blocks: int, relu: bool):
+    """Spatial-psum RMW across the m grid axis through the manual-DMA
+    accumulator (``ys``: the step's [S2, bn, LANES] chunks): first
+    visit writes, middle visits add + write back, the final visit
+    applies the epilogue.  Write-backs complete before the step ends,
+    so a later revisit always reads finished data."""
+    old = lambda c: acc[slot, :, :, _lanes(c)]
+    if n_m_blocks == 1:
+        _stage(acc, slot, ys,
+               lambda c, y: sink.epilogue(y, b_ref, relu, c))
+        _write_back(dst, acc, sem, slot)
+        return
+    last = n_m_blocks - 1
+
+    @pl.when(gm == 0)
+    def _first():
+        _stage(acc, slot, ys, lambda c, y: y)
+        _write_back(dst, acc, sem, slot)
+
+    @pl.when((gm > 0) & (gm < last))
+    def _mid():
+        pltpu.make_async_copy(dst, acc.at[slot], sem.at[slot]).wait()
+        _stage(acc, slot, ys, lambda c, y: old(c) + y)
+        _write_back(dst, acc, sem, slot)
+
+    @pl.when(gm == last)
+    def _last():
+        pltpu.make_async_copy(dst, acc.at[slot], sem.at[slot]).wait()
+        _stage(acc, slot, ys,
+               lambda c, y: sink.epilogue(old(c) + y, b_ref, relu, c))
+        _write_back(dst, acc, sem, slot)
+
+
+def _dma_flush(sink, dst, acc, sem, ys, b_ref, *, slot, relu: bool):
+    """Output-stationary flush: one epilogued write per rectangle."""
+    _stage(acc, slot, ys, lambda c, y: sink.epilogue(y, b_ref, relu, c))
+    _write_back(dst, acc, sem, slot)
+
+
+# ---------------------------------------------------------------------------
+# Flow bodies
+# ---------------------------------------------------------------------------
+
+def _body_os(xs, ws, ops, b_ref, y_hbm, acc_r, acc_i, ydma, sem, *,
+             n_m_blocks: int, relu: bool, sink):
+    """Output-stationary, grid (n, p, m): spectral psums live in VMEM
+    scratch across the innermost m grid dim; IFFT + epilogue + the
+    single DMA write happen once, at the last m block."""
+    dfr, dfi, dvr, dvi = ops
     gm = pl.program_id(2)
     slot = _dma_slot()
     dst = sink.dst(y_hbm, pl.program_id(0), pl.program_id(1))
@@ -556,44 +411,45 @@ def _kernel_os(x_ref, wr_ref, wi_ref, dfr_ref, dfi_ref, dvr_ref, dvi_ref,
         acc_r[...] = jnp.zeros_like(acc_r)
         acc_i[...] = jnp.zeros_like(acc_i)
 
-    re, im = _hadamard(wr_ref, wi_ref,
-                       *_tile_fft(x_ref, dfr_ref, dfi_ref))
-    acc_r[...] += re
-    acc_i[...] += im
+    wr, wi = ws.read()
+    for c, x in enumerate(xs.chunks()):
+        re, im = _hadamard(wr, wi, *_tile_fft(x, dfr[...], dfi[...]),
+                           w_mn=ws.w_mn)
+        acc_r[:, :, _lanes(c)] += re
+        acc_i[:, :, _lanes(c)] += im
 
     @pl.when(gm == n_m_blocks - 1)
     def _flush():
-        bn, bp = acc_r.shape[1], acc_r.shape[2]
-        y = _ifft_real(acc_r[...], acc_i[...], dvr_ref, dvi_ref, bn, bp)
-        _dma_flush(sink, dst, ydma, sem, y, b_ref, slot=slot, relu=relu)
+        ys = [_ifft_real(acc_r[:, :, _lanes(c)], acc_i[:, :, _lanes(c)],
+                         dvr[...], dvi[...])
+              for c in range(acc_r.shape[2] // LANES)]
+        _dma_flush(sink, dst, ydma, sem, ys, b_ref, slot=slot, relu=relu)
 
 
-def _kernel_ws(x_ref, wr_ref, wi_ref, dfr_ref, dfi_ref, dvr_ref, dvi_ref,
-               b_ref, y_hbm, ydma, sem, *, n_m_blocks: int, relu: bool,
-               sink):
+def _body_ws(xs, ws, ops, b_ref, y_hbm, ydma, sem, *, n_m_blocks: int,
+             relu: bool, sink):
     """Weight-stationary, grid (n, m, p): each m block's partial Y~ is
-    IFFT'd eagerly (IFFT is linear) and the real spatial psum is read-
-    modify-written through the manual-DMA accumulator — spectral
-    intermediates never reach HBM.  The epilogue fires on the final m
-    visit, after the last accumulation."""
+    IFFT'd eagerly and the real spatial psum is read-modify-written
+    through the manual-DMA accumulator."""
+    dfr, dfi, dvr, dvi = ops
     gm = pl.program_id(1)
     slot = _dma_slot()
     dst = sink.dst(y_hbm, pl.program_id(0), pl.program_id(2))
     _dma_rmw_start(dst, ydma, sem, slot, gm)
-    re, im = _hadamard(wr_ref, wi_ref,
-                       *_tile_fft(x_ref, dfr_ref, dfi_ref))
-    bn, bp = re.shape[1], re.shape[2]
-    y = _ifft_real(re, im, dvr_ref, dvi_ref, bn, bp)
-    _dma_rmw_finish(sink, dst, ydma, sem, y, b_ref, slot=slot, gm=gm,
+    wr, wi = ws.read()
+    ys = [_ifft_real(*_hadamard(wr, wi, *_tile_fft(x, dfr[...], dfi[...]),
+                                w_mn=ws.w_mn), dvr[...], dvi[...])
+          for x in xs.chunks()]
+    _dma_rmw_finish(sink, dst, ydma, sem, ys, b_ref, slot=slot, gm=gm,
                     n_m_blocks=n_m_blocks, relu=relu)
 
 
-def _kernel_is(x_ref, wr_ref, wi_ref, dfr_ref, dfi_ref, dvr_ref, dvi_ref,
-               b_ref, y_hbm, xfr_s, xfi_s, ydma, sem, *,
-               n_m_blocks: int, relu: bool, sink):
+def _body_is(xs, ws, ops, b_ref, y_hbm, xfr_s, xfi_s, ydma, sem, *,
+             n_m_blocks: int, relu: bool, sink):
     """Input-stationary, grid (p, m, n): the window block is constant
     across the inner n loop, so its FFT is computed once (n-block 0)
     into VMEM scratch and reused — the reuse the flow is named for."""
+    dfr, dfi, dvr, dvi = ops
     gm = pl.program_id(1)
     gn = pl.program_id(2)
     slot = _dma_slot()
@@ -602,97 +458,23 @@ def _kernel_is(x_ref, wr_ref, wi_ref, dfr_ref, dfi_ref, dvr_ref, dvi_ref,
 
     @pl.when(gn == 0)
     def _fft_once():
-        xfr, xfi = _tile_fft(x_ref, dfr_ref, dfi_ref)
-        xfr_s[...] = xfr
-        xfi_s[...] = xfi
+        for c, x in enumerate(xs.chunks()):
+            xfr, xfi = _tile_fft(x, dfr[...], dfi[...])
+            xfr_s[:, :, _lanes(c)] = xfr
+            xfi_s[:, :, _lanes(c)] = xfi
 
-    re, im = _hadamard(wr_ref, wi_ref, xfr_s[...], xfi_s[...])
-    bn, bp = re.shape[1], re.shape[2]
-    y = _ifft_real(re, im, dvr_ref, dvi_ref, bn, bp)
-    _dma_rmw_finish(sink, dst, ydma, sem, y, b_ref, slot=slot, gm=gm,
+    wr, wi = ws.read()
+    ys = [_ifft_real(*_hadamard(wr, wi, xfr_s[:, :, _lanes(c)],
+                                xfi_s[:, :, _lanes(c)], w_mn=ws.w_mn),
+                     dvr[...], dvi[...])
+          for c in range(xfr_s.shape[2] // LANES)]
+    _dma_rmw_finish(sink, dst, ydma, sem, ys, b_ref, slot=slot, gm=gm,
                     n_m_blocks=n_m_blocks, relu=relu)
 
 
-def _kernel_os_sched(x_ref, idx_ref, sel_ref, vr_ref, vi_ref,
-                     dfr_ref, dfi_ref, dvr_ref, dvi_ref, b_ref, y_hbm,
-                     acc_r, acc_i, ydma, sem, *, n_m_blocks: int,
-                     relu: bool, sink):
-    """Output-stationary, scheduled Hadamard: n-leading psums [N', Fa, bp]
-    accumulate in VMEM scratch across the m grid dim."""
-    gm = pl.program_id(2)
-    slot = _dma_slot()
-    dst = sink.dst(y_hbm, pl.program_id(0), pl.program_id(1))
-
-    @pl.when(gm == 0)
-    def _init():
-        acc_r[...] = jnp.zeros_like(acc_r)
-        acc_i[...] = jnp.zeros_like(acc_i)
-
-    re, im = _scheduled_hadamard(idx_ref, sel_ref, vr_ref, vi_ref,
-                                 *_tile_fft(x_ref, dfr_ref, dfi_ref))
-    acc_r[...] += re
-    acc_i[...] += im
-
-    @pl.when(gm == n_m_blocks - 1)
-    def _flush():
-        y = _ifft_real_nf(acc_r[...], acc_i[...], dvr_ref, dvi_ref)
-        _dma_flush(sink, dst, ydma, sem, y, b_ref, slot=slot, relu=relu)
-
-
-def _kernel_ws_sched(x_ref, idx_ref, sel_ref, vr_ref, vi_ref,
-                     dfr_ref, dfi_ref, dvr_ref, dvi_ref, b_ref, y_hbm,
-                     ydma, sem, *, n_m_blocks: int, relu: bool, sink):
-    """Weight-stationary, scheduled Hadamard: the table block (the
-    'kernel' operand of this mode) is constant across the inner p loop;
-    partial psums are IFFT'd eagerly and RMW'd as spatial rows."""
-    gm = pl.program_id(1)
-    slot = _dma_slot()
-    dst = sink.dst(y_hbm, pl.program_id(0), pl.program_id(2))
-    _dma_rmw_start(dst, ydma, sem, slot, gm)
-    re, im = _scheduled_hadamard(idx_ref, sel_ref, vr_ref, vi_ref,
-                                 *_tile_fft(x_ref, dfr_ref, dfi_ref))
-    y = _ifft_real_nf(re, im, dvr_ref, dvi_ref)
-    _dma_rmw_finish(sink, dst, ydma, sem, y, b_ref, slot=slot, gm=gm,
-                    n_m_blocks=n_m_blocks, relu=relu)
-
-
-def _kernel_is_sched(x_ref, idx_ref, sel_ref, vr_ref, vi_ref,
-                     dfr_ref, dfi_ref, dvr_ref, dvi_ref, b_ref, y_hbm,
-                     xfr_s, xfi_s, ydma, sem, *, n_m_blocks: int,
-                     relu: bool, sink):
-    """Input-stationary, scheduled Hadamard: the window block's FFT is
-    computed once (n-block 0) into VMEM scratch and reused while table
-    blocks re-stream."""
-    gm = pl.program_id(1)
-    gn = pl.program_id(2)
-    slot = _dma_slot()
-    dst = sink.dst(y_hbm, gn, pl.program_id(0))
-    _dma_rmw_start(dst, ydma, sem, slot, gm)
-
-    @pl.when(gn == 0)
-    def _fft_once():
-        xfr, xfi = _tile_fft(x_ref, dfr_ref, dfi_ref)
-        xfr_s[...] = xfr
-        xfi_s[...] = xfi
-
-    re, im = _scheduled_hadamard(idx_ref, sel_ref, vr_ref, vi_ref,
-                                 xfr_s[...], xfi_s[...])
-    y = _ifft_real_nf(re, im, dvr_ref, dvi_ref)
-    _dma_rmw_finish(sink, dst, ydma, sem, y, b_ref, slot=slot, gm=gm,
-                    n_m_blocks=n_m_blocks, relu=relu)
-
-
-# ---------------------------------------------------------------------------
-# pallas_call wrapper
-# ---------------------------------------------------------------------------
-
-def _pad_axis(x: Array, axis: int, mult: int) -> Array:
-    rem = (-x.shape[axis]) % mult
-    if rem == 0:
-        return x
-    pad = [(0, 0)] * x.ndim
-    pad[axis] = (0, rem)
-    return jnp.pad(x, pad)
+_BODIES = {"output_stationary": _body_os,
+           "weight_stationary": _body_ws,
+           "input_stationary": _body_is}
 
 
 def _flow_layout(flow: str, gn: int, gm: int, gp: int):
@@ -718,498 +500,83 @@ def _flow_layout(flow: str, gn: int, gm: int, gp: int):
     return grid, canon, semantics
 
 
-def _const_spec(rows: int, cols: int) -> pl.BlockSpec:
-    """Whole-array BlockSpec for the VMEM-resident DFT operators."""
-    return pl.BlockSpec((rows, cols), lambda *_: (0, 0))
+# ---------------------------------------------------------------------------
+# pallas_call builder
+# ---------------------------------------------------------------------------
+
+def _pad_axis(x: Array, axis: int, mult: int) -> Array:
+    rem = (-x.shape[axis]) % mult
+    if rem == 0:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, rem)
+    return jnp.pad(x, pad)
 
 
-def _dma_scratch(sink):
-    """The manual-DMA output scratch every fused kernel appends: the
-    DMA_SLOTS double-buffered staging tiles (in the sink's output
-    layout) and their DMA-completion semaphores."""
-    return [pltpu.VMEM((DMA_SLOTS,) + sink.stage_shape, jnp.float32),
-            pltpu.SemaphoreType.DMA((DMA_SLOTS,))]
+def _fused_call(x_src, x_ops, x_specs, w_src, w_ops, w_specs, ops,
+                bias_col, shortcut, *, flow, grid_counts, canon_layout,
+                bn, bm, bp, np_, pp_, relu, interpret):
+    """One pallas_call of the fused conv.  ``x_src``/``w_src`` are the
+    window / kernel-plane source factories (bound to their static
+    geometry), ``*_ops``/``*_specs`` their operands and BlockSpecs;
+    ``shortcut`` is an optional [S2, np_, pp_] operand in the output
+    layout.  Returns the [S2, np_, pp_] tile stream."""
+    gn, gm, gp = grid_counts
+    grid, canon, semantics = canon_layout
+    fa, s = ops[0].shape
+    s2 = ops[2].shape[0]
+    n_x, n_w = (getattr(src, "func", src).n_refs for src in (x_src, w_src))
+    has_sc = shortcut is not None
 
+    def kernel(*refs):
+        sc, refs = (refs[0], refs[1:]) if has_sc else (None, refs)
+        xs = x_src(refs[:n_x])
+        ws = w_src(refs[n_x:n_x + n_w])
+        rest = refs[n_x + n_w:]
+        ops_refs, b_ref, y_hbm, scratch = rest[:4], rest[4], rest[5], \
+            rest[6:]
+        _BODIES[flow](xs, ws, ops_refs, b_ref, y_hbm, *scratch,
+                      n_m_blocks=gm, relu=relu,
+                      sink=_TileSink(bn, bp, sc))
 
-def _plane_kernel_scratch(flow: str, gm: int, relu: bool, fa: int,
-                          bn: int, bm: int, bp: int, sink, wrap=None):
-    """(kernel, scratch_shapes) of one flow's plane-Hadamard body —
-    shared by the windowed and halo pipeline builders (``wrap`` is the
-    halo gather applied around the body when given)."""
-    body = {"output_stationary": _kernel_os,
-            "weight_stationary": _kernel_ws,
-            "input_stationary": _kernel_is}[flow]
-    kernel = functools.partial(body, n_m_blocks=gm, relu=relu, sink=sink)
-    if wrap is not None:
-        kernel = wrap(kernel)
+    const = lambda r, c: pl.BlockSpec((r, c), lambda *_: (0, 0))
+    in_specs = (list(x_specs) + list(w_specs)
+                + [const(fa, s), const(fa, s), const(s2, fa),
+                   const(s2, fa),
+                   pl.BlockSpec((bn, 1), lambda *g: (canon(*g)[0], 0))])
+    operands = list(x_ops) + list(w_ops) + list(ops) + [bias_col]
+    if has_sc:
+        in_specs = [pl.BlockSpec(
+            (s2, bn, bp), lambda *g: (0, canon(*g)[0], canon(*g)[1]))
+        ] + in_specs
+        operands = [shortcut] + operands
     scratch = {"output_stationary": [pltpu.VMEM((fa, bn, bp),
                                                 jnp.float32)] * 2,
                "weight_stationary": [],
                "input_stationary": [pltpu.VMEM((fa, bm, bp),
                                                jnp.float32)] * 2}[flow]
-    return kernel, scratch + _dma_scratch(sink)
-
-
-def _sched_kernel_scratch(flow: str, gm: int, relu: bool, fa: int,
-                          n_pe: int, bm: int, bp: int, sink, wrap=None):
-    """Scheduled-Hadamard sibling of ``_plane_kernel_scratch`` (the
-    output-stationary psums are n-leading [N', Fa, bp])."""
-    body = {"output_stationary": _kernel_os_sched,
-            "weight_stationary": _kernel_ws_sched,
-            "input_stationary": _kernel_is_sched}[flow]
-    kernel = functools.partial(body, n_m_blocks=gm, relu=relu, sink=sink)
-    if wrap is not None:
-        kernel = wrap(kernel)
-    scratch = {"output_stationary": [pltpu.VMEM((n_pe, fa, bp),
-                                                jnp.float32)] * 2,
-               "weight_stationary": [],
-               "input_stationary": [pltpu.VMEM((fa, bm, bp),
-                                               jnp.float32)] * 2}[flow]
-    return kernel, scratch + _dma_scratch(sink)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("flow", "block_n", "block_m", "block_p", "relu",
-                     "interpret"))
-def fused_spectral_pipeline(xt: Array, wr: Array, wi: Array,
-                            dfr: Array, dfi: Array,
-                            dvr: Array, dvi: Array, bias: Array, *,
-                            flow: str = "output_stationary",
-                            block_n: int = 64, block_m: int = 64,
-                            block_p: int = 128, relu: bool = False,
-                            interpret: bool = True,
-                            shortcut: Array | None = None) -> Array:
-    """FFT -> Hadamard -> IFFT (+ bias/ReLU epilogue) in one pallas_call.
-
-    xt: [S, M, P] f32     overlap-save windows, s-leading (S = K^2,
-                          P = B*T)
-    wr/wi: [Fa, N, M] f32 spectral kernel planes on active bins
-    dfr/dfi: [Fa, S]      forward DFT rows (active bins)
-    dvr/dvi: [S2, Fa]     inverse DFT, valid rows x active columns
-                          (S2 = t^2)
-    bias: [1, N] f32      per-output-channel bias (zeros disable)
-    shortcut: optional [S2, N, P] f32 residual operand in the OUTPUT
-        tile layout (``_shortcut_tiles`` relayout of the producer's
-        activation): one extra input streamed on the flush path and
-        added after bias, before ReLU, inside the kernel.
-    returns [S2, N, P] f32 finished spatial outputs (epilogue applied).
-    """
-    if flow not in FLOWS:
-        raise ValueError(f"flow must be one of {FLOWS}")
-    s, m, p = xt.shape
-    fa, n, _ = wr.shape
-    s2 = dvr.shape[0]
-    assert dfr.shape == (fa, s) and dvr.shape == (s2, fa), \
-        (dfr.shape, dvr.shape, (fa, s, s2))
-    assert bias.shape == (1, n), (bias.shape, n)
-
-    bn, bm, bp = min(block_n, n), min(block_m, m), min(block_p, p)
-    xt_ = _pad_axis(_pad_axis(xt, 1, bm), 2, bp)
-    wr_ = _pad_axis(_pad_axis(wr, 1, bn), 2, bm)
-    wi_ = _pad_axis(_pad_axis(wi, 1, bn), 2, bm)
-    bias_ = _pad_axis(bias, 1, bn)
-    np_, mp_, pp_ = wr_.shape[1], wr_.shape[2], xt_.shape[2]
-    gn, gm, gp = np_ // bn, mp_ // bm, pp_ // bp
-    grid, canon, semantics = _flow_layout(flow, gn, gm, gp)
-    sink = _TileSink(s2, bn, bp)
-    kernel, scratch = _plane_kernel_scratch(flow, gm, relu, fa, bn, bm,
-                                            bp, sink)
-
-    x_spec = pl.BlockSpec(
-        (s, bm, bp), lambda *g: (0, canon(*g)[2], canon(*g)[1]))
-    w_spec = pl.BlockSpec(
-        (fa, bn, bm), lambda *g: (0, canon(*g)[0], canon(*g)[2]))
-    b_spec = pl.BlockSpec((1, bn), lambda *g: (0, canon(*g)[0]))
-
-    in_specs = [x_spec, w_spec, w_spec,
-                _const_spec(fa, s), _const_spec(fa, s),
-                _const_spec(s2, fa), _const_spec(s2, fa), b_spec]
-    operands = [xt_.astype(jnp.float32), wr_, wi_, dfr, dfi, dvr, dvi,
-                bias_]
-    if shortcut is not None:
-        assert shortcut.shape == (s2, n, p), (shortcut.shape, (s2, n, p))
-        kernel = _residual_kernel(kernel, sink)
-        sc_spec = pl.BlockSpec(
-            (s2, bn, bp), lambda *g: (0, canon(*g)[0], canon(*g)[1]))
-        in_specs = [sc_spec] + in_specs
-        operands = [_pad_axis(_pad_axis(shortcut.astype(jnp.float32),
-                                        1, bn), 2, bp)] + operands
-
-    y = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((s2, np_, pp_), jnp.float32),
-        scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=semantics),
-        interpret=interpret,
-    )(*operands)
-    return y[:, :n, :p]
-
-
-def _halo_specs(geo: SpectralGeometry, hg: HaloGeometry, bm: int, canon):
-    """(x, gr, gc) BlockSpecs of the halo input path.
-
-    The x spec uses element-offset (``pl.Unblocked``) indexing: the p
-    grid axis enumerates (image, block-row, block-col) and the offset
-    formula is the traced twin of ``spectral.halo_block_starts`` —
-    consecutive blocks' reads overlap by the k-1 halo, clamped at the
-    image edges.  gr/gc stream the block's one-hot window selectors
-    (standard blocked indexing on their leading block axis)."""
-    t, ov = geo.tile, geo.ksize - 1
-    nb = hg.n_blocks
-    h_hi, w_hi = geo.h_in - hg.rh, geo.w_in - hg.rw
-
-    def decomp(p):
-        return p // nb, (p % nb) // hg.nbw, p % hg.nbw
-
-    def x_idx(*g):
-        _, p, m = canon(*g)
-        b, ib, jb = decomp(p)
-        # + pre_halo_h: sharded bands carry their top halo in-buffer,
-        # shifting every H-axis block start down by the halo rows
-        # (traced twin of spectral.halo_block_starts).
-        return (b, m * bm,
-                jnp.clip(ib * hg.bth * t - ov + geo.pre_halo_h, 0, h_hi),
-                jnp.clip(jb * hg.btw * t - ov, 0, w_hi))
-
-    x_spec = pl.BlockSpec((1, bm, hg.rh, hg.rw), x_idx,
-                          indexing_mode=pl.Unblocked())
-    gr_spec = pl.BlockSpec(
-        (1, hg.bth * geo.fft_size, hg.rh),
-        lambda *g: (decomp(canon(*g)[1])[1], 0, 0))
-    gc_spec = pl.BlockSpec(
-        (1, hg.btw * geo.fft_size, hg.rw),
-        lambda *g: (decomp(canon(*g)[1])[2], 0, 0))
-    return x_spec, gr_spec, gc_spec
-
-
-def _canvas_sc_spec(hg: HaloGeometry, bn: int, tile: int, canon):
-    """BlockSpec of the halo path's shortcut operand: the output-canvas
-    rectangle of the current (n, p) grid position — the same (image,
-    block-row, block-col) decomposition ``_CanvasSink.dst`` uses, as a
-    blocked index map."""
-    nb = hg.n_blocks
-
-    def sc_idx(*g):
-        n_, p, _ = canon(*g)
-        return (p // nb, n_, (p % nb) // hg.nbw, p % hg.nbw)
-
-    return pl.BlockSpec((1, bn, hg.bth * tile, hg.btw * tile), sc_idx)
-
-
-def _shortcut_canvas(sc: Array, geo: SpectralGeometry, hg: HaloGeometry,
-                     bn: int) -> Array:
-    """RAW [B, N, H_out, W_out] shortcut -> the halo pipeline's output
-    canvas layout [B, Np, nbh*bth*t, nbw*btw*t]: the valid 'same'-crop
-    window of the canvas holds the shortcut, everything else is zero
-    (those canvas positions are wraparound garbage and are cropped by
-    ``_crop_canvas`` anyway)."""
-    b, n, h, w = sc.shape
-    t = geo.tile
-    start = geo.ksize - 1 - geo.pad
-    canvas = jnp.zeros((b, n, hg.nbh * hg.bth * t, hg.nbw * hg.btw * t),
-                       jnp.float32)
-    canvas = canvas.at[:, :, start:start + h,
-                       start:start + w].set(sc.astype(jnp.float32))
-    return _pad_axis(canvas, 1, bn)
-
-
-def _shortcut_tiles(sc: Array, geo: SpectralGeometry, t_cnt: int) -> Array:
-    """RAW [B, N, H_out, W_out] shortcut -> the windowed pipelines'
-    output tile layout [S2, N, B*T] (the exact inverse of
-    ``_assemble_output``): embed into valid-tile canvas coordinates,
-    split into t x t tiles, u-major rows."""
-    b, n, h, w = sc.shape
-    t = geo.tile
-    start = geo.ksize - 1 - geo.pad
-    canvas = jnp.zeros((b, n, geo.n_tiles_h * t, geo.n_tiles_w * t),
-                       jnp.float32)
-    canvas = canvas.at[:, :, start:start + h,
-                       start:start + w].set(sc.astype(jnp.float32))
-    tiles = (canvas.reshape(b, n, geo.n_tiles_h, t, geo.n_tiles_w, t)
-             .transpose(0, 1, 2, 4, 3, 5)        # [b, n, ith, jtw, u, v]
-             .reshape(b, n, t_cnt, t * t))
-    return tiles.transpose(3, 1, 0, 2).reshape(t * t, n, b * t_cnt)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "hg", "flow", "block_n", "block_m", "relu",
-                     "interpret"))
-def fused_spectral_pipeline_halo(x: Array, wr: Array, wi: Array,
-                                 dfr: Array, dfi: Array,
-                                 dvr: Array, dvi: Array, bias: Array, *,
-                                 geo: SpectralGeometry, hg: HaloGeometry,
-                                 flow: str = "output_stationary",
-                                 block_n: int = 64, block_m: int = 64,
-                                 relu: bool = False,
-                                 interpret: bool = True,
-                                 shortcut: Array | None = None) -> Array:
-    """The halo-input sibling of ``fused_spectral_pipeline``: gather ->
-    FFT -> Hadamard -> IFFT (+ epilogue) in one pallas_call, reading the
-    RAW activation.  ``shortcut`` is an optional RAW [B, N, H_out,
-    W_out] residual operand, embedded into the output-canvas layout
-    host-side and streamed as one extra flush-path input (added after
-    bias, before ReLU, in-kernel).
-
-    x: [B, M, H, W] f32      raw NCHW activation (no windowing, no
-                             padding — the gather encodes both)
-    wr/wi/dfr/dfi/dvr/dvi/bias: as ``fused_spectral_pipeline``.
-    geo/hg: tile + halo-block geometry (``halo_block_geometry``); the
-        effective block_p is ``hg.block_tiles`` and the p grid axis is
-        B * hg.n_blocks.
-    Returns the assembled spatial output canvas
-    [B, Np, nbh*bth*t, nbw*btw*t] (Np = N padded to block_n): the
-    kernel's flush re-lays each finished tile rectangle into canvas
-    position in VMEM and DMAs it there directly, so the only host-side
-    work left is the 'same'-crop slice (``_crop_canvas``) —
-    ``assemble_valid_tiles`` never runs on this path.
-    """
-    if flow not in FLOWS:
-        raise ValueError(f"flow must be one of {FLOWS}")
-    b, m, h, w_px = x.shape
-    assert (h, w_px) == (geo.h_in, geo.w_in), (x.shape, geo)
-    fa, n, _ = wr.shape
-    s = geo.fft_size * geo.fft_size
-    s2 = dvr.shape[0]
-    assert dfr.shape == (fa, s) and dvr.shape == (s2, fa), \
-        (dfr.shape, dvr.shape, (fa, s, s2))
-    assert bias.shape == (1, n), (bias.shape, n)
-
-    bt = hg.block_tiles
-    bn, bm = min(block_n, n), min(block_m, m)
-    x_ = _pad_axis(x, 1, bm)
-    wr_ = _pad_axis(_pad_axis(wr, 1, bn), 2, bm)
-    wi_ = _pad_axis(_pad_axis(wi, 1, bn), 2, bm)
-    bias_ = _pad_axis(bias, 1, bn)
-    np_, mp_ = wr_.shape[1], wr_.shape[2]
-    gn, gm, gp = np_ // bn, mp_ // bm, b * hg.n_blocks
-    grid, canon, semantics = _flow_layout(flow, gn, gm, gp)
-    gr, gc = (jnp.asarray(a) for a in halo_gather_matrices(geo, hg))
-    wrap = functools.partial(_halo_kernel, bth=hg.bth, btw=hg.btw,
-                             fft_size=geo.fft_size)
-    sink = _CanvasSink(hg, geo.tile, bn)
-    kernel, scratch = _plane_kernel_scratch(flow, gm, relu, fa, bn, bm,
-                                            bt, sink, wrap=wrap)
-
-    x_spec, gr_spec, gc_spec = _halo_specs(geo, hg, bm, canon)
-    w_spec = pl.BlockSpec(
-        (fa, bn, bm), lambda *g: (0, canon(*g)[0], canon(*g)[2]))
-    b_spec = pl.BlockSpec((1, bn), lambda *g: (0, canon(*g)[0]))
-
-    canvas = (b, np_, hg.nbh * hg.bth * geo.tile,
-              hg.nbw * hg.btw * geo.tile)
-    in_specs = [x_spec, gr_spec, gc_spec, w_spec, w_spec,
-                _const_spec(fa, s), _const_spec(fa, s),
-                _const_spec(s2, fa), _const_spec(s2, fa), b_spec]
-    operands = [x_.astype(jnp.float32), gr, gc, wr_, wi_, dfr, dfi, dvr,
-                dvi, bias_]
-    if shortcut is not None:
-        kernel = _residual_kernel(kernel, sink)
-        in_specs = [_canvas_sc_spec(hg, bn, geo.tile, canon)] + in_specs
-        operands = [_shortcut_canvas(shortcut, geo, hg, bn)] + operands
+    scratch = scratch + [pltpu.VMEM((DMA_SLOTS, s2, bn, bp), jnp.float32),
+                         pltpu.SemaphoreType.DMA((DMA_SLOTS,))]
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct(canvas, jnp.float32),
-        scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=semantics),
-        interpret=interpret,
-    )(*operands)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "hg", "n_out", "flow", "block_m", "relu",
-                     "interpret"))
-def fused_spectral_pipeline_scheduled_halo(
-        x: Array, idx: Array, sel: Array, vr: Array, vi: Array,
-        dfr: Array, dfi: Array, dvr: Array, dvi: Array, bias: Array, *,
-        geo: SpectralGeometry, hg: HaloGeometry, n_out: int,
-        flow: str = "output_stationary", block_m: int = 64,
-        relu: bool = False, interpret: bool = True,
-        shortcut: Array | None = None) -> Array:
-    """Halo-input sibling of ``fused_spectral_pipeline_scheduled``: the
-    in-kernel window gather feeding the Alg-2 scheduled datapath.
-    ``shortcut``: optional RAW [B, N, H_out, W_out] residual operand
-    (see ``fused_spectral_pipeline_halo``).
-    Operand contracts are the scheduled pipeline's (tables padded for
-    ``m_pad_to == min(block_m, M)``, block_n implied == N'), except the
-    input is the raw [B, M, H, W] activation and the output is the
-    assembled spatial canvas [B, GN*N', nbh*bth*t, nbw*btw*t] (see
-    ``fused_spectral_pipeline_halo``)."""
-    b, m, h, w_px = x.shape
-    assert (h, w_px) == (geo.h_in, geo.w_in), (x.shape, geo)
-    gn, mp_t, t_cycles, r = idx.shape
-    n_pe = sel.shape[3]
-    fa = dfr.shape[0]
-    s = geo.fft_size * geo.fft_size
-    s2 = dvr.shape[0]
-    assert sel.shape == (gn, mp_t, t_cycles, n_pe), (sel.shape, idx.shape)
-    assert vr.shape == sel.shape and vi.shape == sel.shape
-    assert n_out <= gn * n_pe, (n_out, gn, n_pe)
-    assert bias.shape == (1, n_out), (bias.shape, n_out)
-
-    bt = hg.block_tiles
-    bm = min(block_m, m)
-    x_ = _pad_axis(x, 1, bm)
-    bias_ = _pad_axis(bias, 1, n_pe)
-    mp_ = x_.shape[1]
-    assert mp_ == mp_t, \
-        (f"tables padded for {mp_t} channels but raw input pads to "
-         f"{mp_}; compile_layer_tables(m_pad_to=block_m) must use the "
-         f"same block_m (= {bm})")
-    np_ = gn * n_pe
-    gm, gp = mp_ // bm, b * hg.n_blocks
-    grid, canon, semantics = _flow_layout(flow, gn, gm, gp)
-    gr, gc = (jnp.asarray(a) for a in halo_gather_matrices(geo, hg))
-    wrap = functools.partial(_halo_kernel, bth=hg.bth, btw=hg.btw,
-                             fft_size=geo.fft_size)
-    sink = _CanvasSink(hg, geo.tile, n_pe)
-    kernel, scratch = _sched_kernel_scratch(flow, gm, relu, fa, n_pe,
-                                            bm, bt, sink, wrap=wrap)
-
-    x_spec, gr_spec, gc_spec = _halo_specs(geo, hg, bm, canon)
-    t_spec = lambda lanes: pl.BlockSpec(
-        (1, bm, t_cycles, lanes),
-        lambda *g: (canon(*g)[0], canon(*g)[2], 0, 0))
-    b_spec = pl.BlockSpec((1, n_pe), lambda *g: (0, canon(*g)[0]))
-
-    canvas = (b, np_, hg.nbh * hg.bth * geo.tile,
-              hg.nbw * hg.btw * geo.tile)
-    in_specs = [x_spec, gr_spec, gc_spec, t_spec(r), t_spec(n_pe),
-                t_spec(n_pe), t_spec(n_pe),
-                _const_spec(fa, s), _const_spec(fa, s),
-                _const_spec(s2, fa), _const_spec(s2, fa), b_spec]
-    operands = [x_.astype(jnp.float32), gr, gc, idx, sel, vr, vi, dfr,
-                dfi, dvr, dvi, bias_]
-    if shortcut is not None:
-        kernel = _residual_kernel(kernel, sink)
-        in_specs = [_canvas_sc_spec(hg, n_pe, geo.tile, canon)] + in_specs
-        operands = [_shortcut_canvas(shortcut, geo, hg, n_pe)] + operands
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct(canvas, jnp.float32),
-        scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=semantics),
-        interpret=interpret,
-    )(*operands)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_out", "flow", "block_m", "block_p", "relu",
-                     "interpret"))
-def fused_spectral_pipeline_scheduled(xt: Array, idx: Array, sel: Array,
-                                      vr: Array, vi: Array,
-                                      dfr: Array, dfi: Array,
-                                      dvr: Array, dvi: Array,
-                                      bias: Array, *, n_out: int,
-                                      flow: str = "output_stationary",
-                                      block_m: int = 64,
-                                      block_p: int = 128,
-                                      relu: bool = False,
-                                      interpret: bool = True,
-                                      shortcut: Array | None = None
-                                      ) -> Array:
-    """FFT -> SCHEDULED sparse Hadamard -> IFFT (+ epilogue) in one
-    pallas_call — the element-granular sibling of
-    ``fused_spectral_pipeline``.  ``shortcut``: optional [S2, n_out, P]
-    residual operand in the output tile layout (see
-    ``fused_spectral_pipeline``).
-
-    The kernel operand is not a plane stack but the Alg-2 INDEX/VALUE
-    tables of ``scheduler.LayerTables`` (already padded/remapped):
-
-    xt: [S, M, P] f32          overlap-save windows, s-leading
-    idx: [GN, Mp, T, r] int32  replica read addresses (compacted coords)
-    sel: [GN, Mp, T, N'] int32 crossbar selects
-    vr/vi: [GN, Mp, T, N'] f32 PE weight planes (zero = idle lane)
-    dfr/dfi: [Fa, S], dvr/dvi: [S2, Fa], bias: [1, n_out]
-
-    block_n is implied: it equals the schedule's PE-group size N' (the
-    tables were compiled for it); the table channel padding Mp must
-    equal M padded to block_m — both are enforced.  Returns
-    [S2, n_out, P] finished spatial outputs.
-    """
-    s, m, p = xt.shape
-    gn, mp_t, t_cycles, r = idx.shape
-    n_pe = sel.shape[3]
-    fa = dfr.shape[0]
-    s2 = dvr.shape[0]
-    assert sel.shape == (gn, mp_t, t_cycles, n_pe), (sel.shape, idx.shape)
-    assert vr.shape == sel.shape and vi.shape == sel.shape
-    assert dfr.shape == (fa, s) and dvr.shape == (s2, fa), \
-        (dfr.shape, dvr.shape, (fa, s, s2))
-    assert n_out <= gn * n_pe, (n_out, gn, n_pe)
-    assert bias.shape == (1, n_out), (bias.shape, n_out)
-
-    bm, bp = min(block_m, m), min(block_p, p)
-    xt_ = _pad_axis(_pad_axis(xt, 1, bm), 2, bp)
-    bias_ = _pad_axis(bias, 1, n_pe)
-    mp_, pp_ = xt_.shape[1], xt_.shape[2]
-    assert mp_ == mp_t, \
-        (f"tables padded for {mp_t} channels but windows pad to {mp_}; "
-         f"compile_layer_tables(m_pad_to=block_m) must use the same "
-         f"block_m (= {bm})")
-    np_ = gn * n_pe
-    gm, gp = mp_ // bm, pp_ // bp
-    grid, canon, semantics = _flow_layout(flow, gn, gm, gp)
-    sink = _TileSink(s2, n_pe, bp)
-    kernel, scratch = _sched_kernel_scratch(flow, gm, relu, fa, n_pe,
-                                            bm, bp, sink)
-
-    x_spec = pl.BlockSpec(
-        (s, bm, bp), lambda *g: (0, canon(*g)[2], canon(*g)[1]))
-    t_spec = lambda lanes: pl.BlockSpec(
-        (1, bm, t_cycles, lanes),
-        lambda *g: (canon(*g)[0], canon(*g)[2], 0, 0))
-    b_spec = pl.BlockSpec((1, n_pe), lambda *g: (0, canon(*g)[0]))
-
-    in_specs = [x_spec, t_spec(r), t_spec(n_pe), t_spec(n_pe),
-                t_spec(n_pe),
-                _const_spec(fa, s), _const_spec(fa, s),
-                _const_spec(s2, fa), _const_spec(s2, fa), b_spec]
-    operands = [xt_.astype(jnp.float32), idx, sel, vr, vi, dfr, dfi,
-                dvr, dvi, bias_]
-    if shortcut is not None:
-        assert shortcut.shape == (s2, n_out, p), \
-            (shortcut.shape, (s2, n_out, p))
-        kernel = _residual_kernel(kernel, sink)
-        sc_spec = pl.BlockSpec(
-            (s2, n_pe, bp), lambda *g: (0, canon(*g)[0], canon(*g)[1]))
-        in_specs = [sc_spec] + in_specs
-        operands = [_pad_axis(_pad_axis(shortcut.astype(jnp.float32),
-                                        1, n_pe), 2, bp)] + operands
-    y = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
         out_shape=jax.ShapeDtypeStruct((s2, np_, pp_), jnp.float32),
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
-            dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics,
+            vmem_limit_bytes=TPU_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*operands)
-    return y[:, :n_out, :p]
 
+
+# ---------------------------------------------------------------------------
+# Host-side layouts of the two input modes
+# ---------------------------------------------------------------------------
 
 def _windows_layout(x: Array, geo: SpectralGeometry) -> tuple[Array, int]:
-    """Overlap-save window extraction + s-leading layout [S, M, B*T] —
-    the in-kernel FFT contracts the leading dim with one GEMM, no
-    transposes on the TPU side."""
+    """Overlap-save window extraction + s-leading layout [S, M, B*T]."""
     b, m = x.shape[:2]
     windows = extract_tiles_overlapping(x, geo)         # [B, M, T, K, K]
     t_cnt = windows.shape[2]
@@ -1219,128 +586,248 @@ def _windows_layout(x: Array, geo: SpectralGeometry) -> tuple[Array, int]:
     return xt, t_cnt
 
 
-def _assemble_output(y: Array, geo: SpectralGeometry, b: int, n: int,
-                     t_cnt: int, dtype) -> Array:
-    """[t^2, N, B*T] pipeline output -> assembled [B, N, H, W]."""
+def _shortcut_canvas(sc: Array, geo: SpectralGeometry, rows: int,
+                     cols: int) -> Array:
+    """RAW [B, N, H_out, W_out] shortcut embedded at its full-conv canvas
+    position in a zero [B, N, rows, cols] canvas (positions outside the
+    'same' crop are cropped from the output anyway)."""
+    b, n, h, w = sc.shape
+    start = geo.ksize - 1 - geo.pad
+    canvas = jnp.zeros((b, n, rows, cols), jnp.float32)
+    return canvas.at[:, :, start:start + h,
+                     start:start + w].set(sc.astype(jnp.float32))
+
+
+def _windowed_shortcut(sc: Array, geo: SpectralGeometry, t_cnt: int
+                       ) -> Array:
+    """Shortcut in the windowed output layout [S2, N, B*T] (inverse of
+    ``assemble_tile_canvas``): t x t tiles, u-major rows."""
+    b, n = sc.shape[:2]
+    t = geo.tile
+    canvas = _shortcut_canvas(sc, geo, geo.n_tiles_h * t,
+                              geo.n_tiles_w * t)
+    tiles = (canvas.reshape(b, n, geo.n_tiles_h, t, geo.n_tiles_w, t)
+             .transpose(0, 1, 2, 4, 3, 5)        # [b, n, ith, jtw, u, v]
+             .reshape(b, n, t_cnt, t * t))
+    return tiles.transpose(3, 1, 0, 2).reshape(t * t, n, b * t_cnt)
+
+
+def _windowed_canvas(y: Array, geo: SpectralGeometry, b: int, n: int,
+                     t_cnt: int) -> Array:
+    """[t^2, N, B*T] tile stream -> UNCROPPED [B, N, h_pad, w_pad]."""
     s2 = geo.tile * geo.tile
     y_tiles = (y.reshape(s2, n, b, t_cnt).transpose(2, 1, 3, 0)
                .reshape(b, n, t_cnt, geo.tile, geo.tile))
-    return assemble_valid_tiles(y_tiles.astype(dtype), geo)
+    return assemble_tile_canvas(y_tiles, geo)
 
+
+def _halo_lanes(hg: HaloGeometry) -> int:
+    """Lanes per tile row of a halo block: btw padded to a lane tile."""
+    return lane_round(hg.btw)
+
+
+def _halo_selectors(geo: SpectralGeometry, hg: HaloGeometry
+                    ) -> tuple[np.ndarray, np.ndarray]:
+    """(gr [nbh, bth*K, rh, 1], gc [nbw, rw, K*lw]): the selectors of
+    ``halo_gather_matrices`` laid out for the kernel — each row-selector
+    row as a column (the kernel masks the raw block with it), and
+    output lane ``kw*lw + jj`` of the column selector picks
+    window column kw of tile column jj; lanes jj >= btw stay zero (lane
+    padding)."""
+    k, lw = geo.fft_size, _halo_lanes(hg)
+    gr, gc = halo_gather_matrices(geo, hg)
+    g = gc.reshape(hg.nbw, hg.btw, k, hg.rw).transpose(0, 3, 2, 1)
+    g = np.pad(g, ((0, 0), (0, 0), (0, 0), (0, lw - hg.btw)))
+    return (np.ascontiguousarray(gr[..., None]),
+            np.ascontiguousarray(g.reshape(hg.nbw, hg.rw, k * lw)))
+
+
+def _halo_x_specs(geo: SpectralGeometry, hg: HaloGeometry, bm: int,
+                  canon):
+    """BlockSpecs of the halo input path.  The raw x block is addressed
+    by element offsets (``pl.Element`` dims): the p grid axis enumerates
+    (image, block-row, block-col) and the offsets are the traced twin of
+    ``spectral.halo_block_starts`` — neighbouring blocks overlap by the
+    k-1 halo, clamped at the image edges.  An axis with a single block
+    reads from offset 0; row offsets are multiples of ``ROW_ALIGN``.
+    The selectors use blocked indexing on their leading block axis."""
+    t, ov = geo.tile, geo.ksize - 1
+    nb = hg.n_blocks
+    h_hi, w_hi = geo.h_in - hg.rh, geo.w_in - hg.rw
+    k, lw = geo.fft_size, _halo_lanes(hg)
+
+    def row(ib):
+        if hg.nbh == 1:
+            return 0
+        # + pre_halo_h: sharded bands carry their top halo in-buffer.
+        s = jnp.maximum(ib * hg.bth * t - ov + geo.pre_halo_h, 0)
+        s = jnp.minimum(s // ROW_ALIGN * ROW_ALIGN, h_hi)
+        return pl.multiple_of(s, ROW_ALIGN) if h_hi % ROW_ALIGN == 0 else s
+
+    def col(jb):
+        if hg.nbw == 1:
+            return 0
+        return jnp.clip(jb * hg.btw * t - ov, 0, w_hi)
+
+    def x_idx(*g):
+        _, p, m = canon(*g)
+        b, ib, jb = p // nb, (p % nb) // hg.nbw, p % hg.nbw
+        return (b, m * bm, row(ib), col(jb))
+
+    x_spec = pl.BlockSpec((pl.Element(1), pl.Element(bm),
+                           pl.Element(hg.rh), pl.Element(hg.rw)), x_idx)
+    gr_spec = pl.BlockSpec(
+        (1, hg.bth * k, hg.rh, 1),
+        lambda *g: ((canon(*g)[1] % nb) // hg.nbw, 0, 0, 0))
+    gc_spec = pl.BlockSpec(
+        (1, hg.rw, k * lw), lambda *g: (canon(*g)[1] % hg.nbw, 0, 0))
+    return x_spec, gr_spec, gc_spec
+
+
+def _halo_shortcut(sc: Array, geo: SpectralGeometry, hg: HaloGeometry
+                   ) -> Array:
+    """Shortcut in the halo output layout [S2, N, B*nb*bth*lw]: lanes
+    (image, block-row, block-col, tile-row ii, tile-col jj padded to
+    lw) — the inverse of ``_halo_canvas``."""
+    b, n = sc.shape[:2]
+    t, lw = geo.tile, _halo_lanes(hg)
+    canvas = _shortcut_canvas(sc, geo, hg.nbh * hg.bth * t,
+                              hg.nbw * hg.btw * t)
+    y = canvas.reshape(b, n, hg.nbh, hg.bth, t, hg.nbw, hg.btw, t)
+    y = y.transpose(4, 7, 1, 0, 2, 5, 3, 6)      # u v n b ib jb ii jj
+    y = jnp.pad(y, [(0, 0)] * 7 + [(0, lw - hg.btw)])
+    return y.reshape(t * t, n, b * hg.n_blocks * hg.bth * lw)
+
+
+def _halo_canvas(y: Array, geo: SpectralGeometry, hg: HaloGeometry,
+                 b: int, n: int) -> Array:
+    """Halo tile stream [S2, >=N, B*nb*bth*lw] -> UNCROPPED [B, N,
+    h_pad, w_pad]: tile (ib*bth + ii, jb*btw + jj) lands at canvas
+    (i*t, j*t); lane padding and tiles past the grid are dropped."""
+    t, lw = geo.tile, _halo_lanes(hg)
+    y = y[:, :n].reshape(t, t, n, b, hg.nbh, hg.nbw, hg.bth, lw)
+    y = y[..., :hg.btw].transpose(3, 2, 4, 6, 0, 5, 7, 1)
+    y = y.reshape(b, n, hg.nbh * hg.bth * t, hg.nbw * hg.btw * t)
+    return y[:, :, :geo.h_pad, :geo.w_pad]
+
+
+# ---------------------------------------------------------------------------
+# The jitted conv: layout -> pallas_call -> canvas
+# ---------------------------------------------------------------------------
 
 @functools.partial(
     jax.jit,
-    static_argnames=("geo", "flow", "block_n", "block_m", "block_p",
-                     "relu", "interpret"))
-def _fused_conv(x: Array, wr: Array, wi: Array, dfr: Array, dfi: Array,
+    static_argnames=("geo", "input_mode", "flow", "block_n", "block_m",
+                     "block_p", "relu", "interpret", "crop"))
+def _fused_conv(x: Array, w_ops: tuple, dfr: Array, dfi: Array,
                 dvr: Array, dvi: Array, bias: Array,
                 shortcut: Array | None = None, *,
-                geo: SpectralGeometry, flow: str,
-                block_n: int, block_m: int, block_p: int,
-                relu: bool, interpret: bool) -> Array:
-    """Jitted body: overlap-save window extraction, layout, pipeline,
-    valid-tile assembly — one compiled program per (geo, flow, blocks,
-    relu), so the host-side relayout is not re-dispatched eagerly on
-    every forward call.  All spectral operands arrive precomputed (by
-    ``core.plan`` or the ad-hoc wrapper below); nothing geometric or
-    sparsity-related is derived in here.  ``shortcut`` is an optional
-    RAW [B, N, H_out, W_out] residual operand, relaid to the output
-    tile layout and added in-kernel (after bias, before ReLU)."""
+                geo: SpectralGeometry, input_mode: str, flow: str,
+                block_n: int, block_m: int, block_p: int, relu: bool,
+                interpret: bool, crop: bool = True) -> Array:
+    """One fused spectral conv: host layout, the pallas_call, and the
+    tile-stream -> canvas relayout (+ 'same' crop when ``crop``; sharded
+    bands keep the uncropped canvas).
+
+    x: [B, M, H, W] raw NCHW activation.
+    w_ops: ``(wr, wi)`` planes [Fa, N, M] ('dense'/'bin'), or the Alg-2
+        tables ``(idx, sel, vr, vi)`` — [GN, Mp, T, r] / [GN, Mp, T, N']
+        compiled with ``m_pad_to == min(block_m, M)``; block_n is then
+        the schedule's group size N'.
+    dfr/dfi [Fa, K^2], dvr/dvi [t^2, Fa]: ``overlap_save_operators``.
+    bias: [1, N].  shortcut: optional RAW [B, N, H_out, W_out] residual
+        operand, relaid to the output layout and added in-kernel after
+        bias, before ReLU.
+    """
+    if flow not in FLOWS:
+        raise ValueError(f"flow must be one of {FLOWS}")
+    if input_mode not in INPUT_MODES:
+        raise ValueError(f"input_mode must be one of {INPUT_MODES}")
     b, m = x.shape[:2]
-    n = wr.shape[1]
-    xt, t_cnt = _windows_layout(x, geo)
-    sc = (None if shortcut is None
-          else _shortcut_tiles(shortcut, geo, t_cnt))
-    y = fused_spectral_pipeline(
-        xt, wr, wi, dfr, dfi, dvr, dvi, bias, flow=flow,
-        block_n=block_n, block_m=block_m, block_p=block_p, relu=relu,
-        interpret=interpret, shortcut=sc)               # [t^2, N, B*T]
-    return _assemble_output(y, geo, b, n, t_cnt, x.dtype)
+    n = bias.shape[1]
+    fa = dfr.shape[0]
+    k = geo.fft_size
+    tables = len(w_ops) == 4
+    bm = min(block_m, m)
+    if bm == m:                      # full-extent M: pad to a sublane tile
+        bm = -(-m // SUBLANES) * SUBLANES
+    x = _pad_axis(x.astype(jnp.float32), 1, bm)
+    mp_ = x.shape[1]
+    if tables:
+        if w_ops[0].shape[1] < mp_:  # inert zero channels, as compiled
+            w_ops = tuple(_pad_axis(w, 1, mp_) for w in w_ops)
+        gn, mp_t, _, _ = w_ops[0].shape
+        bn = w_ops[1].shape[3]
+        assert mp_t == mp_, (
+            f"tables padded for {mp_t} channels but the input pads to "
+            f"{mp_}; compile_layer_tables(m_pad_to=block_m) must use the "
+            f"same block_m (= {bm})")
+        np_ = gn * bn
+        w_src = functools.partial(_TableBlock, fa=fa)
+        t_cycles, r = w_ops[0].shape[2:]
+        t_spec = lambda lanes: pl.BlockSpec(
+            (1, bm, t_cycles, lanes),
+            lambda *g: (canon(*g)[0], canon(*g)[2], 0, 0))
+        w_specs = [t_spec(r)] + [t_spec(bn)] * 3
+    else:
+        bn = min(block_n, n)
+        w_ops = tuple(_pad_axis(_pad_axis(w, 1, bn), 2, bm) for w in w_ops)
+        np_ = w_ops[0].shape[1]
+        gn = np_ // bn
+        w_src = _PlaneBlock
+        w_spec = pl.BlockSpec(
+            (fa, bn, bm), lambda *g: (0, canon(*g)[0], canon(*g)[2]))
+        w_specs = [w_spec, w_spec]
+    gm = mp_ // bm
+    bias_col = _pad_axis(bias.astype(jnp.float32), 1, bn).reshape(np_, 1)
+    ops = (dfr, dfi, dvr, dvi)
+
+    if input_mode == "halo":
+        hg = halo_block_geometry(geo, block_p)
+        lw = _halo_lanes(hg)
+        bp = hg.bth * lw
+        gp = b * hg.n_blocks
+        canon_layout = _flow_layout(flow, gn, gm, gp)
+        canon = canon_layout[1]
+        gr, gc = _halo_selectors(geo, hg)
+        x_ops = (x, jnp.asarray(gr), jnp.asarray(gc))
+        x_specs = _halo_x_specs(geo, hg, bm, canon)
+        x_src = functools.partial(_HaloBlock, fft_size=k, bth=hg.bth,
+                                  lw=lw)
+        sc = None if shortcut is None else _halo_shortcut(shortcut, geo, hg)
+        pp_ = gp * bp
+    else:
+        xt, t_cnt = _windows_layout(x, geo)             # [S, Mp, B*T]
+        bp = lane_round(min(block_p, b * t_cnt))
+        xt = _pad_axis(xt, 2, bp)
+        pp_ = xt.shape[2]
+        gp = pp_ // bp
+        canon_layout = _flow_layout(flow, gn, gm, gp)
+        canon = canon_layout[1]
+        x_ops = (xt,)
+        x_specs = [pl.BlockSpec(
+            (k * k, bm, bp), lambda *g: (0, canon(*g)[2], canon(*g)[1]))]
+        x_src = _WindowBlock
+        sc = (None if shortcut is None
+              else _windowed_shortcut(shortcut, geo, t_cnt))
+    if sc is not None:
+        sc = _pad_axis(_pad_axis(sc, 1, bn), 2, bp)
+
+    y = _fused_call(x_src, x_ops, x_specs, w_src, w_ops, w_specs, ops,
+                    bias_col, sc, flow=flow, grid_counts=(gn, gm, gp),
+                    canon_layout=canon_layout, bn=bn, bm=bm, bp=bp,
+                    np_=np_, pp_=pp_, relu=relu, interpret=interpret)
+    if input_mode == "halo":
+        canvas = _halo_canvas(y, geo, hg, b, n)
+    else:
+        canvas = _windowed_canvas(y[:, :n, :b * t_cnt], geo, b, n, t_cnt)
+    return crop_canvas_same(canvas, geo) if crop else canvas
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "n_out", "flow", "block_m", "block_p",
-                     "relu", "interpret"))
-def _fused_conv_scheduled(x: Array, idx: Array, sel: Array, vr: Array,
-                          vi: Array, dfr: Array, dfi: Array, dvr: Array,
-                          dvi: Array, bias: Array,
-                          shortcut: Array | None = None, *,
-                          geo: SpectralGeometry, n_out: int, flow: str,
-                          block_m: int, block_p: int,
-                          relu: bool, interpret: bool) -> Array:
-    """Jitted body of the scheduled-Hadamard fused conv (same relayout
-    contract as ``_fused_conv``; kernel operands are Alg-2 tables)."""
-    b = x.shape[0]
-    xt, t_cnt = _windows_layout(x, geo)
-    sc = (None if shortcut is None
-          else _shortcut_tiles(shortcut, geo, t_cnt))
-    y = fused_spectral_pipeline_scheduled(
-        xt, idx, sel, vr, vi, dfr, dfi, dvr, dvi, bias, n_out=n_out,
-        flow=flow, block_m=block_m, block_p=block_p, relu=relu,
-        interpret=interpret, shortcut=sc)
-    return _assemble_output(y, geo, b, n_out, t_cnt, x.dtype)
-
-
-def _crop_canvas(y: Array, geo: SpectralGeometry, n: int, dtype) -> Array:
-    """[B, Np, nbh*bth*t, nbw*btw*t] halo-pipeline canvas -> [B, N,
-    H_out, W_out]: the kernel already assembled tiles in canvas order
-    (tile (i, j) at (i*t, j*t)), so all that remains is the channel
-    crop and the 'same'-crop slice of ``assemble_valid_tiles`` — a pure
-    slice, zero relayout FLOPs or copies on the host."""
-    start = geo.ksize - 1 - geo.pad
-    h_out = geo.h_in + 2 * geo.pad - geo.ksize + 1
-    w_out = geo.w_in + 2 * geo.pad - geo.ksize + 1
-    return y[:, :n, start:start + h_out,
-             start:start + w_out].astype(dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "flow", "block_n", "block_m", "block_p",
-                     "relu", "interpret"))
-def _fused_conv_halo(x: Array, wr: Array, wi: Array, dfr: Array,
-                     dfi: Array, dvr: Array, dvi: Array, bias: Array,
-                     shortcut: Array | None = None, *,
-                     geo: SpectralGeometry, flow: str,
-                     block_n: int, block_m: int, block_p: int,
-                     relu: bool, interpret: bool) -> Array:
-    """Jitted body of the halo-input fused conv: NO host-side window
-    materialization — the raw activation goes straight into the
-    pallas_call (the in-kernel gather does the windowing) — and NO
-    host-side output relayout either: the kernel DMAs assembled canvas
-    rectangles and only the 'same'-crop slice runs outside.  ``block_p``
-    is split into the 2-D halo block by ``halo_block_geometry``."""
-    n = wr.shape[1]
-    hg = halo_block_geometry(geo, block_p)
-    y = fused_spectral_pipeline_halo(
-        x, wr, wi, dfr, dfi, dvr, dvi, bias, geo=geo, hg=hg, flow=flow,
-        block_n=block_n, block_m=block_m, relu=relu, interpret=interpret,
-        shortcut=shortcut)
-    return _crop_canvas(y, geo, n, x.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "n_out", "flow", "block_m", "block_p",
-                     "relu", "interpret"))
-def _fused_conv_scheduled_halo(x: Array, idx: Array, sel: Array,
-                               vr: Array, vi: Array, dfr: Array,
-                               dfi: Array, dvr: Array, dvi: Array,
-                               bias: Array,
-                               shortcut: Array | None = None, *,
-                               geo: SpectralGeometry,
-                               n_out: int, flow: str, block_m: int,
-                               block_p: int, relu: bool,
-                               interpret: bool) -> Array:
-    """Jitted body of the halo-input scheduled fused conv (same contract
-    as ``_fused_conv_scheduled``, raw activation in)."""
-    hg = halo_block_geometry(geo, block_p)
-    y = fused_spectral_pipeline_scheduled_halo(
-        x, idx, sel, vr, vi, dfr, dfi, dvr, dvi, bias, geo=geo, hg=hg,
-        n_out=n_out, flow=flow, block_m=block_m, relu=relu,
-        interpret=interpret, shortcut=shortcut)
-    return _crop_canvas(y, geo, n_out, x.dtype)
+def _bias_row(bias, n: int) -> Array:
+    if bias is None:
+        return jnp.zeros((1, n), jnp.float32)
+    return jnp.asarray(bias, jnp.float32).reshape(1, n)
 
 
 def fused_spectral_conv2d(x: Array, w_f, geo: SpectralGeometry, *,
@@ -1357,12 +844,8 @@ def fused_spectral_conv2d(x: Array, w_f, geo: SpectralGeometry, *,
     GEMM compaction (dense fallback when nnz ~= K^2).  ``bias``/``relu``
     select the fused epilogue.  ``input_mode`` selects the input path
     (``dataflow.INPUT_MODES``): 'windowed' materializes the overlap-save
-    window tensor host-side (the PR-3 formulation, kept as fallback and
-    oracle), 'halo' reads the raw activation through overlapping halo
-    blocks and gathers the windows in VMEM — numerically identical, one
-    whole HBM materialization pass cheaper plus the (K/t)^2 halo
-    duplication.  In windowed mode the host does only the layout work
-    the paper's DMA engine does; in halo mode not even that.
+    window tensor host-side, 'halo' reads the raw activation through
+    overlapping halo blocks and gathers the windows in VMEM.
 
     NOTE: this ad-hoc entry recomputes compaction + DFT operators per
     call; the compile-once path is ``core.plan.build_network_plan`` +
@@ -1372,7 +855,7 @@ def fused_spectral_conv2d(x: Array, w_f, geo: SpectralGeometry, *,
         raise ValueError(f"input_mode must be one of {INPUT_MODES}, "
                          f"got {input_mode!r}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     if hasattr(w_f, "values"):            # SparseSpectralKernels duck-type
         active = sp.compacted_active_bins(w_f)
         wr, wi = sp.compact_planes(w_f, active)
@@ -1388,15 +871,11 @@ def fused_spectral_conv2d(x: Array, w_f, geo: SpectralGeometry, *,
     ops = overlap_save_operators(
         geo.fft_size, geo.ksize,
         tuple(int(a) for a in active) if active is not None else None)
-    dfr, dfi, dvr, dvi = (jnp.asarray(a) for a in ops)
-    if bias is None:
-        bias_arr = jnp.zeros((1, n), jnp.float32)
-    else:
-        bias_arr = jnp.asarray(bias, jnp.float32).reshape(1, n)
-    conv = _fused_conv_halo if input_mode == "halo" else _fused_conv
-    return conv(x, wr, wi, dfr, dfi, dvr, dvi, bias_arr, geo=geo,
-                flow=flow, block_n=block_n, block_m=block_m,
-                block_p=block_p, relu=relu, interpret=interpret)
+    y = _fused_conv(x, (wr, wi), *(jnp.asarray(a) for a in ops),
+                    _bias_row(bias, n), geo=geo, input_mode=input_mode,
+                    flow=flow, block_n=block_n, block_m=block_m,
+                    block_p=block_p, relu=relu, interpret=interpret)
+    return y.astype(x.dtype)
 
 
 def fused_spectral_conv2d_scheduled(x: Array, sk, geo: SpectralGeometry,
@@ -1415,17 +894,14 @@ def fused_spectral_conv2d_scheduled(x: Array, sk, geo: SpectralGeometry,
     x: [B, M, H, W] real NCHW; sk: ``SparseSpectralKernels`` whose Alg-2
     exact-cover schedule (group size ``n_par`` == the kernel's block_n,
     ``r`` BRAM-replica analogue) is compiled to INDEX/VALUE tables here
-    and executed element-granularly inside the fused kernel.  Pass a
-    precompiled ``scheduler.LayerTables`` via ``tables`` to skip the
-    per-call scheduling (it must have been built with the same
-    ``active`` set and ``m_pad_to == min(block_m, M)``) — repeated
-    calls (e.g. the measured autotune pass) should not re-run, let
-    alone re-time, the host-side scheduler.
+    and executed inside the fused kernel.  Pass a precompiled
+    ``scheduler.LayerTables`` via ``tables`` to skip the per-call
+    scheduling (built with the same ``active`` set and ``m_pad_to ==
+    min(block_m, M)``).
 
     NOTE: without ``tables`` this ad-hoc entry runs the scheduler per
-    call (one schedule per kernel-group x channel); the compile-once
-    path is ``core.plan.build_network_plan(hadamard='scheduled'|'auto')``
-    + ``execute_layer_plan``.
+    call; the compile-once path is ``core.plan.build_network_plan(
+    hadamard='scheduled'|'auto')`` + ``execute_layer_plan``.
     """
     from repro.core import scheduler as sch
 
@@ -1433,7 +909,7 @@ def fused_spectral_conv2d_scheduled(x: Array, sk, geo: SpectralGeometry,
         raise ValueError(f"input_mode must be one of {INPUT_MODES}, "
                          f"got {input_mode!r}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     assert sk.fft_size == geo.fft_size
     k2 = geo.fft_size * geo.fft_size
     n, m = sk.n_out, sk.n_in
@@ -1449,170 +925,62 @@ def fused_spectral_conv2d_scheduled(x: Array, sk, geo: SpectralGeometry,
     ops = overlap_save_operators(
         geo.fft_size, geo.ksize,
         tuple(int(a) for a in active) if active is not None else None)
-    dfr, dfi, dvr, dvi = (jnp.asarray(a) for a in ops)
-    if bias is None:
-        bias_arr = jnp.zeros((1, n), jnp.float32)
-    else:
-        bias_arr = jnp.asarray(bias, jnp.float32).reshape(1, n)
-    conv = (_fused_conv_scheduled_halo if input_mode == "halo"
-            else _fused_conv_scheduled)
-    return conv(
-        x, jnp.asarray(tabs.idx), jnp.asarray(tabs.sel),
-        jnp.asarray(tabs.vr), jnp.asarray(tabs.vi),
-        dfr, dfi, dvr, dvi, bias_arr, geo=geo, n_out=n,
-        flow=flow, block_m=bm, block_p=block_p, relu=relu,
-        interpret=interpret)
+    w_ops = tuple(jnp.asarray(a) for a in (tabs.idx, tabs.sel, tabs.vr,
+                                           tabs.vi))
+    y = _fused_conv(x, w_ops, *(jnp.asarray(a) for a in ops),
+                    _bias_row(bias, n), geo=geo, input_mode=input_mode,
+                    flow=flow, block_n=n_par, block_m=bm,
+                    block_p=block_p, relu=relu, interpret=interpret)
+    return y.astype(x.dtype)
 
 
-def execute_layer_plan(x: Array, lp, *, interpret: bool | None = None,
-                       shortcut: Array | None = None) -> Array:
-    """Run one conv layer from a precompiled ``core.plan.LayerPlan``.
-
-    Consumes the plan's precomputed operands and dispatches on the
-    plan's Hadamard mode: 'dense'/'bin' execute the Karatsuba-GEMM
-    pipeline on the (possibly compacted) kernel planes; 'scheduled'
-    executes the precompiled Alg-2 INDEX/VALUE tables element-
-    granularly.  Nothing is re-derived per call — no scheduling,
-    compaction or geometry work — so repeated forwards hit the jit
-    cache of ``_fused_conv``/``_fused_conv_scheduled`` (or their halo
-    siblings, when the plan's ``input_mode`` is 'halo') directly.
-    Pooling (``lp.epilogue.pool``) is spatial and stays with the
-    caller.
-
-    ``shortcut``: RAW [B, N, H_out, W_out] residual operand for plans
-    whose epilogue is residual-FUSED (``lp.epilogue.residual ==
-    'fused'``); the DAG executor passes the producer node's activation
-    and the kernel adds it after bias, before ReLU.  Ignored epilogue
-    states ('add' / None) never pass one — the add happens in XLA.
-    """
+def _run_plan(x: Array, lp, shortcut, *, interpret: bool | None,
+              crop: bool) -> Array:
+    """Dispatch one ``LayerPlan`` onto ``_fused_conv``.  The
+    fault-injection sites live HERE — outside the jitted pipeline — so
+    a warm jit cache can never bypass them; this is also where a real
+    Mosaic lowering failure or VMEM exhaustion surfaces on hardware."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = default_interpret()
     tn = lp.tuning
-    halo = getattr(lp, "input_mode", "windowed") == "halo"
-    # Fault-injection sites (no-ops without an installed fault).  They
-    # live HERE — outside the jitted pipelines — so a warm jit cache can
-    # never bypass them; this is also where a real Mosaic lowering
-    # failure or VMEM RESOURCE_EXHAUSTED would surface on hardware.
     ctx = dict(layer=lp.layer.name, backend="fused", flow=tn.flow,
                hadamard=getattr(lp, "hadamard", None),
                input_mode=getattr(lp, "input_mode", "windowed"),
                residual=getattr(lp.epilogue, "residual", None))
     res.fault_check("lowering", **ctx)
     res.fault_check("vmem_overflow", **ctx)
-    bias = lp.bias if lp.epilogue.bias else jnp.zeros_like(lp.bias)
+    bias = (lp.bias if lp.epilogue.bias
+            else jnp.zeros(lp.bias.shape, lp.bias.dtype))
     if getattr(lp, "hadamard", None) == "scheduled":
         tb = lp.tables
-        conv = _fused_conv_scheduled_halo if halo else _fused_conv_scheduled
-        y = conv(
-            x, tb.idx, tb.sel, tb.vr, tb.vi,
-            lp.dfr, lp.dfi, lp.dvr, lp.dvi, bias, shortcut, geo=lp.geo,
-            n_out=lp.layer.c_out, flow=tn.flow, block_m=tn.block_m,
-            block_p=tn.block_p, relu=lp.epilogue.relu,
-            interpret=interpret)
-        return res.fault_corrupt("nan_activations", y, **ctx)
-    conv = _fused_conv_halo if halo else _fused_conv
-    y = conv(x, lp.wr, lp.wi, lp.dfr, lp.dfi, lp.dvr, lp.dvi,
-             bias, shortcut, geo=lp.geo, flow=tn.flow,
-             block_n=tn.block_n, block_m=tn.block_m,
-             block_p=tn.block_p, relu=lp.epilogue.relu,
-             interpret=interpret)
-    return res.fault_corrupt("nan_activations", y, **ctx)
+        w_ops, block_n = (tb.idx, tb.sel, tb.vr, tb.vi), tb.sel.shape[3]
+    else:
+        w_ops, block_n = (lp.wr, lp.wi), tn.block_n
+    y = _fused_conv(x, w_ops, lp.dfr, lp.dfi, lp.dvr, lp.dvi, bias,
+                    shortcut, geo=lp.geo,
+                    input_mode=getattr(lp, "input_mode", "windowed"),
+                    flow=tn.flow, block_n=block_n, block_m=tn.block_m,
+                    block_p=tn.block_p, relu=lp.epilogue.relu,
+                    interpret=interpret, crop=crop)
+    return res.fault_corrupt("nan_activations", y.astype(x.dtype), **ctx)
 
 
-# ---------------------------------------------------------------------------
-# Sharded-band execution (ISSUE 9): uncropped canvas contract
-# ---------------------------------------------------------------------------
+def execute_layer_plan(x: Array, lp, *, interpret: bool | None = None,
+                       shortcut: Array | None = None) -> Array:
+    """Run one conv layer from a precompiled ``core.plan.LayerPlan``.
 
-def _assemble_band_canvas(y: Array, geo: SpectralGeometry, b: int, n: int,
-                          t_cnt: int, dtype) -> Array:
-    """[t^2, N, B*T] pipeline output -> UNCROPPED [B, N, h_pad, w_pad]
-    band canvas (``_assemble_output`` without the 'same' crop — sharded
-    bands crop only after cross-shard concatenation)."""
-    s2 = geo.tile * geo.tile
-    y_tiles = (y.reshape(s2, n, b, t_cnt).transpose(2, 1, 3, 0)
-               .reshape(b, n, t_cnt, geo.tile, geo.tile))
-    return assemble_tile_canvas(y_tiles.astype(dtype), geo)
+    Consumes the plan's precomputed operands and dispatches on its
+    Hadamard mode ('dense'/'bin' planes or 'scheduled' tables) and input
+    mode; nothing is re-derived per call, so repeated forwards hit the
+    jit cache of ``_fused_conv``.  Pooling (``lp.epilogue.pool``) stays
+    with the caller.
 
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "flow", "block_n", "block_m", "block_p",
-                     "relu", "interpret"))
-def _band_conv(x: Array, wr: Array, wi: Array, dfr: Array, dfi: Array,
-               dvr: Array, dvi: Array, bias: Array, *,
-               geo: SpectralGeometry, flow: str,
-               block_n: int, block_m: int, block_p: int,
-               relu: bool, interpret: bool) -> Array:
-    """``_fused_conv`` returning the uncropped band canvas."""
-    b, m = x.shape[:2]
-    n = wr.shape[1]
-    xt, t_cnt = _windows_layout(x, geo)
-    y = fused_spectral_pipeline(
-        xt, wr, wi, dfr, dfi, dvr, dvi, bias, flow=flow,
-        block_n=block_n, block_m=block_m, block_p=block_p, relu=relu,
-        interpret=interpret)
-    return _assemble_band_canvas(y, geo, b, n, t_cnt, x.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "n_out", "flow", "block_m", "block_p",
-                     "relu", "interpret"))
-def _band_conv_scheduled(x: Array, idx: Array, sel: Array, vr: Array,
-                         vi: Array, dfr: Array, dfi: Array, dvr: Array,
-                         dvi: Array, bias: Array, *,
-                         geo: SpectralGeometry, n_out: int, flow: str,
-                         block_m: int, block_p: int,
-                         relu: bool, interpret: bool) -> Array:
-    """``_fused_conv_scheduled`` returning the uncropped band canvas."""
-    b = x.shape[0]
-    xt, t_cnt = _windows_layout(x, geo)
-    y = fused_spectral_pipeline_scheduled(
-        xt, idx, sel, vr, vi, dfr, dfi, dvr, dvi, bias, n_out=n_out,
-        flow=flow, block_m=block_m, block_p=block_p, relu=relu,
-        interpret=interpret)
-    return _assemble_band_canvas(y, geo, b, n_out, t_cnt, x.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "flow", "block_n", "block_m", "block_p",
-                     "relu", "interpret"))
-def _band_conv_halo(x: Array, wr: Array, wi: Array, dfr: Array,
-                    dfi: Array, dvr: Array, dvi: Array, bias: Array, *,
-                    geo: SpectralGeometry, flow: str,
-                    block_n: int, block_m: int, block_p: int,
-                    relu: bool, interpret: bool) -> Array:
-    """``_fused_conv_halo`` returning the uncropped band canvas: the
-    halo pipeline already assembles tiles in canvas order, so the band
-    contract is the channel/padding crop WITHOUT the 'same' slice."""
-    n = wr.shape[1]
-    hg = halo_block_geometry(geo, block_p)
-    y = fused_spectral_pipeline_halo(
-        x, wr, wi, dfr, dfi, dvr, dvi, bias, geo=geo, hg=hg, flow=flow,
-        block_n=block_n, block_m=block_m, relu=relu, interpret=interpret)
-    return y[:, :n, :geo.h_pad, :geo.w_pad].astype(x.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("geo", "n_out", "flow", "block_m", "block_p",
-                     "relu", "interpret"))
-def _band_conv_scheduled_halo(x: Array, idx: Array, sel: Array,
-                              vr: Array, vi: Array, dfr: Array,
-                              dfi: Array, dvr: Array, dvi: Array,
-                              bias: Array, *, geo: SpectralGeometry,
-                              n_out: int, flow: str, block_m: int,
-                              block_p: int, relu: bool,
-                              interpret: bool) -> Array:
-    """``_fused_conv_scheduled_halo`` returning the uncropped band
-    canvas."""
-    hg = halo_block_geometry(geo, block_p)
-    y = fused_spectral_pipeline_scheduled_halo(
-        x, idx, sel, vr, vi, dfr, dfi, dvr, dvi, bias, geo=geo, hg=hg,
-        n_out=n_out, flow=flow, block_m=block_m, relu=relu,
-        interpret=interpret)
-    return y[:, :n_out, :geo.h_pad, :geo.w_pad].astype(x.dtype)
+    ``shortcut``: RAW [B, N, H_out, W_out] residual operand for plans
+    whose epilogue is residual-FUSED (``lp.epilogue.residual ==
+    'fused'``); the DAG executor passes the producer node's activation
+    and the kernel adds it after bias, before ReLU.
+    """
+    return _run_plan(x, lp, shortcut, interpret=interpret, crop=True)
 
 
 def execute_band_plan(x_ext: Array, lp, *, interpret: bool | None = None
@@ -1626,33 +994,6 @@ def execute_band_plan(x_ext: Array, lp, *, interpret: bool | None = None
     (``lax.ppermute`` inside the sharded executor; zeros on shard 0).
     Returns the UNCROPPED band canvas [B, N, tr*t, w_pad]: the 'same'
     crop is global, so it runs after the shards' canvases are
-    concatenated (``spectral.crop_canvas_same``).  Same fault sites and
-    dispatch as ``execute_layer_plan``.
+    concatenated (``spectral.crop_canvas_same``).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    tn = lp.tuning
-    halo = getattr(lp, "input_mode", "windowed") == "halo"
-    ctx = dict(layer=lp.layer.name, backend="fused", flow=tn.flow,
-               hadamard=getattr(lp, "hadamard", None),
-               input_mode=getattr(lp, "input_mode", "windowed"))
-    res.fault_check("lowering", **ctx)
-    res.fault_check("vmem_overflow", **ctx)
-    bias = lp.bias if lp.epilogue.bias else jnp.zeros_like(lp.bias)
-    if getattr(lp, "hadamard", None) == "scheduled":
-        tb = lp.tables
-        conv = _band_conv_scheduled_halo if halo else _band_conv_scheduled
-        y = conv(
-            x_ext, tb.idx, tb.sel, tb.vr, tb.vi,
-            lp.dfr, lp.dfi, lp.dvr, lp.dvi, bias, geo=lp.geo,
-            n_out=lp.layer.c_out, flow=tn.flow, block_m=tn.block_m,
-            block_p=tn.block_p, relu=lp.epilogue.relu,
-            interpret=interpret)
-        return res.fault_corrupt("nan_activations", y, **ctx)
-    conv = _band_conv_halo if halo else _band_conv
-    y = conv(x_ext, lp.wr, lp.wi, lp.dfr, lp.dfi, lp.dvr, lp.dvi,
-             bias, geo=lp.geo, flow=tn.flow,
-             block_n=tn.block_n, block_m=tn.block_m,
-             block_p=tn.block_p, relu=lp.epilogue.relu,
-             interpret=interpret)
-    return res.fault_corrupt("nan_activations", y, **ctx)
+    return _run_plan(x_ext, lp, None, interpret=interpret, crop=False)

@@ -23,6 +23,10 @@ Array = jax.Array
 
 
 def default_interpret() -> bool:
+    """The one place that decides how a kernel runs when the caller did
+    not say: compiled by Mosaic on a TPU backend (always), the Pallas
+    interpreter everywhere else (CPU tests).  Code that must not run
+    interpreted checks the platform itself (``chip_smoke.py``)."""
     return jax.default_backend() != "tpu"
 
 

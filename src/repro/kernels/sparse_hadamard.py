@@ -42,8 +42,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
+from repro.core.dataflow import TPU_VMEM_LIMIT_BYTES
 from repro.core.scheduler import ScheduleTables
 
 Array = jax.Array
@@ -131,8 +130,9 @@ def scheduled_sparse_hadamard(index_table: Array, sel: Array, valid: Array,
         out_shape=[jax.ShapeDtypeStruct((n_pe, f, xr.shape[2]),
                                         jnp.float32)] * 2,
         scratch_shapes=[pltpu.VMEM((n_pe, f, bp), jnp.float32)] * 2,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=TPU_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(index_table, sel, valid.astype(jnp.float32), val_r, val_i,
       out_index, xr, xi)

@@ -43,8 +43,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 from repro.core.dataflow import FLOWS
 
 Array = jax.Array
@@ -165,7 +163,7 @@ def spectral_hadamard(wr: Array, wi: Array, xr: Array, xi: Array, *,
         out_specs=[y_spec, y_spec],
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics),
         interpret=interpret,
     )(wr_, wi_, xr_, xi_)

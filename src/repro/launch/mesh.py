@@ -13,7 +13,7 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_shape_dict(mesh) -> dict[str, int]:
@@ -23,7 +23,15 @@ def mesh_shape_dict(mesh) -> dict[str, int]:
 def make_host_mesh(shape: tuple[int, ...] = (1, 1),
                    axes: tuple[str, ...] = ("data", "model")):
     """Mesh over however many (host) devices exist — tests/examples."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
+
+
+def _auto_mesh(shape, axes):
+    """The LM stack places activations with ``with_sharding_constraint``
+    and lets the partitioner resolve the rest, so its mesh axes are
+    Auto (``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_spectral_mesh(n_shards: int, axis: str = "shard"):

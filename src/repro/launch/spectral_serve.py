@@ -718,8 +718,12 @@ def synthetic_requests(n: int, cfg, *, seed: int = 0,
         for i in range(n)]
 
 
-def main() -> None:
+def main() -> int:
+    """Serve a synthetic burst; exit status 1 when any request ``failed``
+    or a tick of the serve loop died (shed and deadline-expired
+    requests are the server's designed outcomes, not failures)."""
     from repro.configs import vgg16_spectral
+    from repro.launch.compile_cache import enable_compile_cache
 
     ap = argparse.ArgumentParser(
         description="overload-resilient spectral serving front end "
@@ -735,6 +739,7 @@ def main() -> None:
                     help="write stats+health JSON to this path "
                          "('-' for stdout)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     srv = SpectralServer(vgg16_spectral.SMOKE, buckets=args.buckets,
                          queue_limit=args.queue_limit, seed=args.seed,
@@ -767,7 +772,13 @@ def main() -> None:
         else:
             with open(args.json, "w") as f:
                 f.write(payload + "\n")
+    failed = stats["counters"]["failed"] + stats["loop_deaths"]
+    if failed:
+        print(f"[spectral-serve] FAILED: {stats['counters']['failed']} "
+              f"failed request(s), {stats['loop_deaths']} loop death(s)",
+              file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -185,8 +185,9 @@ def forward_spectral(params: dict, plan, x: Array, *,
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}")
     if backend == "pallas_fused" and x.shape[0] != plan.batch:
-        on_hw = interpret is False or (interpret is None
-                                       and jax.default_backend() == "tpu")
+        from repro.kernels.ops import default_interpret
+        on_hw = not (default_interpret() if interpret is None
+                     else interpret)
         rmw = [lp.layer.name for lp in plan.layers
                if lp.tuning.flow != "output_stationary"]
         if on_hw and rmw:
@@ -231,11 +232,18 @@ def forward_spectral(params: dict, plan, x: Array, *,
                 refs[s] -= 1
                 if refs[s] == 0:
                     acts.pop(s, None)
-    x = acts[out_id]
+    return fc_head(params, acts[out_id])
+
+
+def fc_head(params: dict, x: Array) -> Array:
+    """The classifier on the flattened features: three dense layers, at
+    HIGHEST precision (a TPU rounds f32 matmul inputs to bf16 by
+    default, which the logits' 1e-4 parity bound does not allow)."""
+    hp = jax.lax.Precision.HIGHEST
     x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ params["fc1"])
-    x = jax.nn.relu(x @ params["fc2"])
-    return x @ params["fc3"]
+    x = jax.nn.relu(jnp.matmul(x, params["fc1"], precision=hp))
+    x = jax.nn.relu(jnp.matmul(x, params["fc2"], precision=hp))
+    return jnp.matmul(x, params["fc3"], precision=hp)
 
 
 def _conv_node(x: Array, lp, node, sc: Array | None, backend: str,
@@ -345,7 +353,4 @@ def forward_spatial(params: dict, cfg: SpectralCNNConfig, x: Array) -> Array:
                 y = jax.nn.relu(y)
         acts[s.id] = y
     x = acts[graph_sink(order)]
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ params["fc1"])
-    x = jax.nn.relu(x @ params["fc2"])
-    return x @ params["fc3"]
+    return fc_head(params, x)

@@ -171,7 +171,6 @@ def forward_ep(params: dict, cfg: MoEConfig, x: Array, *, mesh,
     psum('model') per layer combines — total cross-shard traffic is one
     [G, S, d] all-reduce instead of buffer-wide scatter collectives."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     e = cfg.n_experts
     m_ways = 1
@@ -194,12 +193,12 @@ def forward_ep(params: dict, cfg: MoEConfig, x: Array, *, mesh,
         return jax.lax.psum(y_part, model_axis)
 
     wa = tuple(fsdp_axes) if fsdp_axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(), P(model_axis, wa, None), P(model_axis, wa, None),
                   P(model_axis, None, wa), P(data_axes, None, None)),
         out_specs=P(data_axes, None, None),
-        check_rep=False)
+        check_vma=False)
     y = fn(params["router"], params["w_gate"], params["w_up"],
            params["w_down"], x)
     return y, {"lb_loss": jnp.zeros(()), "dropped_frac": jnp.zeros(())}

@@ -196,7 +196,7 @@ def corrupt_plan_tables(plan, *, layer: str | None = None,
 
     import jax.numpy as jnp
 
-    from repro.core.plan import PlanTables
+    from repro.core.plan import PlanTables, stack_shard_operands
 
     mutate = _DEFAULT_CORRUPT[kind]
     new_layers = []
@@ -384,7 +384,7 @@ def corrupt_shard_tables(splan, *, layer: str | None = None,
 
     import jax.numpy as jnp
 
-    from repro.core.plan import PlanTables
+    from repro.core.plan import PlanTables, stack_shard_operands
 
     mutate = _DEFAULT_CORRUPT[kind]
     new_layers = []
@@ -404,7 +404,10 @@ def corrupt_shard_tables(splan, *, layer: str | None = None,
                 tb = PlanTables(tb.idx, tb.sel,
                                 jnp.asarray(mutate(tb.vr)), tb.vi)
             shards[shard] = dataclasses.replace(sh, tables=tb)
-            slp = dataclasses.replace(slp, shards=tuple(shards))
+            slp = dataclasses.replace(
+                slp, shards=tuple(shards),
+                operands=(stack_shard_operands(shards)
+                          if slp.strategy == "channel" else ()))
             done = True
         new_layers.append(slp)
     if not done:

@@ -25,7 +25,8 @@ SCRIPT = textwrap.dedent("""
     from repro.roofline import hlo_parse
     from repro.roofline.analysis import cost_analysis_dict
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     msd = {"data": 2, "model": 4}
     cfg = configs.get_smoke_config("qwen3-8b").replace(remat=True)
     shape = configs.ShapeConfig("t", seq_len=32, global_batch=4,

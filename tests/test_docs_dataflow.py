@@ -73,21 +73,23 @@ class TestDocFormulasMatchCode:
         nnz = max(1, round(k2 / alpha))
         bn = min(block_n, layer.c_out)
         bm = min(block_m, layer.c_in)
+        if bm == layer.c_in:                   # full extent: 8 sublanes
+            bm = -(-bm // df.SUBLANES) * df.SUBLANES
         gn = max(1, -(-layer.c_out // block_n))
         gm = max(1, -(-layer.c_in // block_m))
-        if imode == "halo":
-            gp = max(1, batch * hg.n_blocks)   # the actual p grid
-        else:
-            gp = max(1, -(-T // block_p))
+        # the actual p grid and its lanes (lane-padded tile axis)
+        bp, gp = df.kernel_lanes(layer, fft, block_p, imode, batch)
         mp = gm * bm
 
         x = self.ns["input_bytes"](
             flow, layer.c_in, layer.h_in, layer.w_in, fft, T, batch,
-            imode, hg.nbh, hg.nbw, hg.rh, hg.rw, hg.bth, hg.btw, gn, gm)
+            imode, hg.nbh, hg.nbw, hg.rh, hg.rw, hg.bth,
+            df.lane_round(hg.btw), gn, gm, mp, gp * bp)
         w = self.ns["kernel_bytes"](
             flow, layer.c_out, layer.c_in, fft, k2, nnz,
             df.SCHEDULE_MU, df.SCHEDULE_R, bn, mp, gp, mode)
-        y = self.ns["output_bytes"](flow, layer.c_out, geo.tile, T, gm)
+        y = self.ns["output_bytes"](flow, layer.c_out, geo.tile,
+                                    gp * bp, gm)
 
         assert x == pytest.approx(c["input_hbm_bytes"]), "input share"
         assert w == pytest.approx(c["kernel_hbm_bytes"]), "kernel share"

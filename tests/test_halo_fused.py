@@ -150,6 +150,7 @@ class TestRepricedCostModel:
     def test_halo_input_bytes_below_windowed_all_layers(self):
         """Acceptance: raw-plus-halo input words beat the materialized
         window stream on every VGG16 layer and flow."""
+        lower = set()
         for layer in df.VGG16_LAYERS:
             for flow in df.FLOWS:
                 w = df.tpu_fused_flow_cost(layer, 8, 4.0, 64, 128, 64,
@@ -158,7 +159,19 @@ class TestRepricedCostModel:
                                            flow, input_mode="halo")
                 assert (h["input_hbm_bytes"]
                         < w["input_hbm_bytes"]), (layer.name, flow)
-                assert h["hbm_bytes"] < w["hbm_bytes"], (layer.name, flow)
+                # the rest of halo's traffic is its tile stream, padded
+                # to a lane tile per tile row: never below windowed's
+                assert (h["hbm_bytes"] - h["input_hbm_bytes"]
+                        >= w["hbm_bytes"] - w["input_hbm_bytes"]), (
+                    layer.name, flow)
+                if h["hbm_bytes"] < w["hbm_bytes"]:
+                    lower.add((layer.name, flow))
+        # in total, halo moves fewer bytes only where the lane padding
+        # costs less than the window stream saves: conv1_2 (224 wide)
+        # and the kernel-bound conv4/conv5 under output-stationary
+        assert lower == ({("conv1_2", f) for f in df.FLOWS}
+                         | {(f"conv{i}_{j}", "output_stationary")
+                            for i in (4, 5) for j in (1, 2, 3)})
 
     def test_input_share_accounted(self):
         """input + kernel shares never exceed the total."""
@@ -189,12 +202,18 @@ class TestRepricedCostModel:
 
 class TestInputModeAutotune:
     def test_axis_picks_halo_on_vgg16(self):
-        """With both modes offered, the repriced input bytes make halo
-        the winner on every VGG16 layer."""
-        for layer in df.VGG16_LAYERS:
-            tn = autotune.autotune_layer(
-                layer, 8, 4.0, input_modes=df.INPUT_MODES)
-            assert tn.input_mode == "halo", layer.name
+        """With both modes offered, Alg 1 ranks halo against windowed
+        per layer.  Priced on the TPU's (8, 128) tiling, halo pads each
+        tile row to a 128-lane tile (38 of 128 lanes used at 224x224, 5
+        at 28x28), and windowed wins every VGG16 layer at batch 1 and
+        8.  Before the padding was priced, halo won all 13."""
+        for batch in (1, 8):
+            picked = {layer.name: autotune.autotune_layer(
+                layer, 8, 4.0, batch=batch,
+                input_modes=df.INPUT_MODES).input_mode
+                for layer in df.VGG16_LAYERS}
+            assert picked == {layer.name: "windowed"
+                              for layer in df.VGG16_LAYERS}, batch
 
     def test_ws_halo_eligible_at_batch_gt_1(self):
         """Manual-DMA accumulators (PR 8) lift the batch-1 limit on halo

@@ -89,7 +89,7 @@ def test_dma_accumulator_geometry_validated(mini_plan):
     split = dataclasses.replace(
         lp, tuning=dataclasses.replace(lp.tuning,
                                        flow="weight_stationary",
-                                       block_p=1))
+                                       block_p=lp.geo.n_tiles_w))
     diags = res.validate_layer_plan(split)
     assert not [d for d in diags if d.severity == "error"]
 

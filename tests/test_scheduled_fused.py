@@ -248,12 +248,22 @@ class TestModeAwareAutotune:
 
     def test_late_layers_prefer_scheduled_early_prefer_planes(self):
         """The per-layer flexibility story: kernel-bound late layers
-        pick the table stream, activation-bound early layers keep the
-        plane GEMM."""
-        modes = {}
+        gain most from the table stream (over 1.5x on conv5_1); on the
+        activation-bound first layer the two streams are within a few
+        percent, so the Hadamard mode hardly matters there.  Priced on
+        the (8, 128) tiling, the tables win by a hair even there, so
+        Alg 1 picks them on all 13 layers (conv1_2 had kept the planes
+        before the padding was priced)."""
+        modes, ratio = {}, {}
         for layer in df.VGG16_LAYERS:
             tn = autotune.autotune_layer(
                 layer, 8, 4.0, hadamard_modes=("bin", "scheduled"))
             modes[layer.name] = tn.hadamard
-        assert modes["conv5_1"] == "scheduled"
-        assert modes["conv1_2"] == "bin"
+            by = {m: autotune.autotune_layer(
+                layer, 8, 4.0, hadamard_modes=(m,)).predicted_s
+                for m in ("bin", "scheduled")}
+            ratio[layer.name] = by["scheduled"] / by["bin"]
+        assert modes == {layer.name: "scheduled"
+                         for layer in df.VGG16_LAYERS}
+        assert ratio["conv5_1"] < 2 / 3
+        assert 0.9 < ratio["conv1_1"] < 1.1

@@ -217,13 +217,22 @@ def test_sharded_parity_subprocess_smoke():
         base = pl.build_network_plan(params, Cfg, batch=2)
         ref = cnn.forward_spectral(params, base, x, backend="einsum")
         for D, strats in [(4, ("channel",)), (2, ("spatial",))]:
+            mesh = make_spectral_mesh(D)
             splan = pl.build_sharded_network_plan(
-                params, Cfg, n_shards=D, batch=2, strategies=strats)
-            y = forward_spectral_sharded(
-                params, splan, x, mesh=make_spectral_mesh(D),
-                interpret=True)
+                params, Cfg, n_shards=D, batch=2, strategies=strats,
+                mesh=mesh)
+            y = forward_spectral_sharded(params, splan, x, mesh=mesh,
+                                         interpret=True)
             err = float(jnp.abs(y - ref).max())
             assert err <= 1e-5, (strats, err)
+            # channel kernels are placed at build: one slice per device
+            placed = [a for slp in splan.layers for a in slp.operands]
+            assert bool(placed) == (strats == ("channel",))
+            for a in placed:
+                assert {s.device for s in a.addressable_shards} == set(
+                    mesh.devices.flat)
+                assert all(s.data.shape[0] == 1
+                           for s in a.addressable_shards)
         print("SHARDED_PARITY_OK")
     """)
     r = subprocess.run([sys.executable, "-c", script],
@@ -399,6 +408,27 @@ class TestMeshCacheKey:
         assert cache.get({}, TinyCfg, 1) == a
         assert len(built) == 3
         assert cache.stats()["hits"] == 2
+
+    def test_sharded_plan_reuses_a_given_base(self):
+        """A base plan built once serves every strategy's sharded plan
+        (full-size builds take minutes); the partitioning is the same
+        as a fresh build's, and a base of another batch is refused."""
+        params, _ = _tiny_params(jax.random.PRNGKey(0))
+        base = pl.build_network_plan(params, TinyCfg, batch=2)
+        for strategies in (("channel",), ("spatial",), None):
+            splan = pl.build_sharded_network_plan(
+                params, TinyCfg, n_shards=2, batch=2,
+                strategies=strategies, base=base)
+            fresh = pl.build_sharded_network_plan(
+                params, TinyCfg, n_shards=2, batch=2,
+                strategies=strategies)
+            assert splan.base is base
+            assert all(s.base is lp
+                       for s, lp in zip(splan.layers, base.layers))
+            assert splan.strategies == fresh.strategies
+        with pytest.raises(ValueError, match="batch"):
+            pl.build_sharded_network_plan(params, TinyCfg, n_shards=2,
+                                          batch=1, base=base)
 
     def test_server_threads_mesh_shape(self):
         """SpectralServer must key every cache access by its mesh."""
