@@ -43,6 +43,7 @@ geometry work ever runs inside (or between) jitted steps.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Sequence
 
 import jax
@@ -53,6 +54,7 @@ from repro.core import autotune as at
 from repro.core import dataflow as df
 from repro.core import resilience as res
 from repro.core import scheduler as sch
+from repro.core import spans
 from repro.core import sparse as sp
 from repro.core import spectral as spec
 
@@ -364,7 +366,8 @@ class NetworkPlan:
     ``build_network_plan`` always populates it (linear configs get the
     synthesized chain).  Plans constructed by hand with ``graph=()``
     fall back to the chain derived from ``layers`` + the epilogue pool
-    flags via ``execution_graph``.
+    flags via ``execution_graph``.  ``phase_s`` holds the host seconds
+    ``build_network_plan`` spent in each of ``spans.PLAN_PHASES``.
     """
 
     name: str
@@ -372,6 +375,7 @@ class NetworkPlan:
     batch: int                        # batch the autotune assumed
     layers: tuple[LayerPlan, ...]
     graph: tuple[PlanNode, ...] = ()
+    phase_s: dict = dataclasses.field(default_factory=dict)
 
     @property
     def tuning(self) -> dict[str, at.FusedTuning]:
@@ -521,6 +525,16 @@ def _resolve_input_modes(input_mode: str) -> list[str]:
         f"got {input_mode!r}")
 
 
+def _plan_build_span(build):
+    """Run ``build`` inside the ``plan.build`` span (arg ``batch``)."""
+    @functools.wraps(build)
+    def traced(params, cfg, *, batch: int = 1, **kwargs):
+        with spans.span(spans.PLAN_BUILD, batch=batch):
+            return build(params, cfg, batch=batch, **kwargs)
+    return traced
+
+
+@_plan_build_span
 def build_network_plan(params: dict, cfg, *,
                        batch: int = 1,
                        prune: str = "magnitude",
@@ -600,6 +614,7 @@ def build_network_plan(params: dict, cfg, *,
     alphas = sp.per_layer_alphas(cfg.alpha, len(layers))
     pool_after = getattr(cfg, "pool_after", frozenset())
     k2 = cfg.fft_size * cfg.fft_size
+    phase_s: dict[str, float] = {}
 
     # --- DAG plan IR (ISSUE 10): resolve + topo-order the node graph.
     # Linear configs get the synthesized chain, so every plan carries a
@@ -623,25 +638,28 @@ def build_network_plan(params: dict, cfg, *,
     for layer, conv, alpha in zip(layers, params["convs"], alphas):
         geo = spec.make_geometry(layer.h_in, layer.w_in, layer.ksize,
                                  cfg.fft_size, layer.pad)
-        w_f = spec.spectral_kernel(conv["w"], cfg.fft_size)
-        sk = prune_fn(w_f, alpha)
+        with spans.counted(phase_s, "prune", layer=layer.name):
+            w_f = spec.spectral_kernel(conv["w"], cfg.fft_size)
+            sk = prune_fn(w_f, alpha)
+            active = sp.compacted_active_bins(sk)
+            wr, wi = sp.compact_planes(sk, active)
 
         cycles = mu = None
         if schedule and alpha > 1.0:
-            cycles, mu, sampled_bins = _sampled_schedule_stats(
-                sk, k2, r=schedule_r, n_par=schedule_n_par,
-                channel_sample=schedule_channel_sample)
-            full = np.asarray(sk.active_bins)
+            with spans.counted(phase_s, "schedule_stats", layer=layer.name):
+                cycles, mu, sampled_bins = _sampled_schedule_stats(
+                    sk, k2, r=schedule_r, n_par=schedule_n_par,
+                    channel_sample=schedule_channel_sample)
+                full = np.asarray(sk.active_bins)
             if not np.isin(sampled_bins, full).all():
                 raise res.PlanValidationError(
                     f"Alg-2 schedule for {layer.name} touched a "
                     f"frequency bin outside the pruned kernel support",
                     layer=layer.name, site="schedule-stats")
 
-        active = sp.compacted_active_bins(sk)
-        wr, wi = sp.compact_planes(sk, active)
-        ops = jnp.asarray  # device placement of the numpy operators
-        dfr, dfi, dvr, dvi = (ops(a) for a in _operators(geo, active))
+        with spans.counted(phase_s, "operators", layer=layer.name):
+            ops = jnp.asarray  # device placement of the numpy operators
+            dfr, dfi, dvr, dvi = (ops(a) for a in _operators(geo, active))
 
         measure_fn = None
         if measure:
@@ -670,16 +688,18 @@ def build_network_plan(params: dict, cfg, *,
                 step_overhead_s=step_overhead_s,
                 residual=residual, measure_fn=measure_fn)
 
-        if residual_mode == "fused":
-            # ShortcutFusion reuse decision: hold the shortcut on-chip
-            # (retained VMEM bytes) when the working set still fits the
-            # budget, else re-read it from HBM on the flush path.
-            tuning = _tune(residual="vmem")
-            if tuning.vmem_bytes > vmem_budget:
-                tuning = _tune(residual="hbm")
-            shortcut_on_chip[layer.name] = tuning.residual == "vmem"
-        else:
-            tuning = _tune()
+        with spans.counted(phase_s, "autotune", layer=layer.name):
+            if residual_mode == "fused":
+                # ShortcutFusion reuse decision: hold the shortcut
+                # on-chip (retained VMEM bytes) when the working set
+                # still fits the budget, else re-read it from HBM on the
+                # flush path.
+                tuning = _tune(residual="vmem")
+                if tuning.vmem_bytes > vmem_budget:
+                    tuning = _tune(residual="hbm")
+                shortcut_on_chip[layer.name] = tuning.residual == "vmem"
+            else:
+                tuning = _tune()
 
         tables = None
         if tuning.hadamard == "scheduled":
@@ -688,14 +708,16 @@ def build_network_plan(params: dict, cfg, *,
             # remapped to the compacted coordinates of the operators
             # above.  Group size == the tuned block_n; channel padding
             # == the tuned block_m.
-            lt = sch.compile_layer_tables(
-                np.asarray(sk.indices),
-                np.asarray(sk.values).reshape(layer.c_out, layer.c_in,
-                                              k2),
-                k2, schedule_r, min(tuning.block_n, layer.c_out),
-                active=active, m_pad_to=min(tuning.block_m, layer.c_in))
-            tables = PlanTables(jnp.asarray(lt.idx), jnp.asarray(lt.sel),
-                                jnp.asarray(lt.vr), jnp.asarray(lt.vi))
+            with spans.counted(phase_s, "tables", layer=layer.name):
+                lt = sch.compile_layer_tables(
+                    np.asarray(sk.indices),
+                    np.asarray(sk.values).reshape(layer.c_out, layer.c_in,
+                                                  k2),
+                    k2, schedule_r, min(tuning.block_n, layer.c_out),
+                    active=active, m_pad_to=min(tuning.block_m, layer.c_in))
+                tables = PlanTables(
+                    jnp.asarray(lt.idx), jnp.asarray(lt.sel),
+                    jnp.asarray(lt.vr), jnp.asarray(lt.vi))
             cycles, mu = lt.total_cycles, lt.pe_utilization  # exact
 
         # On the 'add' rung the kernel flushes bias-only output and the
@@ -729,9 +751,11 @@ def build_network_plan(params: dict, cfg, *,
         for s in order)
     net = NetworkPlan(name=getattr(cfg, "name", "spectral-cnn"),
                       fft_size=cfg.fft_size, batch=batch,
-                      layers=tuple(plans), graph=pnodes)
+                      layers=tuple(plans), graph=pnodes, phase_s=phase_s)
     if validate:
-        res.validate_plan(net, vmem_budget=vmem_budget, hw_safe=hw_safe)
+        with spans.counted(phase_s, "validate"):
+            res.validate_plan(net, vmem_budget=vmem_budget,
+                              hw_safe=hw_safe)
     return net
 
 
@@ -790,16 +814,17 @@ class PlanCache:
     """Keyed, warmable cache of compile-once NetworkPlans.
 
     ``build_network_plan`` is the expensive offline step (~2 minutes on
-    full VGG16: prune + Alg-2 tables + compaction + autotune — see
-    ``plan_build_s`` in BENCH_e2e.json); a serving front end cannot
-    afford it on the request path.  The cache keys plans by
+    full VGG16: prune + Alg-2 tables + compaction + autotune); a serving
+    front end cannot afford it on the request path.  The cache keys plans by
     ``plan_cache_key(cfg, batch)`` and is *warmed* at server startup
     for every batch bucket, so no request ever pays a plan build.
 
     ``invalidate(key)`` drops one entry (e.g. after the serving layer
     detected a corrupted plan) so the next ``get`` rebuilds it; the
-    hit/miss/build/invalidation counters and cumulative build seconds
-    are surfaced via ``stats()`` for the serve-level health report.
+    hit/miss/build/invalidation counters, cumulative build seconds and
+    their split by plan phase (``phase_s``: the plans' ``phase_s``
+    summed, see ``spans.PLAN_PHASES``) are surfaced via ``stats()`` for
+    the serve-level health report.
 
     ``builder`` is injectable for tests (defaults to
     ``build_network_plan``); extra ``get`` kwargs are forwarded to it.
@@ -812,6 +837,7 @@ class PlanCache:
     builds: int = 0
     invalidations: int = 0
     build_s: float = 0.0
+    phase_s: dict = dataclasses.field(default_factory=dict)
 
     def warm(self, params: dict, cfg, batches: Sequence[int],
              mesh_shape: Sequence[int] | None = None,
@@ -852,6 +878,8 @@ class PlanCache:
         plan = builder(params, cfg, batch=batch, **build_kwargs)
         self.build_s += _time.perf_counter() - t0
         self.builds += 1
+        for phase, sec in getattr(plan, "phase_s", {}).items():
+            self.phase_s[phase] = self.phase_s.get(phase, 0.0) + sec
         self._plans[key] = plan
         return plan
 
@@ -870,7 +898,7 @@ class PlanCache:
         return {"entries": len(self._plans), "hits": self.hits,
                 "misses": self.misses, "builds": self.builds,
                 "invalidations": self.invalidations,
-                "build_s": self.build_s}
+                "build_s": self.build_s, "phase_s": dict(self.phase_s)}
 
 
 # ---------------------------------------------------------------------------

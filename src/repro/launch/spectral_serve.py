@@ -23,7 +23,8 @@ Four mechanisms compose:
      {1, 2, 4, 8}) that fits, padded with zero images, and executed
      with a ``NetworkPlan`` cached per (config, alpha, bucket) in a
      ``core.plan.PlanCache`` warmed at startup — no request ever pays
-     ``plan_build_s`` (~2 min on full VGG16, see BENCH_e2e.json).
+     the plan build (``plan_build_s``: 133-138 s per bucket for full
+     VGG16 and ResNet-18 on a TPU v5e host, see PERF.md).
      Plans are tuned *at* their bucket's batch with the interpret-mode
      per-step overhead priced in (``dataflow.INTERPRET_STEP_S``), so
      the batch-8 bucket gets batch-8 blocks instead of inheriting
@@ -68,6 +69,29 @@ Run a synthetic burst from the CLI::
 Timing is injectable (``clock=``, any zero-arg callable returning
 seconds; ``ManualClock`` for deterministic tests) so deadlines, breaker
 cooldowns and the ladder are all testable without wall-clock sleeps.
+
+Tracing.  The server, the forward walk and the plan build always carry
+profiler spans (``repro.core.spans``; ~1 us each while no trace is
+taken).  ``jax.profiler.start_trace(dir)`` ... ``stop_trace()`` around a
+running server captures them on the host timeline beside the chip's
+executions:
+
+  ``serve.submit`` (``rid``);
+  ``serve.tick`` (``rid`` of the batch's first request, ``n``,
+  ``bucket``, ``rung``), holding ``serve.take``, ``serve.upload``
+  (``staged_hit``), ``serve.plan``, ``serve.forward``,
+  ``serve.stage_next``, ``serve.readback`` and ``serve.finish``;
+  inside ``serve.forward`` one ``forward.node`` per graph node
+  (``node``, ``kind``; conv nodes also ``hadamard``, ``flow``,
+  ``input_mode``, ``residual``, ``backend``, ``predicted_us``) and
+  ``forward.fc_head``.
+
+A device execution belongs to the ``forward.node`` span in which the
+host enqueued it (its ``run_id``).  The plan build is not on the request
+path; its seconds per phase (prune, operators, schedule_stats,
+autotune, tables, validate) are counted in
+``health_report()["plan_cache"]["phase_s"]`` and ``stats()
+["plan_phase_s"]``.
 """
 
 from __future__ import annotations
@@ -86,6 +110,7 @@ import numpy as np
 
 from repro.core import dataflow as df
 from repro.core import resilience as res
+from repro.core import spans
 from repro.core.plan import PlanCache, plan_cache_key
 from repro.models import cnn
 
@@ -297,27 +322,28 @@ class SpectralServer:
         Returns the request with either ``submitted_at`` set (queued)
         or a terminal ``overloaded`` / ``failed`` code.
         """
-        now = self._now()
-        req.submitted_at = now
-        if self._first_submit_t is None:
-            self._first_submit_t = now
-        if req.deadline_s is None:
-            req.deadline_s = self.default_deadline_s
-        self.counters["submitted"] += 1
-        img = np.asarray(req.image, np.float32)
-        if img.shape != self.image_shape:
-            self._finish(req, "failed",
-                         error=f"bad_request: image shape {img.shape} "
-                               f"!= {self.image_shape}")
+        with spans.span(spans.SERVE_SUBMIT, rid=req.rid):
+            now = self._now()
+            req.submitted_at = now
+            if self._first_submit_t is None:
+                self._first_submit_t = now
+            if req.deadline_s is None:
+                req.deadline_s = self.default_deadline_s
+            self.counters["submitted"] += 1
+            img = np.asarray(req.image, np.float32)
+            if img.shape != self.image_shape:
+                self._finish(req, "failed",
+                             error=f"bad_request: image shape {img.shape} "
+                                   f"!= {self.image_shape}")
+                return req
+            req.image = img
+            if len(self.queue) >= self.queue_limit:
+                self._finish(req, "overloaded",
+                             error=f"queue full ({len(self.queue)}/"
+                                   f"{self.queue_limit}); request shed")
+                return req
+            self.queue.append(req)
             return req
-        req.image = img
-        if len(self.queue) >= self.queue_limit:
-            self._finish(req, "overloaded",
-                         error=f"queue full ({len(self.queue)}/"
-                               f"{self.queue_limit}); request shed")
-            return req
-        self.queue.append(req)
-        return req
 
     def _finish(self, req: InferenceRequest, code: str, *,
                 error: str | None = None, rung: str | None = None,
@@ -501,13 +527,15 @@ class SpectralServer:
         double-buffered dispatch path consumes a copy started while the
         previous batch's kernels were still running."""
         key = (tuple(r.rid for r in batch), bucket)
-        if self._staged is not None and self._staged["key"] == key:
-            self.counters["staged_hits"] += 1
-            xj = self._staged["xj"]
-        else:
-            xj = jax.device_put(self._pad_batch(batch, bucket))
-        self._staged = None
-        return xj
+        hit = self._staged is not None and self._staged["key"] == key
+        with spans.span(spans.SERVE_UPLOAD, staged_hit=hit):
+            if hit:
+                self.counters["staged_hits"] += 1
+                xj = self._staged["xj"]
+            else:
+                xj = jax.device_put(self._pad_batch(batch, bucket))
+            self._staged = None
+            return xj
 
     def _stage_next(self) -> None:
         """Peek (don't pop) the head of the queue and start uploading
@@ -532,7 +560,8 @@ class SpectralServer:
         or None when even the terminal rung failed (requests then carry
         a ``failed`` response — still a terminal outcome)."""
         xj = self._upload(batch, bucket)
-        plan, force_einsum = self._fetch_plan(bucket)
+        with spans.span(spans.SERVE_PLAN):
+            plan, force_einsum = self._fetch_plan(bucket)
         if force_einsum:
             order = [len(SERVE_RUNGS) - 1]
         else:
@@ -548,18 +577,21 @@ class SpectralServer:
                 res.fault_check("serve_kernel", backend=backend,
                                 bucket=bucket)
                 t0 = time.perf_counter()
-                if force_einsum:
-                    y = cnn.forward_spectral(self.params, plan, xj,
-                                             backend="einsum")
-                else:
-                    y = cnn.forward_spectral(
-                        self.params, self._variant(plan, bucket, r), xj,
-                        backend="pallas_fused", interpret=self.interpret,
-                        guards=self.guards)
+                with spans.span(spans.SERVE_FORWARD):
+                    if force_einsum:
+                        y = cnn.forward_spectral(self.params, plan, xj,
+                                                 backend="einsum")
+                    else:
+                        y = cnn.forward_spectral(
+                            self.params, self._variant(plan, bucket, r),
+                            xj, backend="pallas_fused",
+                            interpret=self.interpret, guards=self.guards)
                 # kernels are dispatched but not awaited: start the next
                 # batch's upload now so the copy rides under them
-                self._stage_next()
-                y = np.asarray(jax.block_until_ready(y))
+                with spans.span(spans.SERVE_STAGE_NEXT):
+                    self._stage_next()
+                with spans.span(spans.SERVE_READBACK):
+                    y = np.asarray(jax.block_until_ready(y))
                 dt = time.perf_counter() - t0
             except Exception as e:      # noqa: BLE001 — isolation edge
                 self.counters["kernel_faults"] += 1
@@ -570,24 +602,26 @@ class SpectralServer:
                 _LOG.error("[spectral-serve] bucket %d failed on rung "
                            "%s: %s", bucket, backend, errors[-1])
                 continue
-            extra = float(res.fault_corrupt("serve_slow", 0.0,
-                                            backend=backend,
-                                            bucket=bucket))
-            if extra:
-                self.counters["slow_injections"] += 1
-                if hasattr(self.clock, "advance"):
-                    self.clock.advance(extra)
-                dt += extra
-            if brk is not None:
-                brk.record_success()
-            self._note_service(backend, dt)
-            done = self._now()
-            for i, req in enumerate(batch):
-                req.logits = y[i]
-                self._finish(req, "ok", rung=backend, completed_at=done)
-            self.served_by[backend] += len(batch)
-            self.batches += 1
-            return backend
+            with spans.span(spans.SERVE_FINISH):
+                extra = float(res.fault_corrupt("serve_slow", 0.0,
+                                                backend=backend,
+                                                bucket=bucket))
+                if extra:
+                    self.counters["slow_injections"] += 1
+                    if hasattr(self.clock, "advance"):
+                        self.clock.advance(extra)
+                    dt += extra
+                if brk is not None:
+                    brk.record_success()
+                self._note_service(backend, dt)
+                done = self._now()
+                for i, req in enumerate(batch):
+                    req.logits = y[i]
+                    self._finish(req, "ok", rung=backend,
+                                 completed_at=done)
+                self.served_by[backend] += len(batch)
+                self.batches += 1
+                return backend
         msg = "; ".join(errors) or "no execution rung available"
         for req in batch:
             self._finish(req, "failed", error=msg)
@@ -599,15 +633,19 @@ class SpectralServer:
         """One serve step: expire deadlines, update the load ladder,
         form one bucket batch and execute it.  Returns the number of
         requests served a terminal outcome this tick."""
-        self._ticks += 1
-        now = self._now()
-        self._update_ladder(now)
-        batch = self._take_batch(now)
-        if not batch:
-            return 0
-        bucket = self._bucket_for(len(batch))
-        self._execute(batch, bucket)
-        return len(batch)
+        with spans.span(spans.SERVE_TICK) as tick_span:
+            self._ticks += 1
+            now = self._now()
+            with spans.span(spans.SERVE_TAKE):
+                self._update_ladder(now)
+                batch = self._take_batch(now)
+            if not batch:
+                return 0
+            bucket = self._bucket_for(len(batch))
+            rung = self._execute(batch, bucket)
+            tick_span.set_metadata(rid=batch[0].rid, n=len(batch),
+                                   bucket=bucket, rung=rung)
+            return len(batch)
 
     def run_until_drained(self, max_ticks: int = 10_000,
                           cooldown_ticks: int | None = None) -> dict:
@@ -653,6 +691,7 @@ class SpectralServer:
             "served_by_rung": dict(self.served_by),
             "demotions": self.n_demotions,
             "promotions": self.n_promotions,
+            "plan_phase_s": self.plans.stats()["phase_s"],
         }
         if lat.size:
             out["latency_ms"] = {

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import dataflow as df
 from repro.core import resilience as res
+from repro.core import spans
 from repro.core import sparse as sp
 from repro.core import spectral as spec
 from repro.models import layers as L
@@ -181,6 +182,9 @@ def forward_spectral(params: dict, plan, x: Array, *,
     derived at plan-build time; nothing (geometry, schedules, pruning,
     table compilation, autotune) is rebuilt here, so repeated calls go
     straight to the jit cache.
+
+    Each node's dispatch runs inside a ``forward.node`` profiler span
+    and the head inside ``forward.fc_head`` (``core.spans``).
     """
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}")
@@ -214,7 +218,8 @@ def forward_spectral(params: dict, plan, x: Array, *,
     for node in graph:
         src = acts[node.inputs[0]]
         if node.kind == "pool":
-            y = _pool(src, node.pool)
+            with spans.span(spans.FORWARD_NODE, node=node.id, kind="pool"):
+                y = _pool(src, node.pool)
         else:
             lp = plan.layers[node.layer_index]
             if src.shape[1:] != (lp.layer.c_in, lp.layer.h_in,
@@ -225,14 +230,24 @@ def forward_spectral(params: dict, plan, x: Array, *,
                     f"{lp.layer.w_in}], got {src.shape}")
             sc = (acts[node.residual_from]
                   if node.residual_from is not None else None)
-            y = _conv_node(src, lp, node, sc, backend, interpret, guards)
+            with spans.span(
+                    spans.FORWARD_NODE, node=node.id, kind="conv",
+                    hadamard=lp.hadamard, flow=lp.tuning.flow,
+                    input_mode=lp.input_mode,
+                    residual=getattr(lp.epilogue, "residual", None),
+                    backend=(getattr(lp, "backend", "fused")
+                             if backend == "pallas_fused" else backend),
+                    predicted_us=lp.tuning.predicted_s * 1e6):
+                y = _conv_node(src, lp, node, sc, backend, interpret,
+                               guards)
         acts[node.id] = y
         for s in (node.inputs[0], node.residual_from):
             if s is not None:
                 refs[s] -= 1
                 if refs[s] == 0:
                     acts.pop(s, None)
-    return fc_head(params, acts[out_id])
+    with spans.span(spans.FORWARD_FC_HEAD):
+        return fc_head(params, acts[out_id])
 
 
 def fc_head(params: dict, x: Array) -> Array:
