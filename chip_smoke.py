@@ -24,8 +24,10 @@ each sharded forward is compared with the one-chip fused forward within
 device, and every device must hold data.  The four forwards are jitted
 with the plans' arrays as arguments and compiled concurrently.  The
 plans are built without Alg-2 schedules (plane Hadamard), which keeps
-host plan building under a minute; the one-chip run covers the
-scheduled datapath.
+host plan building under a minute.  At batch 1 Alg 1 streams planes on
+the one chip too (the in-kernel table expansion costs more than the
+planes' bytes); ``tests/test_tpu_compile.py`` compiles the scheduled
+datapath for the chip.
 
 Everything runs in this one process.  Without a TPU the script exits 1
 and prints no result: it never falls back to the CPU.  The seconds it

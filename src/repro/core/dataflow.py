@@ -326,6 +326,23 @@ def layer_latency_budget(layers: Iterable[ConvLayer], fft_size: int,
 # TPU v5e-class constants (also used by repro.roofline).
 TPU_HBM_GBPS = 819e9
 TPU_PEAK_FLOPS = 197e12
+# Seconds per element of the 'scheduled' datapath's in-kernel table
+# expansion (``fused_spectral_conv._TableBlock.read``).  Every grid step
+# rebuilds its [bm, Fa, N'] kernel planes once per schedule cycle, with
+# N' on the lanes padded to whole lane tiles: t_cyc * bm * Fa *
+# lane_round(N') elements per step.  Per element, the one-hot
+# [Fa, r] @ [r, N'] dot fills r of the MXU's 128 rows of depth and runs
+# 6 bf16 passes at Precision.HIGHEST, 2 * 128 * 6 / TPU_PEAK_FLOPS =
+# 7.8 ps; the VPU compares, the two weight FMAs and the VMEM round trip
+# of the [bm, Fa, N'] carry make up the rest.  Fitted on a TPU v5e to
+# the kernel time per node of spectral VGG16 and ResNet-18 at batch 1,
+# all 33 conv nodes on tables (least squares of time = rate * elements
+# + 11.1 ns per (n, m, p-block) kernel visit, t_cyc the model's own
+# estimate): 9.8 ps.  So a 512->512 node spends 3.1 of its 6.0 ms
+# expanding at N' = 128, and an input-stationary N' = 32 node pays for
+# 128 lanes: 6.2 of 7.6 ms.  Streaming the same planes from HBM costs
+# 512 B per kernel, ~0.6 ns at TPU_HBM_GBPS.
+TABLE_EXPAND_S = 9.8e-12
 # Alg 1's VMEM budget: the working set ``tpu_fused_flow_cost`` prices.
 TPU_VMEM_BYTES = 48 * 2 ** 20
 TPU_ICI_GBPS = 50e9
@@ -587,11 +604,13 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
                         Karatsuba GEMM on active bins actually does);
           'scheduled'   Alg-2 tables — bytes ~ T*(r + 3N') words per
                         (group, channel) with T ~= nnz/mu cycles, i.e.
-                        O(nnz); MACs are the HONEST one-hot-matmul
-                        realization (gather/route/scatter GEMMs), which
-                        exceeds the paper's element count — the mode
-                        wins on bandwidth, not flops, and Alg 1 sees
-                        both sides of that trade.
+                        O(nnz); MACs are the plane GEMM on the Fa bins,
+                        and the kernel first expands the tables into
+                        those planes on every grid step: ``expand_s``,
+                        t_cyc * bm * Fa * lane_round(N') elements per
+                        step at ``TABLE_EXPAND_S`` each, added to
+                        ``compute_s``.  The mode saves bytes and pays
+                        in expansion time; Alg 1 sees both sides.
       r, mu: Alg-2 replica count and estimated Eq-14 utilization used
         to size the scheduled tables before the schedule exists.
       input_mode: input-side path (``INPUT_MODES``), controlling the
@@ -647,7 +666,9 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
     ``input_hbm_bytes`` (the X-operand share: stream * re-read factor
     + the one-off materialization / gather-selector bytes),
     ``had_flops`` (Hadamard stage only), ``flops``, ``vmem_bytes``,
-    ``hbm_s``/``compute_s`` roofline times, ``serial_s``,
+    ``hbm_s``/``compute_s`` roofline times (``compute_s`` = flops at
+    ``TPU_PEAK_FLOPS`` + ``expand_s``, the scheduled mode's table
+    expansion; 0 in the other modes), ``serial_s``,
     ``fits_vmem``, plus (PR 8) ``batch``, ``grid_steps`` (= gn*gm*gp,
     the pallas grid size — the tuner's dispatch-overhead tie-break),
     ``step_s`` and the batch-normalized ``per_image_hbm_bytes`` /
@@ -731,6 +752,7 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
     y_bytes = layer.c_out * s2 * p_pad * bytes_per_el
 
     t_cyc = max(nnz, _ceil(nnz, mu))     # schedule length estimate
+    expand_s = 0.0
     if hadamard is None:                 # legacy compressed stream
         w_bytes = layer.c_out * layer.c_in * nnz * cplx * bytes_per_el
         had_flops = 8 * p_pad * nnz * layer.c_in * layer.c_out
@@ -744,10 +766,12 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
         w_bytes = gn * m_pad * t_cyc * (r + 3 * bn) * bytes_per_el
         # The kernel expands each table block into [Fa, bm, N'] planes
         # in VMEM on every grid step — per cycle one [Fa, r] @ [r, N']
-        # one-hot matmul per channel plus two weight FMAs — then runs
-        # the plane GEMM on the Fa compacted bins.
-        expand = gn * gm * gp * t_cyc * bm * fa * bn * (2 * r + 4)
-        had_flops = 8 * p_pad * fa * m_pad * gn * bn + expand
+        # one-hot matmul per channel plus two weight FMAs, N' padded to
+        # whole lanes — then runs the plane GEMM on the Fa compacted
+        # bins.  Every flow expands again on every p step.
+        expand_s = (gn * gm * gp * t_cyc * bm * fa * lane_round(bn)
+                    * TABLE_EXPAND_S)
+        had_flops = 8 * p_pad * fa * m_pad * gn * bn
 
     if flow == "output_stationary":
         x_hbm = x_stream * gn + x_once
@@ -832,7 +856,7 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
     step_s = float(grid_steps) * float(step_overhead_s)
     hbm_s = float(hbm - serial) / TPU_HBM_GBPS
     serial_s = float(serial) / TPU_HBM_GBPS
-    compute_s = float(flops) / TPU_PEAK_FLOPS
+    compute_s = float(flops) / TPU_PEAK_FLOPS + expand_s
     total_s = serial_s + step_s + max(hbm_s, compute_s)
     return {
         "hbm_bytes": float(hbm),
@@ -845,6 +869,7 @@ def tpu_fused_flow_cost(layer: ConvLayer, fft_size: int, alpha: float,
         "hbm_s": hbm_s,
         "serial_s": serial_s,
         "compute_s": compute_s,
+        "expand_s": expand_s,
         "fits_vmem": vmem <= TPU_VMEM_BYTES,
         # --- residual-shortcut pricing (ISSUE 10) ---------------------
         "residual": residual,

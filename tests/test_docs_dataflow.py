@@ -26,7 +26,7 @@ def _doc_namespace() -> dict:
     ns: dict = {}
     exec(compile(m.group(1), str(DOC), "exec"), ns)  # noqa: S102
     for fn in ("input_bytes", "kernel_bytes", "output_bytes",
-               "per_image_bytes", "step_seconds"):
+               "per_image_bytes", "expand_seconds", "step_seconds"):
         assert fn in ns, f"doc-formulas block lost {fn}()"
     return ns
 
@@ -107,6 +107,12 @@ class TestDocFormulasMatchCode:
             "step_s"
         assert c["grid_steps"] == pytest.approx(gn * gm * gp), \
             "grid_steps"
+
+        # the scheduled datapath's table expansion, inside compute_s
+        assert self.ns["expand_seconds"](
+            gn, gm, gp, nnz, df.SCHEDULE_MU, bm, k2, bn,
+            mode) == pytest.approx(c["expand_s"]), "expand_s"
+        assert self.ns["TABLE_EXPAND_S"] == df.TABLE_EXPAND_S
 
     def test_doc_is_linked(self):
         """README and ARCHITECTURE must point at the walkthrough."""

@@ -26,6 +26,7 @@ from repro.kernels.fused_spectral_conv import (
 from repro.models import cnn
 
 KEY = jax.random.PRNGKey(0)
+VGG = {layer.name: layer for layer in df.VGG16_LAYERS}
 
 
 def _layer_case(h=13, w=12, cin=4, cout=6, alpha=4.0, seed=3):
@@ -247,13 +248,13 @@ class TestModeAwareAutotune:
         assert tn.hadamard is None
 
     def test_late_layers_prefer_scheduled_early_prefer_planes(self):
-        """The per-layer flexibility story: kernel-bound late layers
-        gain most from the table stream (over 1.5x on conv5_1); on the
-        activation-bound first layer the two streams are within a few
-        percent, so the Hadamard mode hardly matters there.  Priced on
-        the (8, 128) tiling, the tables win by a hair even there, so
-        Alg 1 picks them on all 13 layers (conv1_2 had kept the planes
-        before the padding was priced)."""
+        """The per-layer flexibility story, priced where the chip runs
+        it: the tables save the most HBM bytes on the kernel-bound late
+        layers, but the kernel expands them into planes on every grid
+        step, and that expansion grows with the number of kernels.  So
+        at batch 1 the planes win on all 13 layers, by the widest margin
+        on the late ones (~12x on conv5_1) and the narrowest on conv1_1
+        (192 kernels, ~3.5x)."""
         modes, ratio = {}, {}
         for layer in df.VGG16_LAYERS:
             tn = autotune.autotune_layer(
@@ -263,7 +264,78 @@ class TestModeAwareAutotune:
                 layer, 8, 4.0, hadamard_modes=(m,)).predicted_s
                 for m in ("bin", "scheduled")}
             ratio[layer.name] = by["scheduled"] / by["bin"]
-        assert modes == {layer.name: "scheduled"
-                         for layer in df.VGG16_LAYERS}
-        assert ratio["conv5_1"] < 2 / 3
-        assert 0.9 < ratio["conv1_1"] < 1.1
+        assert modes == {layer.name: "bin" for layer in df.VGG16_LAYERS}
+        assert ratio["conv5_1"] > 10
+        assert 2 < ratio["conv1_1"] < ratio["conv5_1"]
+
+
+class TestTableExpansionCost:
+    """The scheduled datapath's in-kernel table expansion, priced on
+    the units that run it (``dataflow.TABLE_EXPAND_S``)."""
+
+    @staticmethod
+    def _cost(layer, bn, bp, bm, flow, mode="scheduled"):
+        return df.tpu_fused_flow_cost(layer, 8, 4.0, bn, bp, bm, flow,
+                                      hadamard=mode)
+
+    @pytest.mark.parametrize("name", ["conv4_1", "conv4_2"])
+    def test_auto_ranks_planes_ahead_of_tables_at_batch_1(self, name):
+        """At VGG16 conv4_1 / conv4_2 shapes, B=1, the best plane
+        candidate beats the best table candidate, so ``auto`` streams
+        planes."""
+        layer = VGG[name]
+        kw = dict(batch=1, input_modes=df.INPUT_MODES)
+        best = {m: autotune.autotune_layer(layer, 8, 4.0,
+                                           hadamard_modes=(m,), **kw)
+                for m in ("dense", "scheduled")}
+        assert best["dense"].predicted_s < best["scheduled"].predicted_s
+        auto = autotune.autotune_layer(
+            layer, 8, 4.0, hadamard_modes=("dense", "scheduled"), **kw)
+        assert auto.hadamard == "dense"
+
+    def test_expansion_grows_with_lane_rounded_block_n(self):
+        """N' rides the lanes: a 32-wide group pays for 128 lanes, so
+        four times the groups cost four times the expansion."""
+        layer = VGG["conv4_2"]
+        for flow in df.FLOWS:
+            narrow = self._cost(layer, 32, 128, 128, flow)
+            wide = self._cost(layer, 128, 128, 128, flow)
+            assert narrow["expand_s"] == pytest.approx(4 * wide["expand_s"])
+            assert narrow["compute_s"] > wide["compute_s"]
+
+    @pytest.mark.parametrize("flow", df.FLOWS)
+    def test_expansion_repeats_on_every_p_step(self, flow):
+        """Each grid step expands its table block: halving block_p on
+        conv1_2 (1444 tiles: 6 -> 12 p steps) doubles the expansion."""
+        layer = VGG["conv1_2"]
+        few = self._cost(layer, 64, 256, 64, flow)
+        many = self._cost(layer, 64, 128, 64, flow)
+        assert many["grid_steps"] == 2 * few["grid_steps"]
+        assert many["expand_s"] == pytest.approx(2 * few["expand_s"])
+
+    def test_rate_covers_the_one_hot_dot(self):
+        """The fitted rate is no less than the one-hot dot's MXU time
+        alone: r of 128 rows of depth, 6 bf16 passes at HIGHEST."""
+        assert df.TABLE_EXPAND_S >= 2 * df.LANES * 6 / df.TPU_PEAK_FLOPS
+
+    def test_forced_scheduled_plan_uses_tables(self):
+        """``auto`` builds no tables where the planes win; a forced
+        'scheduled' plan still compiles its tables and runs them, equal
+        to the plane plan."""
+        cfg = vgg16_spectral.SMOKE
+        params = cnn.init(KEY, cfg)
+        auto = build_network_plan(params, cfg, batch=1)
+        forced = build_network_plan(params, cfg, batch=1,
+                                    hadamard="scheduled")
+        assert all(lp.tables is None and lp.hadamard != "scheduled"
+                   for lp in auto.layers)
+        assert "tables" not in auto.phase_s
+        assert all(lp.hadamard == "scheduled" and lp.tables is not None
+                   for lp in forced.layers)
+        x = jax.random.normal(KEY, (1, 3, cfg.image_size, cfg.image_size))
+        np.testing.assert_allclose(
+            np.asarray(cnn.forward_spectral(params, forced, x,
+                                            backend="pallas_fused")),
+            np.asarray(cnn.forward_spectral(params, auto, x,
+                                            backend="pallas_fused")),
+            atol=1e-4, rtol=1e-4)

@@ -126,6 +126,18 @@ def test_fused_windowed_compiles(one_chip):
     _compile_fused(one_chip, VGG["conv3_1"], tn, 1)
 
 
+def test_fused_plane_node_compiles(one_chip):
+    """A 512->512 node as Alg 1 tunes it at batch 1: the plane datapath
+    (the table expansion costs more than the planes' bytes), at its
+    tuned flow and blocks."""
+    layer = VGG["conv4_2"]
+    tn = at.autotune_layer(layer, FFT, ALPHA, batch=1,
+                           hadamard_modes=["dense", "scheduled"],
+                           input_modes=df.INPUT_MODES)
+    assert tn.hadamard == "dense"
+    _compile_fused(one_chip, layer, tn, 1)
+
+
 def test_fused_residual_compiles(one_chip):
     """A ResNet-18 residual-fused node: the shortcut is one more kernel
     operand, added on the epilogue flush."""
