@@ -19,11 +19,25 @@ and without importing the program under test:
 Matrix products run at ``precision="highest"`` (float32), or at
 ``"high"``: three bfloat16 passes (hi*hi + hi*lo + lo*hi), the control
 that one step less precision must fail.
+
+``network_work`` counts the work of the same mathematics from the shapes
+alone (``bench/work.py`` reads it): overlap-save tiles of t = K - k + 1
+output pixels, one K x K FFT per input channel and tile, a Hadamard
+product over the K^2/alpha kept bins of each (c_out, c_in) kernel, one
+inverse FFT per output channel and tile, and the three-layer FC head.
+No block size, padding, table or layout of the program enters, so a PR
+that changes the kernel leaves these numbers as they are.  Bytes are f32
+(4 per real value, 8 per complex kernel value): each conv reads its input
+activation once, its shortcut once where it has one, its pruned kernel
+values once, and writes its output once; each FC layer reads its weights
+once.  Every conv node runs as the fused spectral conv: its ``kind`` is
+``"spectral"``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +47,8 @@ from bench import graph
 
 F32 = jnp.float32
 PRECISIONS = ("highest", "high")
+F32_BYTES = 4
+C64_BYTES = 8
 
 
 def make_params(cfg: dict, seed: int) -> dict:
@@ -40,8 +56,7 @@ def make_params(cfg: dict, seed: int) -> dict:
     in one jitted call: conv weights N(0, 2/fan_in) [c_out, c_in, k, k],
     biases N(0, 0.01^2), FC weights N(0, 1/fan_in) [in, out]."""
     layers = cfg["layers"]
-    fc = [graph.feature_dim(cfg), cfg["fc_dim"], cfg["fc_dim"],
-          cfg["n_classes"]]
+    fc = [i for i, _ in fc_dims(cfg)] + [cfg["n_classes"]]
 
     def init(key):
         ks = jax.random.split(key, 2 * len(layers) + 3)
@@ -67,7 +82,7 @@ def pruned_kernels(w: jax.Array, fft_size: int, alpha: float):
     """Kept spectral values of one layer as (re, im) [K*K, c_in, c_out]."""
     n, m, k, _ = w.shape
     kk = fft_size * fft_size
-    nnz = max(1, round(kk / alpha))
+    nnz = kept_bins(fft_size, alpha)
     wf = jnp.fft.fft2(jnp.pad(w[..., ::-1, ::-1],
                               ((0, 0), (0, 0), (0, fft_size - k),
                                (0, fft_size - k))).astype(F32))
@@ -175,3 +190,63 @@ class Reference:
             y = self._fwd[precision](self.kernels, self.params, x)
             out.append(np.asarray(y)[:len(part)])
         return np.concatenate(out)
+
+
+# ---------------------------------------------------------------------------
+# The work of one forward pass
+# ---------------------------------------------------------------------------
+
+def fft_flops(k: int) -> float:
+    """Real flops of one K x K complex FFT at the radix-2 count, 5 N log2 N
+    with N = K^2 points."""
+    n = k * k
+    return 5.0 * n * math.log2(n)
+
+
+def kept_bins(fft_size: int, alpha: float) -> int:
+    """Non-zeros kept per K x K spectral kernel."""
+    return max(1, round(fft_size * fft_size / alpha))
+
+
+def _residual_nodes(cfg: dict) -> set[str]:
+    return {n["id"] for n in cfg.get("graph") or ()
+            if n.get("residual_from")}
+
+
+def conv_work(layer: dict, fft_size: int, alpha: float, *, batch: int = 1,
+              residual: bool = False) -> dict:
+    """Flops and HBM bytes of one conv node over ``batch`` images."""
+    k, t = fft_size, fft_size - layer["ksize"] + 1
+    h, w, stride = layer["h_in"], layer["w_in"], layer.get("stride", 1)
+    tiles = math.ceil(h / t) * math.ceil(w / t)
+    c_in, c_out = layer["c_in"], layer["c_out"]
+    nnz = kept_bins(k, alpha)
+    flops = batch * tiles * ((c_in + c_out) * fft_flops(k)
+                             + 8.0 * nnz * c_in * c_out)
+    out_px = math.ceil(h / stride) * math.ceil(w / stride)
+    act = batch * (c_in * h * w + c_out * out_px * (2 if residual else 1))
+    return {"name": layer["name"], "kind": "spectral", "flops": flops,
+            "bytes": act * F32_BYTES + c_in * c_out * nnz * C64_BYTES,
+            "pair_tiles": c_in * c_out * tiles}
+
+
+def fc_dims(cfg: dict) -> list[tuple[int, int]]:
+    """(in, out) of the three FC layers; the first takes the flattened
+    output of the graph's last node."""
+    return [(graph.feature_dim(cfg), cfg["fc_dim"]),
+            (cfg["fc_dim"], cfg["fc_dim"]), (cfg["fc_dim"], cfg["n_classes"])]
+
+
+def network_work(cfg: dict, *, batch: int = 1) -> dict:
+    """Per-node and total work of one forward pass over ``batch`` images,
+    in the form ``bench/work.py`` documents."""
+    res = _residual_nodes(cfg)
+    convs = [conv_work(l, cfg["fft_size"], cfg["alpha"], batch=batch,
+                       residual=l["name"] in res) for l in cfg["layers"]]
+    fc_flops = sum(2.0 * batch * i * o for i, o in fc_dims(cfg))
+    fc_bytes = sum(i * o * F32_BYTES for i, o in fc_dims(cfg))
+    conv_flops = sum(c["flops"] for c in convs)
+    return {"convs": convs, "conv_flops": conv_flops,
+            "conv_bytes": sum(c["bytes"] for c in convs),
+            "fc_flops": fc_flops, "fc_bytes": fc_bytes,
+            "flops": conv_flops + fc_flops}

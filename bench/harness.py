@@ -10,6 +10,21 @@ that ``BENCHMARK.json`` gives it:
 - ``bench/metrics/<metric>.py``, a ``read(ctx)`` that returns the
   metric's value, or None where the run holds nothing to read.
 
+A configuration enters the benchmark with files of its own and entries
+in ``BENCHMARK.json``, and no edit to a file that is here:
+
+- its JSON file.  The harness reads ``name``, ``reference``,
+  ``weights_seed`` and ``limits`` (``logit_err``); ``source``, ``about``,
+  ``reduced`` and ``assumed`` are records for the reader.  Every other
+  key names a field of the program's ``SpectralCNNConfig`` and is passed
+  to it (``program_config``); a key that is neither is an error;
+- the program's preset module ``repro.configs.<name, '-' as '_'>``,
+  with ``CONFIG`` (what ``program_config`` of the file must equal) and
+  ``SMOKE`` (the size the CPU tests run);
+- its reference module ``bench/configs/<reference>.py``, with
+  ``make_params(cfg, seed)``, ``Reference(cfg, params).logits(images,
+  precision)`` and ``network_work(cfg, *, batch)`` (``bench/work.py``).
+
 The program under test is used through its serving front end
 (``repro.launch.spectral_serve.SpectralServer``) and its configuration
 classes; nothing else of it is read.
@@ -95,20 +110,39 @@ def end_to_end_metrics(spec: dict, cell: str) -> list[dict]:
 # The program under test
 # ---------------------------------------------------------------------------
 
-def program_config(cfg: dict):
-    """The program's configuration object for a configuration file."""
+# keys of a configuration file that are the benchmark's, not the program's
+BENCH_KEYS = frozenset({"source", "about", "reference", "weights_seed",
+                        "limits", "reduced", "assumed"})
+
+
+def _field_value(key: str, value):
     from repro.core.dataflow import ConvLayer, NodeSpec
+    if key == "layers":
+        return tuple(ConvLayer(**l) for l in value)
+    if key == "graph":
+        return None if value is None else tuple(
+            NodeSpec(**{**n, "inputs": tuple(n["inputs"])}) for n in value)
+    if key == "pool_after":
+        return frozenset(value)
+    return tuple(value) if isinstance(value, list) else value
+
+
+def program_config(cfg: dict):
+    """The program's configuration object for a configuration file: every
+    key that names a field of ``SpectralCNNConfig`` is passed to it.
+    ``layers`` become ``ConvLayer``s, ``graph`` nodes ``NodeSpec``s,
+    ``pool_after`` a frozenset, any other list a tuple.  A file without
+    ``pool_after`` has no 2x2 pools after its layers (``bench/graph.py``
+    reads it so), whatever the program's default."""
     from repro.models.cnn import SpectralCNNConfig
-    graph = cfg.get("graph")
-    return SpectralCNNConfig(
-        name=cfg["name"],
-        layers=tuple(ConvLayer(**l) for l in cfg["layers"]),
-        fft_size=cfg["fft_size"], alpha=cfg["alpha"],
-        n_classes=cfg["n_classes"], image_size=cfg["image_size"],
-        fc_dim=cfg["fc_dim"],
-        pool_after=frozenset(cfg.get("pool_after", ())),
-        graph=None if graph is None else tuple(
-            NodeSpec(**{**n, "inputs": tuple(n["inputs"])}) for n in graph))
+    fields = {f.name for f in dataclasses.fields(SpectralCNNConfig)}
+    unknown = sorted(set(cfg) - fields - BENCH_KEYS)
+    if unknown:
+        raise ValueError(
+            f"configuration {cfg.get('name')!r}: keys {unknown} name no "
+            f"field of SpectralCNNConfig and no key of the benchmark's")
+    return SpectralCNNConfig(**{"pool_after": frozenset(), **{
+        k: _field_value(k, v) for k, v in cfg.items() if k in fields}})
 
 
 @dataclasses.dataclass

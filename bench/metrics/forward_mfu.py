@@ -1,7 +1,7 @@
 """Percent of the chip's peak flop/s that the whole forward pass's
-counted flops (``bench/work.py``: conv nodes and FC head, per image)
-reach at the traced window's image rate.  The peak is the bf16 one; the
-program computes in f32."""
+counted flops (``bench/work.py``: every conv node, of any kind, and the
+head, per image) reach at the traced window's image rate.  The peak is
+the bf16 one; the program computes in f32."""
 
 from bench import work
 

@@ -1,8 +1,9 @@
-"""Percent of the fused conv's roofline: the least time the conv nodes'
-work could take on this chip (``bench/work.py``: per node the larger of
-flops over peak flop/s and bytes over peak HBM bandwidth), for every
-batch the window ran, over the device time of the ``_fused_conv``
-executables.  The peak is the bf16 one; the program computes in f32."""
+"""Percent of the fused conv's roofline: the least time the work of the
+nodes that run as the fused spectral conv could take on this chip
+(``bench/work.py``: per ``"spectral"`` node the larger of flops over peak
+flop/s and bytes over peak HBM bandwidth), for every batch the window
+ran, over the device time of the ``_fused_conv`` executables.  The peak
+is the bf16 one; the program computes in f32."""
 
 from bench import work
 

@@ -261,29 +261,27 @@ def fused_conv_model_x(ctx) -> float | None:
 def breakdown(ctx, *, top: int = 10) -> dict:
     """``program_idle``: the ``top`` program spans by idle seconds;
     ``nodes``: the ``top`` conv nodes by device ms per image, each with
-    its least time per image (``bench/work.py``), roofline share and
-    Alg 1's prediction against its measured kernel time per call."""
+    its least time per image (its entry of ``work.network_work``),
+    roofline share and Alg 1's prediction against its measured kernel
+    time per call."""
     prog = _program(ctx)
     if prog is None:
         return {}
     idle = sorted(prog["idle_by_span"].items(), key=lambda kv: -kv[1])
-    cfg, images = ctx["cfg"], ctx["images"] or 1
-    layers = {l["name"]: l for l in cfg["layers"]}
-    residual = work._residual_nodes(cfg)
+    images = ctx["images"] or 1
+    least = {}
+    for b, n in ctx["batches"].items():
+        for w in work.network_work(ctx["cfg"], batch=b)["convs"]:
+            least[w["name"]] = least.get(w["name"], 0.0) + n * (
+                work.least_time_s(w["flops"], w["bytes"], ctx["peaks"])[0])
     rows = []
     for nid, node in prog["nodes"].items():
         if node["args"].get("kind") != "conv":
             continue
-        least = 0.0
-        for b, n in ctx["batches"].items():
-            w = work.conv_work(layers[nid], cfg["fft_size"], cfg["alpha"],
-                               batch=b, residual=nid in residual)
-            least += n * work.least_time_s(w["flops"], w["bytes"],
-                                           ctx["peaks"])[0]
         rows.append([nid, {
             "ms_per_image": 1e3 * node["device_s"] / images,
-            "least_ms_per_image": 1e3 * least / images,
-            "roofline_pct": (100.0 * least / node["device_s"]
+            "least_ms_per_image": 1e3 * least[nid] / images,
+            "roofline_pct": (100.0 * least[nid] / node["device_s"]
                              if node["device_s"] else None),
             "kernel_us_per_call": kernel_us_per_call(node),
             "predicted_us": node["args"].get("predicted_us"),

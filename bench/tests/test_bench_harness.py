@@ -3,21 +3,39 @@ traffic generator on a stub server, the metric readers, and whole runs of
 small configurations through the program with the chip check skipped:
 correct when the program is sound, not correct when an answer is
 altered where it is produced, and the control (the reference at one step
-less precision) reads far above the program."""
+less precision) reads far above the program.  A stand-in configuration,
+made here, enters by its own files alone."""
 
 import dataclasses
+import importlib
+import json
 import re
+import sys
+import types
 
 import numpy as np
 import pytest
 
-from bench import harness, run, traffic
+from bench import graph, harness, run, traffic, work
 
 SPEC = harness.benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-PRESETS = {"vgg16-spectral": "repro.configs.vgg16_spectral",
-           "resnet18-spectral": "repro.configs.resnet18_spectral"}
+
+
+def preset(name: str):
+    """The program's preset module of configuration ``name``:
+    ``repro.configs.<name with '-' as '_'>``, with ``CONFIG`` and
+    ``SMOKE``."""
+    module = "repro.configs." + name.replace("-", "_")
+    try:
+        return importlib.import_module(module)
+    except ModuleNotFoundError as e:
+        if e.name != module:
+            raise
+        raise ModuleNotFoundError(
+            f"configuration {name!r} has no preset module {module} "
+            f"(with CONFIG and SMOKE)", name=module) from e
 
 
 # ---------------------------------------------------------------------------
@@ -55,15 +73,26 @@ def test_benchmark_json_keeps_its_contract():
 
 @pytest.mark.parametrize("entry", SPEC["configs"], ids=lambda c: c["name"])
 def test_each_config_loads_by_name_and_is_the_programs_preset(entry):
-    import importlib
     cfg = harness.config(SPEC, entry["name"])
     assert cfg["name"] == entry["name"]
     assert entry["file"].startswith("bench/configs/")
     assert cfg["reduced"] == entry["reduced"]
     ref = harness.reference_module(cfg)
     assert callable(ref.make_params) and callable(ref.Reference)
-    preset = importlib.import_module(PRESETS[entry["name"]]).CONFIG
-    assert harness.program_config(cfg) == preset
+    assert callable(ref.network_work)
+    assert harness.program_config(cfg) == preset(entry["name"]).CONFIG
+
+
+def test_a_key_that_names_no_field_is_refused():
+    cfg = {**harness.config(SPEC, "vgg16-spectral"), "fc_dimm": 512}
+    with pytest.raises(ValueError, match=r"\['fc_dimm'\]"):
+        harness.program_config(cfg)
+
+
+def test_a_config_without_a_preset_names_the_module_it_needs():
+    with pytest.raises(ModuleNotFoundError,
+                       match="repro.configs.no_such_cnn"):
+        preset("no-such-cnn")
 
 
 @pytest.mark.parametrize("w", SPEC["workloads"], ids=lambda w: w["name"])
@@ -202,7 +231,6 @@ def test_images_follow_the_seed():
 
 def test_metric_readers_on_a_known_context():
     cfg = harness.config(SPEC, "vgg16-spectral")
-    from bench import work
     peaks = work.peaks_for("TPU v5 lite")
     least = work.conv_least_time_s(cfg, peaks)[0]
     ctx = {"cfg": cfg, "plan_build_s": 170.0, "warmup_s": 3.0,
@@ -222,6 +250,11 @@ def test_metric_readers_on_a_known_context():
     flops = work.network_work(cfg)["flops"]
     assert read("forward_mfu") == pytest.approx(
         100 * flops * 40 / peaks["flops_per_s"])
+    # the readings the work count gave before it moved to the reference
+    assert read("forward_mfu") == 0.17432381595939087
+    assert read("fused_conv_roofline") == 1.0
+    ctx["batches"] = {1: 300, 8: 20}
+    assert read("fused_conv_roofline") == 0.9056978285526872
     # nothing to read: no value, never a 0
     ctx["trace"] = None
     for name in ("device_idle_share", "fused_conv_ms_per_image",
@@ -233,36 +266,39 @@ def test_metric_readers_on_a_known_context():
 # Whole runs at a small size, chip check skipped
 # ---------------------------------------------------------------------------
 
-def small_config(name: str) -> dict:
-    """The cell's configuration file at the program's SMOKE size, with
-    the full-size cell's limits."""
-    import importlib
-    pc = importlib.import_module(PRESETS[name]).SMOKE
-    cfg = harness.config(SPEC, name)
-    cfg.update(name=pc.name, image_size=pc.image_size,
-               n_classes=pc.n_classes, fc_dim=pc.fc_dim,
-               layers=[dataclasses.asdict(l) for l in pc.layers])
-    if pc.graph:
-        cfg["graph"] = [{"id": n.id, "kind": n.kind,
-                         "inputs": list(n.inputs), "pool": n.pool,
-                         "residual_from": n.residual_from, "relu": n.relu}
-                        for n in pc.graph]
+def file_value(value):
+    """A program field's value as a configuration file holds it."""
+    if dataclasses.is_dataclass(value):
+        return {f.name: file_value(getattr(value, f.name))
+                for f in dataclasses.fields(value)}
+    if isinstance(value, frozenset):
+        return sorted(value)
+    if isinstance(value, (tuple, list)):
+        return [file_value(v) for v in value]
+    return value
+
+
+def small_config(name: str, spec: dict = SPEC) -> dict:
+    """The configuration file with every field of the program's SMOKE
+    preset in place of its own, and the full-size cell's limits."""
+    pc = preset(name).SMOKE
+    cfg = harness.config(spec, name)
+    cfg.update(file_value(pc))
     assert harness.program_config(cfg) == pc
     return cfg
 
 
 def small_run(name, seed, **kw):
     cfg = small_config(name)
-    mx = harness.mix("stream-b1")
-    cell = {"vgg16-spectral": "vgg16-b1-stream",
-            "resnet18-spectral": "resnet18-b1-stream"}[name]
-    return harness.run(cell, seed, 1.0, False, t_start=0.0,
+    cell = next(w for w in SPEC["workloads"] if w["config"] == name)
+    return harness.run(cell["name"], seed, 1.0, False, t_start=0.0,
                        device={"platform": "cpu", "kind": "cpu",
                                "count": 1},
-                       cfg=cfg, mx=mx, log=lambda msg: None, **kw)
+                       cfg=cfg, mx=harness.mix(cell["traffic"]),
+                       log=lambda msg: None, **kw)
 
 
-@pytest.mark.parametrize("name", list(PRESETS))
+@pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
 def test_a_small_run_is_correct(name):
     out = small_run(name, 2 ** 35 + 17)
     assert list(out) == ["correct", "attempted", "failed", "metrics",
@@ -308,16 +344,146 @@ def test_an_answer_altered_where_it_is_produced_is_not_correct(monkeypatch):
     assert out["checks"]["logit_err"]["value"] >= 1e-3
 
 
-@pytest.mark.parametrize("name", list(PRESETS))
+@pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
 def test_the_control_reads_far_above_the_program(name):
     """The reference at "high" (three bf16 passes) in the program's
     place fails the cell's limit, where the program passes it."""
     cfg = small_config(name)
     params = harness.reference_module(cfg).make_params(cfg, 0)
     ref = harness.reference_module(cfg).Reference(cfg, params)
-    pool = traffic.image_pool((3, 32, 32), 8, seed=4)
+    shape = graph.shapes(cfg)["input"]
+    pool = traffic.image_pool(shape, 8, seed=4)
     recs = [traffic.Record(i, i, 0.0, 0.0, "ok") for i in range(8)]
     win = harness.Window(recs, 0.0, 1.0, pool, {1: 8}, 0)
     control = harness.compare(ref, win, recs, control=True)
     limit = cfg["limits"]["logit_err"]
     assert control > limit
+
+
+# ---------------------------------------------------------------------------
+# A configuration that enters by its own files alone
+# ---------------------------------------------------------------------------
+
+STANDIN = "standin-cnn"
+
+
+def standin_work(cfg: dict, *, batch: int = 1) -> dict:
+    """The stand-in reference's count: its ``"dense"`` nodes as 1x1
+    GEMMs, the others as spectral convs, and a one-layer head."""
+    from bench.configs import spectral_cnn
+    ops = {n["id"]: n["op"] for n in cfg["graph"] if n["kind"] == "conv"}
+    convs = []
+    for l in cfg["layers"]:
+        if ops[l["name"]] == "dense":
+            px, mn = l["h_in"] * l["w_in"], l["c_in"] * l["c_out"]
+            convs.append({"name": l["name"], "kind": "dense",
+                          "flops": 2.0 * batch * px * mn,
+                          "bytes": 4 * (batch * px * (l["c_in"] + l["c_out"])
+                                        + mn)})
+        else:
+            convs.append(spectral_cnn.conv_work(
+                l, cfg["fft_size"], cfg["alpha"], batch=batch))
+    head = graph.feature_dim(cfg) * cfg["n_classes"]
+    conv_flops = sum(c["flops"] for c in convs)
+    return {"convs": convs, "conv_flops": conv_flops,
+            "conv_bytes": sum(c["bytes"] for c in convs),
+            "fc_flops": 2.0 * batch * head, "fc_bytes": 4 * head,
+            "flops": conv_flops + 2.0 * batch * head}
+
+
+@pytest.fixture
+def standin(monkeypatch, tmp_path):
+    """A third configuration whose program fields include two that the
+    program lacks (a node's ``op``, the config's ``fc_layers``), with its
+    file, preset module and reference module: the spec that lists it."""
+    from repro.core import dataflow
+    from repro.models import cnn
+
+    @dataclasses.dataclass(frozen=True)
+    class Node(dataflow.NodeSpec):
+        op: str = "spectral"
+
+    @dataclasses.dataclass(frozen=True)
+    class Config(cnn.SpectralCNNConfig):
+        fc_layers: int = 3
+
+    def make(name, size, width):
+        return Config(
+            name=name, n_classes=10, image_size=size, fc_dim=width,
+            pool_after=frozenset(), fc_layers=1,
+            layers=(dataflow.ConvLayer("a", 3, width, size, size),
+                    dataflow.ConvLayer("b", width, 2 * width, size, size,
+                                       ksize=1, pad=0)),
+            graph=(Node("a"), Node("b", inputs=("a",), op="dense"),
+                   Node("head:pool", kind="pool", pool="avg",
+                        inputs=("b",))))
+
+    monkeypatch.setattr(dataflow, "NodeSpec", Node)
+    monkeypatch.setattr(cnn, "SpectralCNNConfig", Config)
+    monkeypatch.setitem(sys.modules, "repro.configs.standin_cnn",
+                        types.SimpleNamespace(CONFIG=make(STANDIN, 32, 16),
+                                              SMOKE=make("standin-s", 8, 4)))
+    monkeypatch.setitem(sys.modules, "bench.configs.standin_ref",
+                        types.SimpleNamespace(network_work=standin_work))
+    layer = {"h_in": 32, "w_in": 32, "stride": 1}
+    file = {
+        "name": STANDIN, "source": "https://example.org/standin",
+        "reference": "standin_ref", "weights_seed": 0,
+        "image_size": 32, "fft_size": 8, "alpha": 4.0, "n_classes": 10,
+        "fc_dim": 16, "fc_layers": 1, "pool_after": [],
+        "layers": [{**layer, "name": "a", "c_in": 3, "c_out": 16,
+                    "ksize": 3, "pad": 1},
+                   {**layer, "name": "b", "c_in": 16, "c_out": 32,
+                    "ksize": 1, "pad": 0}],
+        "graph": [{"id": "a", "kind": "conv", "inputs": ["input"],
+                   "op": "spectral"},
+                  {"id": "b", "kind": "conv", "inputs": ["a"],
+                   "op": "dense"},
+                  {"id": "head:pool", "kind": "pool", "inputs": ["b"],
+                   "pool": "avg"}],
+        "reduced": [], "limits": {"logit_err": 5e-06}}
+    path = tmp_path / f"{STANDIN}.json"
+    path.write_text(json.dumps(file))
+    return {**SPEC,
+            "configs": SPEC["configs"] + [
+                {"name": STANDIN, "source": file["source"],
+                 "file": str(path), "reduced": [], "why": "a stand-in"}],
+            "workloads": SPEC["workloads"] + [
+                {"name": "standin-b1-stream", "config": STANDIN,
+                 "traffic": "stream-b1", "chips": 1, "why": "a stand-in"}]}
+
+
+def test_a_config_with_fields_of_its_own_reaches_the_program(standin):
+    cfg = harness.config(standin, STANDIN)
+    pc = harness.program_config(cfg)
+    assert pc == preset(STANDIN).CONFIG
+    assert pc.fc_layers == 1 and [n.op for n in pc.graph[:2]] == [
+        "spectral", "dense"]
+    small = small_config(STANDIN, standin)
+    assert small["name"] == "standin-s" and small["fc_layers"] == 1
+    assert [n.get("op") for n in small["graph"]] == [
+        "spectral", "dense", "spectral"]
+    assert small["limits"] == cfg["limits"]
+
+
+def test_the_roofline_counts_only_spectral_nodes_and_mfu_all(standin):
+    cfg = harness.config(standin, STANDIN)
+    w = work.network_work(cfg)
+    assert [(c["name"], c["kind"]) for c in w["convs"]] == [
+        ("a", "spectral"), ("b", "dense")]
+    peaks = work.peaks_for("TPU v5 lite")
+    spectral = work.least_time_s(w["convs"][0]["flops"],
+                                 w["convs"][0]["bytes"], peaks)[0]
+    assert work.conv_least_time_s(cfg, peaks) == (
+        spectral, {"flops": 0, "bytes": 1})
+    ctx = {"cfg": cfg, "images": 400, "window_s": 10.0, "batches": {1: 400},
+           "peaks": peaks, "trace": {"window_s": 10.0, "busy_s": 8.0,
+                                     "module_s": {"jit__fused_conv":
+                                                  100 * spectral * 400}}}
+    assert harness.metric_reader("fused_conv_roofline")(ctx) == (
+        pytest.approx(1.0))
+    flops = (w["convs"][0]["flops"] + w["convs"][1]["flops"]
+             + 2.0 * 16 * 16 * 32 * 10)
+    assert w["flops"] == flops
+    assert harness.metric_reader("forward_mfu")(ctx) == pytest.approx(
+        100 * flops * 40 / peaks["flops_per_s"])

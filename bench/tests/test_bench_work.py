@@ -1,4 +1,5 @@
-"""Work counts and peaks of the benchmark (bench/work.py, bench/peaks.json)."""
+"""Work counts and peaks of the benchmark (bench/work.py, bench/peaks.json,
+and the spectral CNN reference's count in bench/configs/spectral_cnn.py)."""
 
 import dataclasses
 import json
@@ -7,6 +8,7 @@ from pathlib import Path
 import pytest
 
 from bench import work
+from bench.configs import spectral_cnn
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
@@ -39,11 +41,56 @@ def test_resnet18_counts():
     cfg = load("resnet18-spectral")
     w = work.network_work(cfg)
     assert sum(c["pair_tiles"] for c in w["convs"]) == 38_247_168
-    assert work.fc_dims(cfg) == [(25088, 512), (512, 512), (512, 1000)]
+    assert spectral_cnn.fc_dims(cfg) == [(25088, 512), (512, 512),
+                                         (512, 1000)]
     # residual nodes also read their shortcut
     by = {c["name"]: c for c in w["convs"]}
-    plain = work.conv_work(cfg["layers"][1], 8, 4.0)
+    plain = spectral_cnn.conv_work(cfg["layers"][1], 8, 4.0)
     assert by["s1b1b"]["bytes"] - plain["bytes"] == 64 * 112 * 112 * 4
+
+
+# the counts as they stood when bench/work.py itself counted every config
+PINNED = {
+    "vgg16-spectral": {
+        "fc_dims": [(25088, 4096), (4096, 4096), (4096, 1000)],
+        "pair_tiles": 54_909_696,
+        1: ({"conv_flops": 8338180608.0, "conv_bytes": 299732992,
+             "fc_flops": 247267328.0, "fc_bytes": 494534656,
+             "flops": 8585447936.0},
+            (0.0003659743492063492, {"flops": 0, "bytes": 13})),
+        8: ({"conv_flops": 66705444864.0, "conv_bytes": 933355520,
+             "fc_flops": 1978138624.0, "fc_bytes": 494534656,
+             "flops": 68683583488.0},
+            (0.0011396282295482296, {"flops": 0, "bytes": 13}))},
+    "resnet18-spectral": {
+        "fc_dims": [(25088, 512), (512, 512), (512, 1000)],
+        "pair_tiles": 38_247_168,
+        1: ({"conv_flops": 6045633024.0, "conv_bytes": 282390528,
+             "fc_flops": 27238400.0, "fc_bytes": 54476800,
+             "flops": 6072871424.0},
+            (0.0003447991794871795, {"flops": 0, "bytes": 20})),
+        8: ({"conv_flops": 48365064192.0, "conv_bytes": 857006080,
+             "fc_flops": 217907200.0, "fc_bytes": 54476800,
+             "flops": 48582971392.0},
+            (0.0010464054700854702, {"flops": 0, "bytes": 20}))},
+}
+
+
+@pytest.mark.parametrize("name", list(PINNED))
+def test_the_references_count_keeps_the_pinned_totals(name):
+    cfg, pin = load(name), PINNED[name]
+    peaks = work.peaks_for("TPU v5 lite")
+    assert spectral_cnn.fc_dims(cfg) == pin["fc_dims"]
+    for batch in (1, 8):
+        w = work.network_work(cfg, batch=batch)
+        assert w == spectral_cnn.network_work(cfg, batch=batch)
+        assert {k: v for k, v in w.items() if k != "convs"} == pin[batch][0]
+        assert sum(c["pair_tiles"] for c in w["convs"]) == pin["pair_tiles"]
+        assert [c["name"] for c in w["convs"]] == [
+            l["name"] for l in cfg["layers"]]
+        assert {c["kind"] for c in w["convs"]} == {"spectral"}
+        assert work.conv_least_time_s(cfg, peaks, batch=batch) == (
+            pin[batch][1])
 
 
 def test_batch_reads_kernels_once():
@@ -72,7 +119,8 @@ def test_count_ignores_the_plans_blocks_and_hadamard_mode():
     assert ({lp.hadamard for lp in a.layers}
             != {lp.hadamard for lp in b.layers})
     assert [lp.tuning for lp in a.layers] != [lp.tuning for lp in b.layers]
-    base = {"image_size": pc.image_size, "fft_size": pc.fft_size,
+    base = {"reference": "spectral_cnn",
+            "image_size": pc.image_size, "fft_size": pc.fft_size,
             "alpha": pc.alpha, "n_classes": pc.n_classes,
             "fc_dim": pc.fc_dim, "pool_after": sorted(pc.pool_after)}
     wa = work.network_work({**base, "layers": _layers_of(a)})

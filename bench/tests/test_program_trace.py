@@ -7,6 +7,7 @@ from pathlib import Path
 import pytest
 
 from bench import harness, program_trace as pt, trace_reduce as tr, work
+from bench.configs import spectral_cnn
 
 DATA = Path(__file__).resolve().parent / "data" / "trace_program.pbtxt"
 US = 1e-6
@@ -131,7 +132,8 @@ def test_deferred_enqueues_go_to_the_node_that_owns_nothing_yet():
 
 LAYER = {"ksize": 3, "pad": 1, "stride": 1, "h_in": 16, "w_in": 16,
          "c_in": 8, "c_out": 8}
-CFG = {"fft_size": 8, "alpha": 4.0,
+CFG = {"reference": "spectral_cnn", "image_size": 16, "n_classes": 10,
+       "fc_dim": 16, "fft_size": 8, "alpha": 4.0,
        "layers": [{**LAYER, "name": f"conv{i}"} for i in (1, 2, 3)]}
 
 
@@ -171,7 +173,7 @@ def test_the_breakdown_ranks_conv_nodes_by_device_time(parsed, reduced):
     assert conv2["kernel_us_per_call"] == pytest.approx(37.0)
     assert conv2["predicted_us"] == 37.0
     assert conv2["hadamard"] == "scheduled"
-    w = work.conv_work(CFG["layers"][1], 8, 4.0)
+    w = spectral_cnn.conv_work(CFG["layers"][1], 8, 4.0)
     least = work.least_time_s(w["flops"], w["bytes"], ctx["peaks"])[0]
     assert conv2["least_ms_per_image"] == pytest.approx(1e3 * least)
     assert conv2["roofline_pct"] == pytest.approx(100 * least / (39 * US))
